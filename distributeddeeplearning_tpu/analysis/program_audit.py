@@ -39,6 +39,7 @@ import logging
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
+import jax.extend.core as _jcore
 import jax.numpy as jnp
 import numpy as np
 
@@ -56,11 +57,6 @@ _last_skips: List[str] = []
 def skipped_audits() -> List[str]:
     """Human-readable descriptions of audits the last run skipped."""
     return list(_last_skips)
-
-try:  # jax moved core between minor versions; both spellings in the wild
-    from jax._src import core as _jcore
-except ImportError:  # pragma: no cover
-    import jax.core as _jcore  # type: ignore
 
 #: host-callback primitives banned in hot programs
 BANNED_PRIMITIVES = (
@@ -410,7 +406,11 @@ class CollectiveContract:
     """What the comm-overlap program must look like at the jaxpr level."""
 
     in_scan_reduce_scatter_min: int  # one per bucket per microbatch
-    psum_outside_scan_max: int = 1  # the single fused metrics pmean
+    # psum binds outside the scan that carry anything but scalars: none.
+    # The fused metrics pmean is all scalars (jax 0.9.0 binds one psum
+    # per leaf of a tree-level pmean, so its bind COUNT means nothing);
+    # a hoisted gradient all-reduce is an array.
+    psum_outside_scan_max: int = 0
     all_gather_min: int = 1  # params (or grads) return via all-gather
 
 
@@ -427,7 +427,8 @@ def check_collective_contract(
             else:
                 outside_rs += 1
         elif prim == "psum" and not in_scan:
-            psum_outside += 1
+            if any(getattr(v.aval, "ndim", 0) > 0 for v in eqn.invars):
+                psum_outside += 1
         elif prim == "all_gather":
             all_gathers += 1
     findings: List[Finding] = []
@@ -449,11 +450,12 @@ def check_collective_contract(
         findings.append(
             Finding(
                 "collective-signature", path, line,
-                f"{name}: {psum_outside} psum ops outside the scan "
-                f"(contract allows {contract.psum_outside_scan_max}: the "
-                "fused metrics pmean) — a hoisted all-reduce crept back in",
+                f"{name}: {psum_outside} array-valued psum ops outside "
+                f"the scan (contract allows "
+                f"{contract.psum_outside_scan_max}; the scalar metrics "
+                "pmean is not counted) — a hoisted all-reduce crept back in",
                 hint="gradient traffic must ride the in-scan reduce-"
-                "scatter; keep metrics to ONE tree-level pmean bind",
+                "scatter; only scalar metrics reduce outside it",
             )
         )
     if all_gathers < contract.all_gather_min:
@@ -666,6 +668,19 @@ def check_sharding_coverage() -> List[Finding]:
         "v_scale": _sds((5, _PAGE, _H), jnp.float32),
         "tables": _sds((_SLOTS, 4), jnp.int32),
         "posmat": _sds((_SLOTS, 4), jnp.int32),
+        "k_own": _sds((_SLOTS, _H, _D // _H), jnp.float32),
+        "v_own": _sds((_SLOTS, _H, _D // _H), jnp.float32),
+    }
+    attn_dense_abs = {
+        "q": attn_abs["q"],
+        "out": attn_abs["out"],
+        "k_rows": _sds((_SLOTS, _SEQ, _H, _D // _H), jnp.float32),
+        "v_rows": _sds((_SLOTS, _SEQ, _H, _D // _H), jnp.float32),
+        "k_scale": _sds((_SLOTS, _SEQ, _H), jnp.float32),
+        "v_scale": _sds((_SLOTS, _SEQ, _H), jnp.float32),
+        "posmat": attn_abs["posmat"],
+        "k_own": attn_abs["k_own"],
+        "v_own": attn_abs["v_own"],
     }
     for tname, tree, prefix in (
         ("serve.params.f32", fx.params, "params"),
@@ -677,6 +692,7 @@ def check_sharding_coverage() -> List[Finding]:
         ("kv.paged.int8", fx.paged_int8.cache, "kv_paged"),
         ("engine.io", io_abs, "io"),
         ("flash_decode.operands", attn_abs, "attn"),
+        ("flash_decode.dense_operands", attn_dense_abs, "attn_dense"),
     ):
         findings += check_rule_fallthrough(
             tree, prefix=prefix, name=tname, path=rpath, line=rline

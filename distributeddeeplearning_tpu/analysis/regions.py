@@ -174,14 +174,14 @@ JIT_BUILDER_REGIONS: Tuple[HotRegion, ...] = (
     # verify program, so ANY host-sync token is a per-step round-trip
     # hiding inside the compiled step — zero designed syncs, markers
     # don't waive.  The landmarks double as the dispatch-shape guard:
-    # both the Pallas kernel call (via the tensor-parallel shard_map
-    # wrapper ``_pallas_tp``) and the legacy gather fallback must
-    # remain reachable from this one site.
+    # both the Pallas kernel call (via the per-shard shard_map wrapper
+    # ``_pallas_paged``) and the legacy gather path must remain
+    # reachable from this one site.
     HotRegion(
         name="flash-decode-dispatch",
         module="distributeddeeplearning_tpu.ops.flash_decode",
         qualname="decode_attention_paged",
-        landmarks=("_pallas_tp(", "_gather_decode_paged("),
+        landmarks=("_pallas_paged(", "_gather_decode_paged("),
         honor_markers=False,
     ),
     HotRegion(

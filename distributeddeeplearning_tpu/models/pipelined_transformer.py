@@ -237,6 +237,7 @@ def forward_prefill(
     *,
     num_heads: int,
     attention: str = "dense",
+    attention_fn=None,
 ):
     """Prompt pass for the serving engine: logits AND per-layer K/V.
 
@@ -247,14 +248,16 @@ def forward_prefill(
     Returns ``(logits [b, s, vocab], k, v)`` with k/v in the cache layout
     ``[b, L, s, h, hd]`` (``serve.kv_cache`` slot layout minus the slot
     padding).  ``attention="flash"`` runs the causal Pallas kernel for the
-    prompt pass — the O(S²)-free long-prompt path.
+    prompt pass — the O(S²)-free long-prompt path; under a mesh the caller
+    passes the per-shard form as ``attention_fn`` (see
+    :func:`block_apply`), since a bare kernel cannot be partitioned.
     """
     x = _embed(params, tokens)
 
     def body(carry, layer_params):
         h, kv = block_apply(
             layer_params, carry, num_heads=num_heads, attention=attention,
-            return_kv=True,
+            attention_fn=attention_fn, return_kv=True,
         )
         return h, kv
 

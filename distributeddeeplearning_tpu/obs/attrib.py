@@ -348,6 +348,27 @@ def tracked_jit(name: str, fn) -> TrackedProgram:
     return _PROGRAMS.track(name, fn)
 
 
+#: the custom-call target a Pallas TPU kernel lowers to (Mosaic)
+MOSAIC_CALL_TARGET = "tpu_custom_call"
+
+
+def mosaic_call_counts(programs: Sequence[TrackedProgram]) -> Dict[str, int]:
+    """``program name -> Mosaic custom calls`` in the lowered text of each
+    program's compiled signatures (the fewest over its signatures; 0 for
+    a program that never compiled).  The evidence that a Pallas kernel is
+    IN the program a run executed — read off the program itself, not off
+    the option that asked for it; a ``lax.scan`` body counts once.
+    Re-traces each signature, so call it once at the end of a run."""
+    out: Dict[str, int] = {}
+    for prog in programs:
+        counts = [
+            prog.lower(*args, **kwargs).as_text().count(MOSAIC_CALL_TARGET)
+            for args, kwargs in list(prog._sigs.values())
+        ]
+        out[prog.name] = min(counts, default=0)
+    return out
+
+
 # the program-cost table rides every flight-recorder dump (cached table
 # only — no lowering mid-crash); see obs/recorder.register_dump_context
 _recorder_mod.register_dump_context(
@@ -520,21 +541,31 @@ def compute_collective_split(
 
 def reference_peaks() -> Tuple[float, float, str]:
     """(peak_tflops, peak_hbm_gbps, source) for the roofline columns:
-    the real chip's datasheet peaks when :func:`utils.hardware` knows
-    BOTH its compute and HBM-bandwidth ceilings, otherwise the v5e
-    nominals LABELED as reference numbers — achieved-vs-roofline ratios
-    off-TPU (or on a chip with only one known ceiling, which would pair
-    a real compute peak with another chip's memory ceiling) are then
-    explicitly "vs a v5e", never passed off as this host's ceiling."""
+    the real chip's datasheet peaks (source ``"device"``) when
+    :func:`utils.hardware` knows BOTH its compute and HBM-bandwidth
+    ceilings.  On the CPU backend — the hermetic self-check — the v5e
+    nominals come back LABELED ``"v5e-nominal-reference"``, so the ratios
+    read "vs a v5e", never as this host's ceiling.  An accelerator that
+    is missing from the peaks tables raises: a roofline against another
+    chip's ceiling is a wrong number, not a default."""
+    import jax
+
     from distributeddeeplearning_tpu.utils.hardware import (
         peak_bf16_flops,
         peak_hbm_gbps,
     )
 
-    peak = peak_bf16_flops()
-    bw = peak_hbm_gbps()
+    device = jax.devices()[0]
+    peak = peak_bf16_flops(device)
+    bw = peak_hbm_gbps(device)
     if peak is not None and bw is not None:
         return peak / 1e12, bw, "device"
+    if device.platform != "cpu":
+        raise ValueError(
+            f"no published peaks for device kind {device.device_kind!r} "
+            "in utils/hardware.py (_PEAK_BF16_FLOPS / _PEAK_HBM_GBPS) — "
+            "add the chip's datasheet figures there"
+        )
     return 197.0, 819.0, "v5e-nominal-reference"
 
 

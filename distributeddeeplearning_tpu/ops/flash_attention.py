@@ -30,17 +30,12 @@ all-float.
 from __future__ import annotations
 
 import functools
-import warnings
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific pallas extras are absent on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_BIG = -1e30  # finite mask fill; -inf poisons the online-softmax max
 
@@ -135,8 +130,6 @@ def _flash_fwd_pallas(q3, k3, v3, bias2, *, heads: int, block_q: int,
                       block_k: int, out_dtype, causal: bool = False,
                       has_bias: bool = True):
     """q3/k3/v3: [BH, S, D]; bias2: [B, S] f32 → (o [BH,S,D], lse [BH,S])."""
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU support unavailable in this jax build")
     bh, s, d = q3.shape
     scale = 1.0 / (d ** 0.5)
     grid = (bh, s // block_q, s // block_k)
@@ -180,6 +173,7 @@ def _flash_fwd_pallas(q3, k3, v3, bias2, *, heads: int, block_q: int,
         scratch_shapes=scratch,
         compiler_params=compiler_params,
         interpret=_use_interpret(),
+        name="flash_attention_fwd",
     )(q3, k3, v3, bias2[:, None, :])
     return o3, lse3[:, 0, :]
 
@@ -309,8 +303,6 @@ def _flash_bwd_pallas(q3, k3, v3, bias2, o3, lse, do3, *, heads: int,
                       block_q: int, block_k: int, causal: bool = False,
                       has_bias: bool = True):
     """FlashAttention-2 backward: (dq, dk, dv), each [BH, S, D]."""
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU support unavailable in this jax build")
     bh, s, d = q3.shape
     scale = 1.0 / (d ** 0.5)
     # delta_i = Σ_d dO ⊙ O — one cheap O(S·D) elementwise reduce in XLA.
@@ -342,6 +334,7 @@ def _flash_bwd_pallas(q3, k3, v3, bias2, o3, lse, do3, *, heads: int,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=compiler_params,
         interpret=_use_interpret(),
+        name="flash_attention_bwd_dq",
     )(q3, k3, v3, bias3, do3, lse3, delta3)
 
     # dk/dv pass: swap the roles — k blocks resident (grid dim 1), q blocks
@@ -370,6 +363,7 @@ def _flash_bwd_pallas(q3, k3, v3, bias2, o3, lse, do3, *, heads: int,
         ],
         compiler_params=compiler_params,
         interpret=_use_interpret(),
+        name="flash_attention_bwd_dkv",
     )(q3, k3, v3, bias3, do3, lse3, delta3)
     return dq3, dk3, dv3
 
@@ -416,8 +410,7 @@ def _auto_block(s: int, cap: int = 1024) -> int:
 
     Sequence lengths with low power-of-two divisibility land on tiny
     blocks (1032 → 8, odd → 1) whose (S/b)² grids are pathological;
-    :func:`flash_attention` falls back to the dense path below
-    ``AUTO_BLOCK_FLOOR`` instead of running them.
+    :func:`flash_attention` refuses them below ``AUTO_BLOCK_FLOOR``.
     """
     b = min(cap, s)
     while s % b:
@@ -425,23 +418,24 @@ def _auto_block(s: int, cap: int = 1024) -> int:
     return b
 
 
-# Auto-selected blocks below this run a pathological (S/b)² grid; the
-# wrapper warns and takes the dense path instead.  S itself below the floor
-# is fine (the grid is a single tile), so the effective floor is min(S, 128).
+# Auto-selected blocks below this run a pathological (S/b)² grid, which
+# the wrapper refuses.  S itself below the floor is fine (the grid is a
+# single tile), so the effective floor is min(S, 128).
 AUTO_BLOCK_FLOOR = 128
 
-#: Shape classes (s, block_q, block_k) the dense-fallback warning already
-#: fired for — warn ONCE per process per shape: small-dim serve loops and
-#: tests hit the fallback every call, and a per-call warning floods stderr
-#: without adding information.
-_WARNED_FALLBACKS: set = set()
+
+def auto_block_tiles(s: int) -> bool:
+    """Whether auto-selected blocks tile a length-``s`` sequence at or
+    above the floor — callers that pick sequence lengths (the dense serve
+    engine's prompt buckets, the LM workload's ``seq_len``) check this up
+    front instead of discovering the refusal mid-run."""
+    return _auto_block(s) >= min(s, AUTO_BLOCK_FLOOR)
 
 
 def _dense_attention(q, k, v, mask, *, dtype, causal):
     """Reference dense attention with the kernel's exact semantics (f32
-    softmax, key-padding mask, causal triangle) — the fallback when the
-    auto-selected block is pathologically small, and differentiable by
-    plain XLA autodiff."""
+    softmax, key-padding mask, causal triangle) — what the tests and
+    ``chip_smoke.py`` compare the kernel against."""
     b, s, h, d = q.shape
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * (
         1.0 / d ** 0.5
@@ -488,22 +482,15 @@ def flash_attention(
     floor = min(s, AUTO_BLOCK_FLOOR)
     if (auto_q and block_q < floor) or (auto_k and block_k < floor):
         # Low power-of-two divisibility (1032 → block 8, odd S → 1): the
-        # (S/b)² grid compiles and runs pathologically.  Degrading LOUDLY
-        # to dense beats both silent degradation and the old hard error —
-        # but loudly ONCE per shape class: a serve loop hits this every
-        # decode/prefill call with the same shapes.
-        shape_class = (s, block_q, block_k)
-        if shape_class not in _WARNED_FALLBACKS:
-            _WARNED_FALLBACKS.add(shape_class)
-            warnings.warn(
-                f"flash_attention: seq len {s} auto-selects block "
-                f"({block_q}, {block_k}) below the {AUTO_BLOCK_FLOOR} "
-                "floor — falling back to dense attention (pad the "
-                "sequence or pass explicit block_q/block_k to force the "
-                "kernel; warned once per shape)",
-                stacklevel=2,
-            )
-        return _dense_attention(q, k, v, mask, dtype=dtype, causal=causal)
+        # (S/b)² grid compiles and runs pathologically.  Refused, not
+        # rerouted to dense: a caller that asked for the kernel must not
+        # silently get another program.
+        raise ValueError(
+            f"flash_attention: seq len {s} auto-selects block "
+            f"({block_q}, {block_k}) below the {AUTO_BLOCK_FLOOR} floor — "
+            f"pad the sequence to a multiple of {AUTO_BLOCK_FLOOR}, pass "
+            "explicit block_q/block_k, or ask for dense attention"
+        )
     if s % block_q or s % block_k:
         raise ValueError(
             f"seq len {s} not divisible by blocks ({block_q}, {block_k})"
@@ -548,7 +535,7 @@ def make_flash_attention(block_q: Optional[int] = None,
         from distributeddeeplearning_tpu.parallel import sharding as _layout
         from distributeddeeplearning_tpu.parallel.compat import shard_map
 
-        qkv_spec, mask_spec = _layout.tp_attention_specs()
+        qkv_spec, mask_spec = _layout.tp_attention_specs(q.shape, mesh)
         if mask is None:
             # keep mask=None through the shard_map so the kernels compile
             # with has_bias=False — fabricating an all-ones mask here would

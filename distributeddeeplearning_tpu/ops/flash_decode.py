@@ -7,9 +7,10 @@ attention consuming a block-table-gathered, fully *dequantized* f32
 history.  This module makes the attention read quantized bytes all the way
 into the tile:
 
-- **Pallas kernel** (:func:`_pallas_attention`): grid ``(slots, heads,
-  history_blocks)`` with the history dimension sequential — an
-  online-softmax split-K over the slot's pages.  Block tables ride as
+- **Pallas kernel** (:func:`_pallas_attention`): grid ``(slots,
+  history_blocks)`` with the history dimension sequential and every head
+  of the page handled per step — an online-softmax split-K over the
+  slot's pages.  Block tables ride as
   scalar prefetch (``pltpu.PrefetchScalarGridSpec``) so each K/V tile's
   ``BlockSpec`` index_map resolves ``logical page j -> physical page
   tables[b, j]`` and the pages stream HBM→VMEM **directly** — the gathered
@@ -17,7 +18,8 @@ into the tile:
   dequantize *inside the tile*: ``kf = k_int8 · scale[pos, head]`` at
   ``[page_size, hd]`` granularity, so f32 history never exists in HBM at
   all.  Runs in interpret mode off-TPU (same pattern as
-  ``ops.flash_attention``), which is how tier-1 pins its math on CPU.
+  ``ops.flash_attention``), which is how tier-1 pins its math on CPU;
+  ``tests/test_tpu_lowering.py`` pins that every form lowers for TPU.
 
 - **Fused-XLA twin** (the ``_xla_*`` paths): the same read discipline
   expressed in XLA for backends where interpret-mode Pallas would be an
@@ -40,8 +42,10 @@ into the tile:
 
 Kernel selection (:func:`resolve_kernel`): ``"auto"`` → ``"flash"``;
 ``"flash"`` runs the Pallas kernel on TPU and the fused-XLA twin
-elsewhere (or when the shapes don't tile); ``"gather"`` forces the legacy
-path.  ``"pallas"``/``"xla"`` pin one flash implementation for tests.
+elsewhere — the platform alone decides (:func:`flash_impl`, which the
+engines put in their reports), never the shape; ``"gather"`` forces the
+legacy path.  ``"pallas"``/``"xla"`` pin one flash implementation for
+tests.
 
 Exact-current-token semantics are preserved: the int8 *decode* paths
 overlay the in-flight token's exact f32 K/V (storage is quantized, the
@@ -55,25 +59,17 @@ upstream, so its flash path is the bitwise-identical f32 form.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from distributeddeeplearning_tpu.parallel import sharding as _layout
 
-try:  # TPU-specific pallas extras are absent on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
 NEG_BIG = -1e30  # finite mask fill, matching the gather reference
-
-#: Pallas history blocks below this run a pathological grid on TPU; the
-#: flash dispatch falls back to the fused-XLA twin instead (page_size
-#: already bounds the tile, so this only bites hand-picked tiny pages).
-PALLAS_BLOCK_FLOOR = 8
 
 KERNELS = ("auto", "flash", "gather", "pallas", "xla")
 
@@ -93,13 +89,16 @@ def resolve_kernel(kernel: str) -> str:
     return "flash" if kernel == "auto" else kernel
 
 
-def _flash_impl(kernel: str) -> str:
-    """Which flash implementation a resolved kernel runs HERE: the Pallas
-    kernel on TPU, the fused-XLA twin elsewhere; explicit ``pallas``/
-    ``xla`` force one (tests; the Pallas path interprets off-TPU)."""
-    if kernel in ("pallas", "xla"):
-        return kernel
-    return "pallas" if not _use_interpret() else "xla"
+def flash_impl(kernel: str) -> str:
+    """What a resolved kernel actually runs HERE — ``"pallas"``,
+    ``"xla"`` or ``"gather"``: ``flash`` is the Pallas kernel on TPU and
+    the fused-XLA twin elsewhere; explicit ``pallas``/``xla`` force one
+    (tests; the Pallas path interprets off-TPU).  The engines report
+    this, so a run that asked for the kernel and got the twin says so."""
+    kernel = resolve_kernel(kernel)
+    if kernel == "flash":
+        return "xla" if _use_interpret() else "pallas"
+    return kernel
 
 
 def _sqrt_dim(hd: int):
@@ -113,74 +112,101 @@ def _sqrt_dim(hd: int):
 # --------------------------------------------------------------------------
 
 
-def _kernel(tables_ref, posmat_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-            ko_ref, vo_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            block: int, hd: int, quantized: bool, overlay: bool):
-    """One (slot, head, history-block) grid step.
+def _kernel(tables_ref, maxpos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
+            ko_ref, vo_ref, pos_ref, o_ref, m_ref, l_ref, acc_ref, *,
+            block: int, num_heads: int, hd: int, quantized: bool,
+            overlay: bool):
+    """One (slot, history-block) grid step over ALL heads.
 
-    ``q_ref`` [1, nq, 1, hd]; ``k_ref``/``v_ref`` [1, block, 1, hd] — the
+    Every block covers its array's trailing dims whole — the Mosaic block
+    rule (last two block dims divisible by the dtype tile or equal to the
+    array dims) holds for any ``h``/``hd``, so ``hd=64 < 128`` lanes and
+    ``h=12`` need no padding:
+
+    ``q_ref``/``o_ref`` [1, h, nq, hd] (head-major, so a head is a
+    leading-dim index); ``k_ref``/``v_ref`` [1, block, h, hd] — the
     physical page the index_map resolved through the prefetched block
-    table; ``ks_ref``/``vs_ref`` [1, block, 1] per-(position, head)
-    scales (int8 pools); ``ko_ref``/``vo_ref`` [1, 1, hd] the slot's
-    exact in-flight token (decode overlay).  Scratch ``m``/``l``
-    [nq, 128] and ``acc`` [nq, hd] carry the online-softmax state across
-    the sequential history dimension.
+    table, one head read per loop step as a second-minor strided load;
+    ``ks_ref``/``vs_ref`` [1, block, h] per-(position, head) scales (int8
+    pools); ``ko_ref``/``vo_ref`` [1, h, hd] the slot's exact in-flight
+    token (decode overlay); ``pos_ref`` [1, nq, 1] the per-query
+    positions as a VMEM vector.  SMEM carries scalars only: the block
+    tables (index_map) and ``maxpos_ref`` [b], the block-skip bound.
+    Scratch ``m``/``l`` [h, nq, 128] and ``acc`` [h, nq, hd] carry the
+    online-softmax state across the sequential history dimension.
     """
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    posmat = posmat_ref[b]  # [nq] this slot's per-query positions (SMEM)
+    maxpos = maxpos_ref[b]  # scalar (SMEM): newest visible position
 
     # whole-block skip past the newest visible position: blocks beyond
     # max(posmat) contribute nothing (the split-K causal saving)
-    @pl.when(j * block <= jnp.max(posmat))
+    @pl.when(j * block <= maxpos)
     def _compute():
-        q = q_ref[0, :, 0, :]  # [nq, hd]
-        k = k_ref[0, :, 0, :]  # [block, hd] int8 | f32
-        v = v_ref[0, :, 0, :]
+        pos = pos_ref[0]  # [nq, 1] int32
+        nq = pos.shape[0]
         cols = j * block + jax.lax.broadcasted_iota(
-            jnp.int32, (block, 1), 0
-        )[:, 0]  # [block] logical positions of this tile
-        if quantized:
-            # in-tile dequant: one multiply per stored vector at
-            # [block, hd] granularity — f32 history never leaves VMEM
-            kf = k.astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-            vf = v.astype(jnp.float32) * vs_ref[0, :, 0][:, None]
-        else:
-            kf = k.astype(jnp.float32)
-            vf = v.astype(jnp.float32)
+            jnp.int32, (nq, block), 1
+        )  # logical positions of this tile, per query row
+        visible = cols <= pos  # [nq, block]
         if overlay:
             # decode's exact-current-token contract: the attended view
             # holds the in-flight f32 K/V at the slot's own position
-            own = (cols == posmat[0])[:, None]
-            kf = jnp.where(own, ko_ref[0, 0][None, :], kf)
-            vf = jnp.where(own, vo_ref[0, 0][None, :], vf)
-        s = jax.lax.dot_general(
-            q, kf, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) / _sqrt_dim(hd)  # [nq, block]
-        s = jnp.where(cols[None, :] <= posmat[:, None], s, NEG_BIG)
-        m_prev = m_ref[:, :1]
-        m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_cur)
-        corr = jnp.exp(m_prev - m_cur)
-        l_ref[:, :1] = l_ref[:, :1] * corr + p.sum(axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p, vf, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:, :1] = m_cur
+            # (nq == 1, so that position IS the skip bound)
+            own = (
+                j * block
+                + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+            ) == maxpos  # [block, 1]
+        for hh in range(num_heads):
+            q = q_ref[0, hh]  # [nq, hd]
+            kf = k_ref[0, :, hh, :].astype(jnp.float32)  # [block, hd]
+            vf = v_ref[0, :, hh, :].astype(jnp.float32)
+            if quantized:
+                # in-tile dequant: one multiply per stored vector at
+                # [block, hd] granularity — f32 history never leaves VMEM
+                kf = kf * ks_ref[0, :, hh:hh + 1]
+                vf = vf * vs_ref[0, :, hh:hh + 1]
+            if overlay:
+                kf = jnp.where(own, ko_ref[0, hh:hh + 1, :], kf)
+                vf = jnp.where(own, vo_ref[0, hh:hh + 1, :], vf)
+            s = jax.lax.dot_general(
+                q, kf, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) / math.sqrt(hd)  # [nq, block]
+            s = jnp.where(visible, s, NEG_BIG)
+            m_prev = m_ref[hh, :, :1]
+            m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_cur)
+            corr = jnp.exp(m_prev - m_cur)
+            l_ref[hh, :, :1] = (
+                l_ref[hh, :, :1] * corr + p.sum(axis=-1, keepdims=True)
+            )
+            acc_ref[hh] = acc_ref[hh] * corr + jax.lax.dot_general(
+                p, vf, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[hh, :, :1] = m_cur
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[:] / l).astype(o_ref.dtype)
+        for hh in range(num_heads):
+            l = jnp.maximum(l_ref[hh, :, :1], 1e-30)
+            o_ref[0, hh] = (acc_ref[hh] / l).astype(o_ref.dtype)
+
+
+def _kernel_name(nq: int, quantized: bool, overlay: bool) -> str:
+    """Stable ``pallas_call`` name per kernel form, so a trace reduction
+    finds the decode / chunk-prefill / verify kernels after a refactor."""
+    form = "decode" if nq == 1 else "multiquery"
+    pool = "int8" if quantized else "f32"
+    return f"flash_decode_{form}_{pool}" + ("_overlay" if overlay else "")
 
 
 def _pallas_attention(
@@ -200,162 +226,198 @@ def _pallas_attention(
     ``v_l`` [P, block, h, hd] addressed through ``tables`` [b, nb];
     ``posmat`` [b, nq] per-query visibility.  Returns [b, nq, h, hd] f32.
     """
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU support unavailable in this jax build")
     b, nq, h, hd = q4.shape
     nb = tables.shape[1]
     quantized = k_s is not None
     overlay = k_own is not None
     if overlay and nq != 1:
-        # the in-kernel own-position select reads posmat[0] — the
-        # single-token decode contract; a multi-query overlay would
-        # silently place every row's overlay at query 0's position
+        # the in-kernel own-position select reads the slot's single
+        # position — the single-token decode contract; a multi-query
+        # overlay would silently place every row's overlay at one position
         raise ValueError(
             "own-token overlay supports single-query decode only "
             f"(nq={nq})"
         )
     kern = functools.partial(
-        _kernel, block=block, hd=hd, quantized=quantized, overlay=overlay,
+        _kernel, block=block, num_heads=h, hd=hd, quantized=quantized,
+        overlay=overlay,
     )
     # unquantized/no-overlay variants still take the operand slots (one
     # kernel signature); size-1 dummies keep the BlockSpecs trivial
     dummy_s = jnp.zeros((1, 1, 1), jnp.float32)
     dummy_o = jnp.zeros((1, 1, hd), jnp.float32)
+    head_major = pl.BlockSpec(
+        (1, h, nq, hd), lambda bb, j, tbl, mp: (bb, 0, 0, 0)
+    )
     page_spec = pl.BlockSpec(
-        (1, block, 1, hd), lambda bb, hh, j, tbl, pm: (tbl[bb, j], 0, hh, 0)
+        (1, block, h, hd), lambda bb, j, tbl, mp: (tbl[bb, j], 0, 0, 0)
     )
     if quantized:
         scale_spec = pl.BlockSpec(
-            (1, block, 1), lambda bb, hh, j, tbl, pm: (tbl[bb, j], 0, hh)
+            (1, block, h), lambda bb, j, tbl, mp: (tbl[bb, j], 0, 0)
         )
     else:
         scale_spec = pl.BlockSpec(
-            (1, 1, 1), lambda bb, hh, j, tbl, pm: (0, 0, 0)
+            (1, 1, 1), lambda bb, j, tbl, mp: (0, 0, 0)
         )
     if overlay:
         own_spec = pl.BlockSpec(
-            (1, 1, hd), lambda bb, hh, j, tbl, pm: (bb, hh, 0)
+            (1, h, hd), lambda bb, j, tbl, mp: (bb, 0, 0)
         )
     else:
         own_spec = pl.BlockSpec(
-            (1, 1, hd), lambda bb, hh, j, tbl, pm: (0, 0, 0)
+            (1, 1, hd), lambda bb, j, tbl, mp: (0, 0, 0)
         )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # tables + posmat land in SMEM up front
-        grid=(b, h, nb),
+        num_scalar_prefetch=2,  # tables + skip bound land in SMEM up front
+        grid=(b, nb),
         in_specs=[
-            pl.BlockSpec(
-                (1, nq, 1, hd), lambda bb, hh, j, tbl, pm: (bb, 0, hh, 0)
-            ),
+            head_major,
             page_spec,
             page_spec,
             scale_spec,
             scale_spec,
             own_spec,
             own_spec,
+            pl.BlockSpec((1, nq, 1), lambda bb, j, tbl, mp: (bb, 0, 0)),
         ],
-        out_specs=pl.BlockSpec(
-            (1, nq, 1, hd), lambda bb, hh, j, tbl, pm: (bb, 0, hh, 0)
-        ),
+        out_specs=head_major,
         scratch_shapes=[
-            pltpu.VMEM((nq, 128), jnp.float32),
-            pltpu.VMEM((nq, 128), jnp.float32),
-            pltpu.VMEM((nq, hd), jnp.float32),
+            pltpu.VMEM((h, nq, 128), jnp.float32),
+            pltpu.VMEM((h, nq, 128), jnp.float32),
+            pltpu.VMEM((h, nq, hd), jnp.float32),
         ],
     )
     compiler_params = None
     if not _use_interpret():
         compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+            dimension_semantics=("parallel", "arbitrary")
         )
-    return pl.pallas_call(
+    posmat = posmat.astype(jnp.int32)
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nq, h, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, h, nq, hd), jnp.float32),
         compiler_params=compiler_params,
         interpret=_use_interpret(),
+        name=_kernel_name(nq, quantized, overlay),
     )(
         tables,
-        posmat,
-        q4,
+        posmat.max(axis=1),
+        jnp.swapaxes(q4, 1, 2),
         k_l,
         v_l,
         k_s if quantized else dummy_s,
         v_s if quantized else dummy_s,
         k_own if overlay else dummy_o,
         v_own if overlay else dummy_o,
+        posmat[:, :, None],
     )
+    return jnp.swapaxes(out, 1, 2)
 
 
-def attention_partition_specs(operands, *, mesh):
-    """PartitionSpecs for the Pallas kernel's operands under a tensor-
-    parallel mesh, resolved through the partition-rule layout table (the
-    ``attn/`` rules in ``parallel.sharding.LAYOUT_RULES``) — the kernel's
-    block-spec partitioning never hand-wires a mesh axis.  ``operands``:
-    name → array (None entries are absent kernel slots and are skipped).
-    Returns ``(names, in_specs, out_spec)``; size-1 dummy operands
-    replicate via the table's divisibility drop."""
+def attention_partition_specs(operands, *, mesh, namespace: str = "attn"):
+    """PartitionSpecs for the Pallas kernel's operands under a mesh,
+    resolved through the partition-rule layout table (the ``attn/`` and
+    ``attn_dense/`` rules in ``parallel.sharding.LAYOUT_RULES``) — the
+    kernel's block-spec partitioning never hand-wires a mesh axis.
+    ``operands``: name → array (None entries are absent kernel slots and
+    are skipped).  Returns ``(names, in_specs, out_spec)``; size-1 dummy
+    operands replicate via the table's divisibility drop."""
     names = [k for k, v in operands.items() if v is not None]
     in_specs = tuple(
         _layout.spec_for(
-            f"attn/{k}", shape=tuple(operands[k].shape), mesh=mesh
+            f"{namespace}/{k}", shape=tuple(operands[k].shape), mesh=mesh
         )
         for k in names
     )
     out_spec = _layout.spec_for(
-        "attn/out", shape=tuple(operands["q"].shape), mesh=mesh
+        f"{namespace}/out", shape=tuple(operands["q"].shape), mesh=mesh
     )
     return names, in_specs, out_spec
 
 
-def _pallas_tp(mesh, q4, k_l, v_l, k_s, v_s, tables, posmat, *, block,
-               k_own=None, v_own=None):
-    """Dispatch the Pallas kernel, shard_mapped over the ``tensor`` mesh
-    axis when one is active: each chip runs the kernel over its LOCAL
-    heads (the grid's head axis shrinks to h/tp; heads are independent in
-    attention, so no collective is needed), with operand partitioning
-    resolved through the same layout table the engines use — paged int8
-    decode works under TP without a second sharding scheme."""
-    if _layout.tensor_parallel_size(mesh) <= 1:
-        return _pallas_attention(
-            q4, k_l, v_l, k_s, v_s, tables, posmat, block=block,
-            k_own=k_own, v_own=v_own,
-        )
+def _per_shard(mesh, operands, run, *, namespace: str):
+    """Call ``run(**operands)`` per shard: a bare ``pallas_call`` cannot be
+    partitioned by GSPMD (it would gather the global operands onto every
+    chip or be refused), so under a multi-device mesh the kernel runs
+    inside ``shard_map`` with operand partitioning resolved through the
+    layout table — heads over ``tensor`` (independent in attention, so no
+    collective), dense-layout slots over the data axes."""
+    if mesh is None or mesh.devices.size == 1:
+        return run(**operands)
     from distributeddeeplearning_tpu.parallel.compat import shard_map
 
-    operands = {
+    names, in_specs, out_spec = attention_partition_specs(
+        operands, mesh=mesh, namespace=namespace
+    )
+    absent = {k: None for k in operands if k not in names}
+    return shard_map(
+        lambda *present: run(**dict(zip(names, present)), **absent),
+        mesh=mesh, in_specs=in_specs, out_specs=out_spec,
+    )(*(operands[k] for k in names))
+
+
+def _pallas_paged(mesh, q4, k_l, v_l, k_s, v_s, tables, posmat, *, block,
+                  k_own=None, v_own=None):
+    """The kernel over pool pages, per shard under a mesh: each chip runs
+    its LOCAL heads (the paged pool never shards its page axis, so page
+    addressing stays chip-local by construction)."""
+
+    def run(q, k_pages, v_pages, k_scale, v_scale, tables, posmat, k_own,
+            v_own):
+        return _pallas_attention(
+            q, k_pages, v_pages, k_scale, v_scale, tables, posmat,
+            block=block, k_own=k_own, v_own=v_own,
+        )
+
+    return _per_shard(mesh, {
         "q": q4, "k_pages": k_l, "v_pages": v_l,
         "k_scale": k_s, "v_scale": v_s,
         "tables": tables, "posmat": posmat,
         "k_own": k_own, "v_own": v_own,
-    }
-    names, in_specs, out_spec = attention_partition_specs(
-        operands, mesh=mesh
-    )
+    }, run, namespace="attn")
 
-    def run(*present):
-        vals = dict(zip(names, present))
+
+def _pallas_dense(mesh, q4, k_l, v_l, k_s, v_s, posmat, *, k_own=None,
+                  v_own=None):
+    """The kernel over the dense [B, S, h, hd] layout, per shard under a
+    mesh: each chip views ITS slots' rows as synthetic pages (the identity
+    block tables are built per shard, so they index local rows)."""
+    block = dense_block(k_l.shape[1])
+
+    def run(q, k_rows, v_rows, k_scale, v_scale, posmat, k_own, v_own):
+        kp, vp, ksp, vsp, tables = _dense_as_pages(
+            k_rows, v_rows, k_scale, v_scale, block
+        )
         return _pallas_attention(
-            vals["q"], vals["k_pages"], vals["v_pages"],
-            vals.get("k_scale"), vals.get("v_scale"),
-            vals["tables"], vals["posmat"], block=block,
-            k_own=vals.get("k_own"), v_own=vals.get("v_own"),
+            q, kp, vp, ksp, vsp, tables, posmat, block=block,
+            k_own=k_own, v_own=v_own,
         )
 
-    return shard_map(
-        run, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
-    )(*(operands[k] for k in names))
+    return _per_shard(mesh, {
+        "q": q4, "k_rows": k_l, "v_rows": v_l,
+        "k_scale": k_s, "v_scale": v_s, "posmat": posmat,
+        "k_own": k_own, "v_own": v_own,
+    }, run, namespace="attn_dense")
 
 
-def _dense_block(s: int, cap: int = 128) -> int:
-    """Largest power-of-two-descending divisor of ``s`` up to ``cap`` —
-    the synthetic "page size" the dense layout tiles its [B, S] rows into
-    for the kernel (below :data:`PALLAS_BLOCK_FLOOR` the dispatch takes
-    the XLA twin instead of running a pathological grid)."""
-    b = min(cap, s)
-    while s % b:
-        b //= 2
-    return b
+def dense_block(s: int, cap: int = 128) -> int:
+    """The synthetic "page size" the dense layout tiles its [B, S] rows
+    into for the kernel: ``s`` itself up to ``cap``, else the largest
+    sublane-aligned (multiple of 8) divisor of ``s`` up to ``cap``.  A
+    length with no such divisor would run a pathological grid, and is
+    refused here rather than rerouted."""
+    if s <= cap:
+        return s
+    for b in range(cap, 7, -8):
+        if s % b == 0:
+            return b
+    raise ValueError(
+        f"dense cache length {s} has no multiple-of-8 divisor up to {cap} "
+        "for the flash-decode kernel to tile it by — pick a max_seq that "
+        "is a multiple of 8 (128 tiles best), or the paged layout"
+    )
 
 
 def _dense_as_pages(k_l, v_l, k_s, v_s, block: int):
@@ -432,11 +494,10 @@ def decode_attention_paged(
     b, num_heads, hd = q3.shape
     nb = block_tables.shape[1]
     s = nb * page_size
-    kernel = resolve_kernel(kernel)
-    if kernel in ("flash", "pallas", "xla"):
-        impl = _flash_impl(kernel)
-        if impl == "pallas" and page_size >= PALLAS_BLOCK_FLOOR:
-            out = _pallas_tp(
+    impl = flash_impl(kernel)
+    if impl != "gather":
+        if impl == "pallas":
+            out = _pallas_paged(
                 mesh, q3[:, None], k_l, v_l, k_s, v_s, block_tables,
                 pos[:, None], block=page_size,
                 k_own=k_t if k_s is not None else None,
@@ -514,17 +575,11 @@ def decode_attention_dense(
     (same contract as :func:`decode_attention_paged`, no indirection)."""
     b, num_heads, hd = q3.shape
     s = k_l.shape[1]
-    kernel = resolve_kernel(kernel)
-    if kernel in ("flash", "pallas", "xla"):
-        impl = _flash_impl(kernel)
-        block = _dense_block(s)
-        if impl == "pallas" and block >= PALLAS_BLOCK_FLOOR:
-            kp, vp, ksp, vsp, tables = _dense_as_pages(
-                k_l, v_l, k_s, v_s, block
-            )
-            out = _pallas_tp(
-                mesh, q3[:, None], kp, vp, ksp, vsp, tables, pos[:, None],
-                block=block,
+    impl = flash_impl(kernel)
+    if impl != "gather":
+        if impl == "pallas":
+            out = _pallas_dense(
+                mesh, q3[:, None], k_l, v_l, k_s, v_s, pos[:, None],
                 k_own=k_t if k_s is not None else None,
                 v_own=v_t if k_s is not None else None,
             )
@@ -575,11 +630,10 @@ def chunk_attention(
     C, num_heads, hd = q_c.shape
     nb = block_table.shape[0]
     s = nb * page_size
-    kernel = resolve_kernel(kernel)
-    if kernel in ("flash", "pallas", "xla"):
-        impl = _flash_impl(kernel)
-        if impl == "pallas" and page_size >= PALLAS_BLOCK_FLOOR:
-            out = _pallas_tp(
+    impl = flash_impl(kernel)
+    if impl != "gather":
+        if impl == "pallas":
+            out = _pallas_paged(
                 mesh, q_c[None], k_l, v_l, k_s, v_s, block_table[None],
                 posns[None], block=page_size,
             )
@@ -641,16 +695,11 @@ def verify_attention_paged(
     through unchanged; on TPU the Pallas kernel streams the same pages
     the decode step does.  Returns ctx [b, K1, h, hd]."""
     b, K1, num_heads, hd = q4.shape
-    kernel = resolve_kernel(kernel)
-    if kernel in ("flash", "pallas", "xla"):
-        if (
-            _flash_impl(kernel) == "pallas"
-            and page_size >= PALLAS_BLOCK_FLOOR
-        ):
-            return _pallas_tp(
-                mesh, q4, k_l, v_l, None, None, block_tables, posmat,
-                block=page_size,
-            )
+    if flash_impl(kernel) == "pallas":
+        return _pallas_paged(
+            mesh, q4, k_l, v_l, None, None, block_tables, posmat,
+            block=page_size,
+        )
     nb = block_tables.shape[1]
     s = nb * page_size
     k_seq = k_l[block_tables].reshape(b, s, num_heads, hd)
@@ -662,19 +711,9 @@ def verify_attention_dense(q4, k_l, v_l, posmat, *, kernel: str = "gather",
                            mesh=None):
     """Speculative-verify attention over the dense cache ``k_l``/``v_l``
     [b, S, h, hd] (f32 only, see :func:`verify_attention_paged`)."""
-    b, K1, num_heads, hd = q4.shape
-    s = k_l.shape[1]
-    kernel = resolve_kernel(kernel)
-    if kernel in ("flash", "pallas", "xla"):
-        block = _dense_block(s)
-        if _flash_impl(kernel) == "pallas" and block >= PALLAS_BLOCK_FLOOR:
-            kp, vp, _, _, tables = _dense_as_pages(
-                k_l, v_l, None, None, block
-            )
-            return _pallas_tp(
-                mesh, q4, kp, vp, None, None, tables, posmat, block=block
-            )
-    return _verify_dense_math(q4, k_l, v_l, posmat, hd)
+    if flash_impl(kernel) == "pallas":
+        return _pallas_dense(mesh, q4, k_l, v_l, None, None, posmat)
+    return _verify_dense_math(q4, k_l, v_l, posmat, q4.shape[-1])
 
 
 def _verify_dense_math(q4, k_seq, v_seq, posmat, hd):

@@ -1,32 +1,16 @@
-"""JAX API-drift shims shared by the ops layer.
-
-``shard_map`` moved from ``jax.experimental.shard_map`` to ``jax.shard_map``
-and renamed ``check_rep`` → ``check_vma`` around jax 0.8.  Every ops module
-needs the same wrapper; keep ONE copy here so the next drift is a one-line
-fix.
-"""
+"""The one ``shard_map`` spelling the ops layer uses."""
 
 from __future__ import annotations
 
+import jax
+
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable ``shard_map``.
-
-    ``check_vma=False`` by default: pallas calls and masked-psum patterns
-    inside our kernels cannot annotate varying-mesh-axes metadata, and the
-    ops' own tests pin correctness against unsharded references instead.
-    """
-    try:
-        from jax import shard_map as _shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as _shard_map
-    try:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    except TypeError:  # pragma: no cover - jax<0.8 spells it check_rep
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
+    """``jax.shard_map`` with ``check_vma=False`` by default: pallas calls
+    and masked-psum patterns inside our kernels cannot annotate
+    varying-mesh-axes metadata, and the ops' own tests pin correctness
+    against unsharded references instead."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
+    )

@@ -23,15 +23,12 @@ DP over N — exactly the reference's semantics (Horovod world = all GPUs).
 from __future__ import annotations
 
 import dataclasses
-import logging
 import math
 from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
-
-logger = logging.getLogger("ddlt.mesh")
 
 # Canonical axis order: outermost (slowest-varying, crosses DCN first) to
 # innermost (fastest-varying, stays on ICI).  Data-parallel gradients tolerate
@@ -120,8 +117,8 @@ def create_mesh(
     Replaces Horovod's implicit world: the reference gets its communicator
     from ``hvd.init()`` (``resnet_main.py:232``); here the mesh *is* the
     communicator, and every collective in the train step is expressed against
-    its named axes.  ``jax.experimental.mesh_utils`` is used when available so
-    the device order respects physical TPU topology (ICI neighbours stay
+    its named axes.  On TPU ``jax.experimental.mesh_utils`` orders the devices
+    so that the mesh respects the physical topology (ICI neighbours stay
     mesh-adjacent).
 
     ``num_slices > 1`` builds a **multi-slice (DCN) mesh**: the ``data``
@@ -159,20 +156,14 @@ def create_mesh(
         dev_array = np.concatenate(sub, axis=data_pos)
         return Mesh(dev_array, AXIS_ORDER)
     if all(d.platform == "tpu" for d in devices):
-        try:
-            from jax.experimental import mesh_utils
+        # topology-aware or not at all: an enumeration-order layout would
+        # run, with collectives off their ICI neighbours and nothing in
+        # any report to say so — a shape mesh_utils cannot place raises
+        from jax.experimental import mesh_utils
 
-            dev_array = mesh_utils.create_device_mesh(
-                sizes, devices=devices, allow_split_physical_axes=True
-            )
-        except Exception as exc:  # topology mismatch / API drift
-            logger.warning(
-                "mesh_utils.create_device_mesh failed (%s); falling back to "
-                "enumeration-order device layout — collectives may not be "
-                "ICI-adjacent",
-                exc,
-            )
-            dev_array = np.asarray(devices).reshape(sizes)
+        dev_array = mesh_utils.create_device_mesh(
+            sizes, devices=devices, allow_split_physical_axes=True
+        )
     else:
         # CPU/GPU fakes have no ICI topology; plain reshape is exact.
         dev_array = np.asarray(devices).reshape(sizes)

@@ -71,8 +71,7 @@ def shard_batch(mesh: Mesh, batch: PyTree) -> PyTree:
         isinstance(x, jax.Array) and x.sharding == sharding for x in leaves
     ):
         # Already placed (e.g. a device-resident benchmark batch): skip the
-        # no-op device_put — its dispatch is not free, especially on
-        # remote/tunneled backends.
+        # no-op device_put — its dispatch is not free.
         return batch
     if jax.process_count() == 1:
         return jax.device_put(batch, sharding)
@@ -214,7 +213,7 @@ LAYOUT_RULES: LayoutRules = (
     (r"^io/(tokens?|pos|slots?|lengths?|step)$", (DATA_AXES,)),
     (r"^io/(block_tables?|page_ids|k|v|from_(pos|offs)|offsets?|draft_len)$", ()),
     # -- flash-decode kernel operands (``attn/`` namespace): the Pallas
-    # path shard_maps over ``tensor`` so each chip's kernel instance runs
+    # path shard_maps over the mesh so each chip's kernel instance runs
     # its LOCAL heads — q/pages/out head dim over tensor, scale leaves
     # likewise, block tables and position matrices replicated (page
     # addressing is chip-local by construction).
@@ -222,6 +221,13 @@ LAYOUT_RULES: LayoutRules = (
     (r"^attn/(k|v)_scale$", (None, None, "tensor")),
     (r"^attn/(k|v)_own$", (None, "tensor", None)),
     (r"^attn/(tables|posmat)$", ()),
+    # the same kernel over the DENSE layout ([slots, S, h, hd] rows viewed
+    # as per-shard synthetic pages): slots ride the data axes like the
+    # cache they come from, heads over tensor.
+    (r"^attn_dense/(q|out|(k|v)_rows)$", (DATA_AXES, None, "tensor", None)),
+    (r"^attn_dense/(k|v)_scale$", (DATA_AXES, None, "tensor")),
+    (r"^attn_dense/(k|v)_own$", (DATA_AXES, "tensor", None)),
+    (r"^attn_dense/posmat$", (DATA_AXES,)),
     # -- serve-path transformer weights (stacked [L, ...]; Megatron TP) ----
     # column-parallel (output width over tensor): qkv, w_in.  QTensor
     # scale leaves (axis=-2 keepdims) keep the same rank, so one rule
@@ -497,12 +503,22 @@ def seq_parallel_specs(axis_name: str) -> Tuple[P, P]:
     )
 
 
-def tp_attention_specs() -> Tuple[P, P]:
+def tp_attention_specs(
+    shape: Optional[Tuple[int, ...]] = None, mesh: Optional[Mesh] = None
+) -> Tuple[P, P]:
     """(qkv_spec, mask_spec) for head-sharded attention ([B, S, H, D]
-    layout): heads over ``tensor``, mask replicated across heads."""
+    layout): batch over the data axes, heads over ``tensor``, mask
+    replicated across heads.  With the operand ``shape`` and the ``mesh``
+    an axis that does not divide its dim replicates instead — a one-
+    sequence serving prefill under a data mesh runs whole on every chip."""
+    mask_shape = None if shape is None else (shape[0], 1, 1, shape[1])
     return (
-        P(DATA_AXES, None, "tensor", None),
-        P(DATA_AXES, None, None, None),
+        _spec_from_entries(
+            (DATA_AXES, None, "tensor", None), shape=shape, mesh=mesh
+        ),
+        _spec_from_entries(
+            (DATA_AXES, None, None, None), shape=mask_shape, mesh=mesh
+        ),
     )
 
 

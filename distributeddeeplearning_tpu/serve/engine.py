@@ -50,7 +50,12 @@ from distributeddeeplearning_tpu.models.pipelined_transformer import (
     forward_prefill,
     forward_prefill_chunk,
 )
-from distributeddeeplearning_tpu.ops.flash_decode import resolve_kernel
+from distributeddeeplearning_tpu.ops.flash_attention import auto_block_tiles
+from distributeddeeplearning_tpu.ops.flash_decode import (
+    dense_block,
+    flash_impl,
+    resolve_kernel,
+)
 from distributeddeeplearning_tpu.parallel import sharding as layout
 from distributeddeeplearning_tpu.parallel.mesh import data_parallel_size
 from distributeddeeplearning_tpu.quant.calibrate import params_dtype
@@ -316,9 +321,10 @@ class InferenceEngine:
     (``can_admit`` / ``release`` / ``prefill_compiles``).
 
     ``prefill_attention="flash"`` (default) runs the prompt pass through
-    the Pallas kernel; tiny prompts fall back to dense inside
-    ``ops.flash_attention`` (the auto-block floor).  Decode is always
-    dense against the cache — there is no S² term to flash away.
+    the Pallas kernel, one compiled program per prompt bucket; the top
+    bucket is ``max_seq`` itself, so ``max_seq`` must be a length the
+    kernel's auto-selected blocks tile (up to 1024, or a multiple of
+    128) — refused at construction otherwise, never rerouted to dense.
     """
 
     def __init__(
@@ -339,11 +345,23 @@ class InferenceEngine:
     ):
         self.kv_layout = "dense"
         self.chunked_prefill = False
+        self.prefill_attention = prefill_attention
         # "flash" = ops.flash_decode (Pallas kernel on TPU; fused-XLA
         # twin elsewhere, where it is bitwise == gather for f32 caches);
         # "gather" = the legacy dense cache read.  Resolved once so the
-        # compiled programs and the provenance the reports carry agree.
+        # compiled programs and the provenance the reports carry agree;
+        # decode_impl is what actually runs HERE (pallas | xla | gather).
         self.decode_kernel = resolve_kernel(decode_kernel)
+        self.decode_impl = flash_impl(self.decode_kernel)
+        if self.decode_impl == "pallas":
+            dense_block(max_seq)  # raises on a length the kernel can't tile
+        if prefill_attention == "flash" and not auto_block_tiles(max_seq):
+            raise ValueError(
+                f"max_seq {max_seq} is not a length the flash prefill "
+                "kernel tiles (the top prompt bucket is max_seq itself): "
+                "use max_seq <= 1024 or a multiple of 128, or "
+                "prefill_attention='dense'"
+            )
         # distinct compiled prefill shapes (each new power-of-two bucket
         # is a mid-run jit recompile — ServeReport surfaces the count so
         # benchmark warmup can prove it drove them all to 0)
@@ -410,9 +428,16 @@ class InferenceEngine:
             self._cache = jax.device_put(self._cache, c_shard)
             # prefill's emitted K/V carry the cache head sharding (same
             # kv_dense rules — [1, L, P, h, hd] rides the 5-dim entry
-            # list), so insert never pays a resharding copy
+            # list), so insert never pays a resharding copy.  Resolved
+            # WITH the shape: the one-sequence leading dim cannot split
+            # over the data axes, and the table's divisibility drop
+            # replicates it (the bucket dim P is never sharded)
+            seed = jax.ShapeDtypeStruct(
+                (1, num_layers, max_seq, num_heads, head_dim),
+                self._cache["k"].dtype,
+            )
             kv_seed = layout.resolve_shardings(
-                mesh, {"k": None, "v": None}, prefix="kv_dense"
+                mesh, {"k": seed, "v": seed}, prefix="kv_dense"
             )
             decode_in = (p_shard, c_shard, slot_vec, slot_vec, scalar)
             decode_out = (rep, rep, c_shard)  # tokens, finite, cache
@@ -438,10 +463,25 @@ class InferenceEngine:
                 top_k=top_k,
             )
 
+        prefill_attention_fn = None
+        if sharded and prefill_attention == "flash":
+            # a bare pallas_call cannot be partitioned (the TPU compiler
+            # refuses it under a multi-device jit): the kernel runs per
+            # shard — local heads under TP, the one prompt whole on every
+            # chip of a data mesh
+            from distributeddeeplearning_tpu.ops.flash_attention import (
+                make_flash_attention,
+            )
+
+            prefill_attention_fn = make_flash_attention(
+                mesh=mesh, causal=True
+            )
+
         def _prefill_fn(params, tokens, length):
             logits, k, v = forward_prefill(
                 params, tokens, num_heads=num_heads,
                 attention=prefill_attention,
+                attention_fn=prefill_attention_fn,
             )
             last = jax.lax.dynamic_index_in_dim(
                 logits, length - 1, axis=1, keepdims=False
@@ -507,6 +547,17 @@ class InferenceEngine:
     @property
     def cache(self):
         return self._cache
+
+    def kernel_programs(self):
+        """The jitted programs that asked for a Pallas kernel (flash
+        prefill, flash decode) — what ``obs.attrib.mosaic_call_counts``
+        inspects to prove the kernel is in them."""
+        progs = []
+        if self.prefill_attention == "flash":
+            progs.append(self._prefill_jit)
+        if self.decode_kernel != "gather":
+            progs.append(self._decode_jit)
+        return progs
 
     def kv_bytes(self) -> int:
         """Total KV pool bytes (the HBM the layout RESERVES)."""
@@ -723,6 +774,7 @@ class PagedInferenceEngine:
         # ops.flash_decode (in-tile int8 dequant — the QUANT_r15 speed
         # lever), "gather" is the legacy block-table-gather read
         self.decode_kernel = resolve_kernel(decode_kernel)
+        self.decode_impl = flash_impl(self.decode_kernel)
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         if prefill_chunk < 1:
@@ -939,6 +991,12 @@ class PagedInferenceEngine:
     @property
     def block_tables(self) -> np.ndarray:
         return self._block_tables
+
+    def kernel_programs(self):
+        """See :meth:`InferenceEngine.kernel_programs`."""
+        if self.decode_kernel == "gather":
+            return []
+        return [self._chunk_jit, self._decode_jit]
 
     def kv_bytes(self) -> int:
         return cache_bytes(self._cache)

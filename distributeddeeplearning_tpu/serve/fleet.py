@@ -70,6 +70,7 @@ import queue as queue_mod
 import signal
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from unittest import mock
 
 from distributeddeeplearning_tpu.obs.fleet import (
     fleet_latency,
@@ -286,9 +287,62 @@ class FleetReport:
     fleet_latency_per_class: Dict[str, Any] = dataclasses.field(
         default_factory=dict
     )
+    # ``{"platform", "kind", "count"}`` as the WORKERS report it in their
+    # ready handshake (count is per worker: one chip each on a TPU host,
+    # the whole virtual pod on the CPU) — empty when no worker came up
+    device: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
+
+
+# -- device placement --------------------------------------------------------
+
+
+class ReplicaPlacementError(ValueError):
+    """More replicas were asked for than the host has chips to give one
+    each — entry points turn this into a one-line refusal."""
+
+
+def replica_device_envs(replicas: int) -> List[Dict[str, str]]:
+    """The environment overlay that gives each of ``replicas`` workers
+    exactly ONE chip: a chip belongs to one process at a time, and a
+    worker left to its defaults takes every chip on the host, so the
+    second worker fails or hangs.  Worker ``i`` is spawned with chip
+    ``i`` visible and a process topology of one.
+
+    The router must hold no chip itself, so it asks a throwaway child
+    what the host has (``utils.hardware.probe_devices``) — skipped when
+    the environment already pins the CPU platform, where workers share
+    the host and need no placement.  Raises
+    :class:`ReplicaPlacementError` when the replicas outnumber the
+    visible chips."""
+    from distributeddeeplearning_tpu.utils.hardware import probe_devices
+    from distributeddeeplearning_tpu.utils.virtual_pod import (
+        cpu_platform_pinned,
+    )
+
+    if cpu_platform_pinned():
+        return [{} for _ in range(replicas)]
+    device = probe_devices()
+    if device["platform"] != "tpu":
+        return [{} for _ in range(replicas)]
+    if replicas > device["count"]:
+        raise ReplicaPlacementError(
+            f"{replicas} replicas exceed the {device['count']} visible "
+            f"chip(s) ({device['kind']}): each replica worker owns one chip"
+        )
+    return [
+        {
+            "TPU_VISIBLE_DEVICES": str(i),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            # one mesh controller per worker, or they collide on the port
+            "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{8476 + i}",
+            "TPU_MESH_CONTROLLER_PORT": str(8476 + i),
+        }
+        for i in range(replicas)
+    ]
 
 
 # -- worker side -----------------------------------------------------------
@@ -312,7 +366,7 @@ def _build_engine(spec: ReplicaSpec):
     # the persistent cache turns both into loads.  Restart latency is
     # recovery overhead, so this is a resilience knob, not a nicety;
     # floor 0 so even sub-second CPU-smoke programs hit on restart.
-    enable_compilation_cache(0)
+    enable_compilation_cache(min_compile_time_secs=0)
 
     if spec.checkpoint_dir:
         from distributeddeeplearning_tpu.train.checkpoint import Checkpointer
@@ -546,10 +600,15 @@ def _worker_main(
     # epoch (wall clock) + send time; the router turns that into a
     # per-worker clock-offset estimate for the shard merge (send->receive
     # delay bounds the estimate's error)
+    from distributeddeeplearning_tpu.utils.hardware import device_summary
+
     outbox.put(("ready", replica_id, {
         "pid": os.getpid(),
         "epoch_unix_s": tracer.epoch_unix_s,
         "sent_unix_s": time.time(),
+        # the device this worker actually serves on — the router never
+        # touches a backend, so the fleet report names its device by this
+        "device": device_summary(),
     }))
 
     closed = False
@@ -837,6 +896,10 @@ class FleetRouter:
             else os.environ.get(faults_mod.ENV_VAR, "")
         )
         self._dealt = faults_mod.deal_serve_faults(faults_text, replicas)
+        # one chip per worker (raises when replicas outnumber chips); a
+        # restarted replica inherits its predecessor's chip
+        self._device_envs = replica_device_envs(replicas)
+        self.device: Dict[str, Any] = {}
         # spawn context: workers must re-import jax fresh — a fork would
         # clone a parent whose XLA runtime threads are mid-flight
         self._ctx = mp.get_context("spawn")
@@ -885,7 +948,11 @@ class FleetRouter:
             name=f"ddlt-serve-replica-{index}",
             daemon=True,
         )
-        proc.start()
+        # a spawned child copies os.environ at start(): the overlay is the
+        # environment the worker is spawned with, in place before libtpu
+        # loads, and gone again after.  Spawns happen on the router thread.
+        with mock.patch.dict(os.environ, self._device_envs[index]):
+            proc.start()
         get_tracer().event(
             "fleet/replica_spawned", cat="fleet", replica=index,
             pid=proc.pid, faults=faults_spec,
@@ -1088,6 +1155,8 @@ class FleetRouter:
         elif kind == "ready":
             if member is not None:
                 member.ready = True  # a worker coming up mid-pump counts
+                if isinstance(msg[2], dict) and msg[2].get("device"):
+                    self.device = msg[2]["device"]
         elif kind != "hb":
             self._stashed_msgs.append(msg)
 
@@ -1435,6 +1504,8 @@ class FleetRouter:
             elif kind == "ready" and member is not None:
                 member.ready = True
                 hs = msg[2]
+                if isinstance(hs, dict) and hs.get("device"):
+                    self.device = hs["device"]
                 if isinstance(hs, dict) and "epoch_unix_s" in hs:
                     # clock handshake: worker tracer epoch (wall clock)
                     # vs the router's — the per-shard offset estimate
@@ -1811,6 +1882,7 @@ class FleetRouter:
             hbm_watermarks=_hbm_watermarks(metric_states),
             tier_watermarks=_tier_watermarks(metric_states),
             per_class=per_class,
+            device=dict(self.device),
         )
         reg = get_registry()
         reg.counter("fleet.replica_deaths").inc(self.replica_deaths)

@@ -236,8 +236,12 @@ class ServeReport:
     layout_rules: str = ""
     # which attention kernel consumed the cache ("flash" =
     # ops.flash_decode, "gather" = the legacy dense read) — the QUANT
-    # artifacts compare the two, so the report must say which ran
+    # artifacts compare the two, so the report must say which ran —
+    # and what that kernel actually was on this platform ("pallas" |
+    # "xla" | "gather"): a run that asked for the Pallas kernel and got
+    # the XLA twin says so here
     decode_kernel: str = "gather"
+    decode_impl: str = "gather"
     prefix_hit_rate: float = 0.0  # prompt tokens served from shared pages
     kv_bytes: int = 0  # KV pool bytes reserved
     # peak bytes committed to live sequences — equals kv_bytes under the
@@ -1831,6 +1835,7 @@ class ContinuousBatchingScheduler:
             tp=getattr(engine, "tp", 1),
             layout_rules=getattr(engine, "layout_rules", ""),
             decode_kernel=getattr(engine, "decode_kernel", "gather"),
+            decode_impl=getattr(engine, "decode_impl", "gather"),
             prefix_hit_rate=(
                 round(engine.prefix_hit_rate(), 4)
                 if hasattr(engine, "prefix_hit_rate")

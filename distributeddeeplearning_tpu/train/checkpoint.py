@@ -51,7 +51,7 @@ import threading
 import time
 import zlib
 from pathlib import Path
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -296,6 +296,27 @@ def latest_verified_step_in_dir(directory) -> Optional[int]:
     if (directory / DURABLE_MARKER).exists():
         return None  # durable dir with zero verified generations
     return steps[0]  # legacy (pre-manifest) directory
+
+
+def verified_param_shapes(directory) -> Optional[Dict[str, Tuple[int, ...]]]:
+    """``keystr -> shape`` of the ``params`` item in the newest generation
+    that carries a valid manifest, read from the manifest alone — no
+    restore, no device.  The fleet router validates prompts against the
+    model's vocab and position table with this while its workers (which
+    each own a chip) do the real restores.  None when no generation has
+    a manifest."""
+    directory = Path(directory)
+    step = latest_verified_step_in_dir(directory)
+    manifest = (
+        load_manifest(directory / str(step)) if step is not None else None
+    )
+    if manifest is None:
+        return None
+    return {
+        name[len("params/"):]: tuple(entry["shape"])
+        for name, entry in manifest["leaves"].items()
+        if name.startswith("params/")
+    }
 
 
 class Checkpointer:

@@ -60,10 +60,11 @@ def cross_entropy_loss(
 
 def topk_correct(logits: jax.Array, labels: jax.Array, k: int) -> jax.Array:
     """Fraction of examples whose label is in the top-k logits — parity with
-    ``accuracy(output, target, topk=(1,5))`` (``imagenet_pytorch_horovod.py:149-163``)."""
+    ``accuracy(output, target, topk=(1,5))`` (``imagenet_pytorch_horovod.py:149-163``).
+    ``logits`` [..., classes] against ``labels`` [...]: any leading dims."""
     k = min(k, logits.shape[-1])  # top-5 on a <5-class head degrades gracefully
     _, top = jax.lax.top_k(logits.astype(jnp.float32), k)
-    hit = (top == labels[:, None]).any(axis=-1)
+    hit = (top == labels[..., None]).any(axis=-1)
     return hit.mean()
 
 
@@ -164,6 +165,19 @@ def _state_shardings(mesh, state_example, rules, logical_axes):
         params=p_shard,
         opt_state=opt_shardings,
         batch_stats=jax.tree_util.tree_map(lambda _: r_shard, state_example.batch_stats),
+    )
+
+
+def place_state(mesh, state, *, rules=None, logical_axes=None):
+    """Put a fresh ``TrainState`` onto the shardings the train step built
+    from the same ``rules``/``logical_axes`` takes it in.  A workload with
+    rule-sharded params (fsdp / tensor / pipe) calls this before
+    ``Trainer.fit``: the placed state is the checkpoint RESTORE TEMPLATE,
+    so a resume reads every leaf straight into its shards — restored into
+    a fresh single-device template instead, the leaves come back committed
+    to one device and the sharded step refuses them."""
+    return jax.device_put(
+        state, _state_shardings(mesh, state, rules or [], logical_axes)
     )
 
 

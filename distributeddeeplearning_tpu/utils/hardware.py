@@ -10,7 +10,12 @@ from the TPU and GPU datasheets.
 
 from __future__ import annotations
 
-from typing import Optional
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+from typing import Any, Dict, Optional
 
 import jax
 
@@ -131,24 +136,74 @@ def mfu(
     return (flops_per_step * steps / wall_s) / (peak * n_chips)
 
 
-def enable_compilation_cache(min_compile_time_secs: int = 1) -> None:
-    """Persistent XLA compilation cache — repeated invocations of the same
-    program (driver runs, bench sweeps, dryruns) skip the multi-minute
-    recompile.  Best-effort: never fails the caller."""
-    import os
+#: Where the persistent compile cache lives when nothing outside places
+#: it: one fixed path inside the checkout (the path is part of the cache
+#: key's environment — a directory that moves between runs never hits),
+#: derived from the package's location and ignored by git.
+DEFAULT_COMPILATION_CACHE_DIR = str(
+    Path(__file__).resolve().parents[2] / ".jax_cache"
+)
 
-    try:
-        cache = os.path.join(
-            os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-            "jax",
+
+def enable_compilation_cache(min_compile_time_secs: float = 1.0) -> str:
+    """Turn on the persistent XLA compilation cache for this process —
+    every entry point (``ddlt serve``/``train``, fleet workers, bench,
+    ``chip_smoke.py``) calls this once, so repeated invocations of the
+    same program load instead of recompiling.  Returns the directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache was placed from
+    outside (JAX reads the variable itself) and no directory is set in
+    code; otherwise it goes to :data:`DEFAULT_COMPILATION_CACHE_DIR`.
+    Programs that compiled faster than ``min_compile_time_secs`` are not
+    stored."""
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", min_compile_time_secs
+    )
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    os.makedirs(DEFAULT_COMPILATION_CACHE_DIR, exist_ok=True)
+    jax.config.update(
+        "jax_compilation_cache_dir", DEFAULT_COMPILATION_CACHE_DIR
+    )
+    return DEFAULT_COMPILATION_CACHE_DIR
+
+
+def device_summary() -> Dict[str, Any]:
+    """``{"platform", "kind", "count"}`` of this process's backend, as
+    JAX reports it — what every report and artifact names its device by.
+    Initialises the backend (and so takes the chip)."""
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
+_PROBE_SNIPPET = (
+    "import json, jax; d = jax.devices(); "
+    "print(json.dumps({'platform': d[0].platform, "
+    "'kind': d[0].device_kind, 'count': len(d)}))"
+)
+
+
+def probe_devices(timeout_s: float = 300.0) -> Dict[str, Any]:
+    """:func:`device_summary` taken by a throwaway child process, for a
+    parent that must stay off the backend: a chip belongs to one process
+    at a time, so a router or bench parent that is about to start workers
+    asks a child that has exited (and released the chip) by the time
+    this returns.  Raises when the child cannot initialise a backend."""
+    proc = subprocess.run(
+        [sys.executable, "-c", _PROBE_SNIPPET],
+        capture_output=True, text=True, timeout=timeout_s,
+    )
+    if proc.returncode != 0:
+        raise RuntimeError(
+            "device probe failed (no usable JAX backend?): "
+            + proc.stderr.strip()[-500:]
         )
-        os.makedirs(cache, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", min_compile_time_secs
-        )
-    except Exception:
-        pass
+    return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 def step_flops(compiled) -> Optional[float]:

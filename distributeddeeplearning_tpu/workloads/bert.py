@@ -145,6 +145,7 @@ def main(
     from distributeddeeplearning_tpu.train.step import (
         build_eval_step,
         build_train_step,
+        place_state,
     )
 
     if expert > 1 and num_experts == 0:
@@ -264,6 +265,8 @@ def main(
     eval_step = build_eval_step(
         mesh, state, compute_dtype=dtype, rules=rules, logical_axes=axes
     )
+    # the placed state is the checkpoint restore template (place_state)
+    state = place_state(mesh, state, rules=rules, logical_axes=axes)
 
     train_iter = _batches(
         data_format, training_data_path, True, per_host_batch,

@@ -84,10 +84,11 @@ def _batches(
     if data_format == "tfrecords":
         if input_pipeline == "raw":
             # Decode-once uint8 cache (data/raw_cache.py) — the pipeline for
-            # decode-bound hosts (BENCH_DATA_r04: streaming decode feeds a
-            # v5e at 0.1-0.2x; the cache at 1.9x).  Pixels arrive uint8; the
-            # train/eval steps normalize ON DEVICE via input_transform (the
-            # caller wires uint8_normalizer when input_pipeline == 'raw').
+            # decode-bound hosts (streaming JPEG decode cannot keep a chip
+            # fed from few cores; not measured on today's code).  Pixels
+            # arrive uint8; the train/eval steps normalize ON DEVICE via
+            # input_transform (the caller wires uint8_normalizer when
+            # input_pipeline == 'raw').
             if augment != "reference":
                 raise ValueError(
                     "input_pipeline='raw' caches deterministically-"

@@ -150,6 +150,7 @@ def main(
     from distributeddeeplearning_tpu.train.step import (
         build_eval_step,
         build_train_step,
+        place_state,
         topk_correct,
     )
 
@@ -365,12 +366,13 @@ def main(
             return next_token_loss(logits, labels)
 
         def lm_metrics(logits, tokens, loss):
-            b, s = tokens.shape
-            flat = logits[:, :-1].reshape(b * (s - 1), -1)
-            targets = tokens[:, 1:].reshape(b * (s - 1))
+            # the shifted logits stay 3-D, as in next_token_loss:
+            # flattening the non-contiguous slice to [b·(s-1), V] is a
+            # 1 GB compaction copy at the full LM width, and cost the TPU
+            # compiler three minutes of the train step's compile
             return {
                 "loss": loss.astype(jnp.float32),
-                "top1": topk_correct(flat, targets, 1),
+                "top1": topk_correct(logits[:, :-1], tokens[:, 1:], 1),
                 "perplexity": jnp.exp(loss).astype(jnp.float32),
             }
 
@@ -390,6 +392,11 @@ def main(
     if comm_overlap:
         # prepared state doubles as the checkpoint restore template
         state = train_step.prepare_state(state)
+    else:
+        # so does the placed state: a resume restores into these shards
+        state = place_state(
+            mesh, state, rules=rules, logical_axes=logical_axes
+        )
     eval_step = build_eval_step(
         mesh, state, compute_dtype=dtype, rules=rules,
         logical_axes=logical_axes, loss_fn=lm_loss, metrics_fn=lm_metrics,

@@ -542,8 +542,11 @@ class TestRooflineMath:
         # paired with another chip's memory bandwidth (a v5p roofline
         # built on v5e's 819 GB/s would flip compute-bound programs to
         # "hbm-bandwidth"); on CPU both lookups miss and the v5e
-        # nominals are returned explicitly labeled as reference numbers
+        # nominals are returned explicitly labeled as reference numbers;
+        # an accelerator missing from the tables raises instead of
+        # borrowing them
         from types import SimpleNamespace
+        from unittest import mock
 
         from distributeddeeplearning_tpu.obs.attrib import reference_peaks
         from distributeddeeplearning_tpu.utils.hardware import (
@@ -558,6 +561,13 @@ class TestRooflineMath:
         assert peak_hbm_gbps(v5p) == 2765.0
         assert peak_bf16_flops(v5p) == 459e12
         assert peak_hbm_gbps(SimpleNamespace(device_kind="cpu")) is None
+        unknown = SimpleNamespace(platform="tpu", device_kind="TPU v99")
+        with mock.patch("jax.devices", return_value=[unknown]):
+            with pytest.raises(ValueError, match="no published peaks"):
+                reference_peaks()
+        known = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+        with mock.patch("jax.devices", return_value=[known]):
+            assert reference_peaks() == (197.0, 819.0, "device")
 
 
 # --- hardened history reader ------------------------------------------------

@@ -345,23 +345,26 @@ def test_auto_block_nondivisible_seq():
     assert bool(jnp.isfinite(out).all())
 
 
-def test_auto_block_floor_falls_back_to_dense():
+def test_auto_block_floor_is_refused_not_rerouted():
     """Low-divisibility seq lens (1032 -> block 8, odd -> 1) must not run
-    a pathological (S/b)^2 grid: the wrapper warns and takes the dense
-    path, matching a plain-XLA reference exactly."""
+    a pathological (S/b)^2 grid — and must not silently run another
+    program either: the wrapper raises, and ``auto_block_tiles`` lets a
+    caller check a length up front."""
     import warnings
 
-    import jax
     import jax.numpy as jnp
     import numpy as np
 
     from distributeddeeplearning_tpu.ops.flash_attention import (
-        _WARNED_FALLBACKS,
         _auto_block,
+        auto_block_tiles,
         flash_attention,
     )
 
     assert _auto_block(1032) == 8  # the pathological selection itself
+    assert not auto_block_tiles(1032) and not auto_block_tiles(2049)
+    assert auto_block_tiles(64) and auto_block_tiles(333)  # one tile
+    assert auto_block_tiles(1536) and auto_block_tiles(2048)
 
     rng = np.random.default_rng(1)
     s = 1032
@@ -369,31 +372,8 @@ def test_auto_block_floor_falls_back_to_dense():
         jnp.asarray(rng.standard_normal((1, s, 1, 8)), jnp.float32)
         for _ in range(3)
     )
-    _WARNED_FALLBACKS.clear()  # a prior test may have burned this shape
-    with pytest.warns(UserWarning, match="below the 128 floor"):
-        out = flash_attention(q, k, v, None, dtype=jnp.float32, causal=True)
-
-    # warn-once per shape class: the second identical call must be
-    # SILENT (serve loops hit the fallback every step — a per-call
-    # warning floods stderr without adding information)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
+    with pytest.raises(ValueError, match="below the 128 floor"):
         flash_attention(q, k, v, None, dtype=jnp.float32, causal=True)
-
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(8.0)
-    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -1e30)
-    ref = jnp.einsum(
-        "bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v
-    )
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-    # the fallback is differentiable (custom_vjp no longer in the path)
-    g = jax.grad(
-        lambda q: flash_attention(
-            q, k, v, None, dtype=jnp.float32, causal=True
-        ).sum()
-    )(q)
-    assert bool(jnp.isfinite(g).all())
 
     # seqs at/below the floor keep the kernel: single-tile grids are fine
     q2, k2, v2 = (x[:, :64] for x in (q, k, v))
