@@ -110,7 +110,10 @@ class FlightRecorder:
     atomic under the GIL and the ring never shrinks concurrently.
     """
 
-    def __init__(self, capacity: int = 256, *, enabled: bool = True):
+    # 768: a busy serve-loop turn records 7-10 entries (its spans, a
+    # request's events, metric deltas), so the ring holds the last
+    # hundred turns or so
+    def __init__(self, capacity: int = 768, *, enabled: bool = True):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = capacity

@@ -18,7 +18,16 @@ Design constraints (enforced by the ``analysis/`` host-sync checker via
 - **near-zero cost when disabled**: ``span()`` on a disabled tracer
   returns a shared no-op context manager without reading the clock or
   allocating an event — the hot paths stay hot with observability off
-  (the default).
+  (the default);
+- **follows any profiler capture**: while a ``jax.profiler`` capture is
+  live (``TraceAnnotation.is_enabled()``, one ~60 ns call per span) the
+  tracer records exactly as if enabled — whoever started the capture (a
+  benchmark harness, an operator attaching to a live ``ddlt serve``)
+  gets the program's spans in the capture's host plane, on the
+  profiler's clock, with no switch in the program;
+- **bounded**: the event list is a ring of ``max_events`` entries, the
+  oldest dropped and counted (``dropped``), so a tracer left enabled on
+  a days-long worker cannot grow without bound.
 
 Usage::
 
@@ -34,8 +43,10 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
+from collections import deque
 from typing import Any, Dict, List, Optional
 
 from distributeddeeplearning_tpu.obs import recorder as _recorder_mod
@@ -58,6 +69,11 @@ __all__ = [
 #: leaving it bound to the recorder that existed at import
 PROCESS_RECORDER: Any = object()
 
+#: how many events a tracer keeps (oldest dropped, counted in ``dropped``):
+#: about a quarter of an hour of the serve loop's ~10 spans a turn, well
+#: under 100 MB of event dicts at worst
+MAX_EVENTS = 131_072
+
 
 class _NullSpan:
     """The disabled-tracer span: a shared, stateless no-op.
@@ -77,6 +93,10 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+
+def _never() -> bool:
+    return False
 
 
 class _Span:
@@ -100,7 +120,12 @@ class _Span:
             # align the host and device clocks
             ann = tracer._trace_annotation
             if ann is not None:
-                self._annotation = ann(self._name)
+                # scalar args ride along, so the capture's host plane
+                # shows uid/trace/active beside the name
+                self._annotation = ann(self._name, **{
+                    k: v for k, v in self._args.items()
+                    if isinstance(v, (str, int, float))
+                })
                 self._annotation.__enter__()
         self._t0 = time.perf_counter()
         tracer._depth_local.depth = getattr(tracer._depth_local, "depth", 0) + 1
@@ -118,6 +143,7 @@ class _Span:
             dict(self._args) if self._args else {}
         )
         args["depth"] = depth - 1  # 0 = top-level: span nesting, testable
+        tracer._appended += 1
         tracer._events.append(
             {
                 "ph": "X",
@@ -145,10 +171,12 @@ class _Span:
 class Tracer:
     """Nested host spans + instant events on one monotonic clock.
 
-    Thread-safe by construction: events append to one list (atomic under
-    the GIL) and nesting depth is tracked per thread, so the scheduler
-    loop, the trainer loop and the watchdog thread can all report into the
-    same tracer.
+    Thread-safe by construction: events append to one bounded deque
+    (atomic under the GIL) and nesting depth is tracked per thread, so the
+    scheduler loop, the trainer loop and the watchdog thread can all
+    report into the same tracer.  It records while ``enabled`` OR while
+    any ``jax.profiler`` capture is live (``annotate=False`` opts out of
+    both the pass-through and the following).
     """
 
     def __init__(
@@ -159,11 +187,18 @@ class Tracer:
         pid: Optional[int] = None,
         process_name: Optional[str] = None,
         recorder: Optional[FlightRecorder] = None,
+        max_events: int = MAX_EVENTS,
     ):
         self._enabled = enabled
         self._annotate_requested = annotate
         self._annotate = False
         self._trace_annotation = None
+        # "is a jax.profiler capture live?" — the unbound probe until
+        # jax is importable-without-cost (already in sys.modules), then
+        # TraceAnnotation.is_enabled itself
+        self._capture_live = (
+            self._probe_capture if annotate else _never
+        )
         # pid/process_name derive from the EXPORTING process (the old
         # hardcoded pid-1 interleaved every fleet worker's spans into one
         # track when shards merged); ``process_name`` overrides for
@@ -176,7 +211,8 @@ class Tracer:
         # replica=k so every scheduler span carries its replica identity)
         self._context: Dict[str, Any] = {}
         self._recorder = recorder
-        self._events: List[Dict[str, Any]] = []
+        self._events: deque = deque(maxlen=int(max_events))
+        self._appended = 0  # events ever appended; dropped = this - kept
         self._depth_local = threading.local()
         # epoch pair: perf_counter for span math, wall clock so merged
         # timelines can be stamped in absolute time (and so fleet shards
@@ -195,9 +231,21 @@ class Tracer:
             from jax.profiler import TraceAnnotation
 
             self._trace_annotation = TraceAnnotation
+            self._capture_live = TraceAnnotation.is_enabled
             self._annotate = True
         except Exception:  # pragma: no cover - jax always present in-repo
             self._annotate = False
+            self._capture_live = _never
+
+    def _probe_capture(self) -> bool:
+        """The capture probe before ``TraceAnnotation`` is bound: no
+        capture can be live in a process that has not imported jax, and
+        the tracer never imports it on a hot path's behalf (a fleet
+        router stays off jax).  Once jax is there, bind and ask."""
+        if "jax" not in sys.modules:
+            return False
+        self._resolve_annotation()
+        return self._capture_live()
 
     # -- control ----------------------------------------------------------
     @property
@@ -210,6 +258,18 @@ class Tracer:
         anchor fleet shard merging aligns worker clocks with."""
         return self._epoch_wall
 
+    @property
+    def epoch_perf_s(self) -> float:
+        """``time.perf_counter()`` at this tracer's epoch: an event's
+        ``ts`` is microseconds after it, so a reader can put events on
+        another clock from two ``perf_counter`` readings of its own."""
+        return self._epoch_perf
+
+    @property
+    def dropped(self) -> int:
+        """Events pushed out of the bounded list since the last clear."""
+        return self._appended - len(self._events)
+
     def enable(self) -> "Tracer":
         self._enabled = True
         self._resolve_annotation()
@@ -220,7 +280,8 @@ class Tracer:
         return self
 
     def clear(self) -> None:
-        self._events = []
+        self._events.clear()
+        self._appended = 0
 
     def set_context(self, **args: Any) -> "Tracer":
         """Merge default args stamped onto every subsequent span/event —
@@ -239,12 +300,13 @@ class Tracer:
 
     # -- recording --------------------------------------------------------
     def span(self, name: str, cat: str = "host", **args):
-        """Context manager timing a host-side phase.  Disabled tracer
+        """Context manager timing a host-side phase.  Records while
+        enabled or while a ``jax.profiler`` capture is live.  Otherwise,
         without a recorder: the shared no-op span (no clock read, no
         allocation).  With a flight recorder attached the disabled path
         hands out the recorder's lightweight span instead — one ring
         append, still zero-sync (lint-pinned)."""
-        if self._enabled:
+        if self._enabled or self._capture_live():
             return _Span(self, name, cat, args)
         rec = self._recorder
         if rec is PROCESS_RECORDER:
@@ -256,15 +318,17 @@ class Tracer:
     def event(self, name: str, cat: str = "host", **args) -> None:
         """Instant event (Chrome ``"i"``): watchdog trips, preemptions,
         anomaly detections — point-in-time marks on the same timeline.
-        Recorded into the attached flight recorder even when disabled."""
+        Recorded into the attached flight recorder even when disabled;
+        into the tracer while enabled or while a capture is live."""
         rec = self._recorder
         if rec is PROCESS_RECORDER:
             rec = _recorder_mod._RECORDER
         if rec is not None and rec.enabled:
             rec.record_event(name, cat, args)
-        if not self._enabled:
+        if not (self._enabled or self._capture_live()):
             return
         ctx = self._context
+        self._appended += 1
         self._events.append(
             {
                 "ph": "i",
@@ -307,6 +371,7 @@ class Tracer:
                 "clock": "perf_counter us since tracer epoch",
                 "host_pids": [self.pid],
                 "process_name": self.process_name,
+                "dropped": self.dropped,
             },
         }
 
@@ -328,8 +393,9 @@ _TRACER = Tracer(enabled=False, recorder=PROCESS_RECORDER)
 
 
 def get_tracer() -> Tracer:
-    """The process's tracer.  Disabled (no-op spans) until a driver —
-    ``ddlt obs``, ``bench.py --obs``, ``--trace-dir`` — enables it."""
+    """The process's tracer.  Records nothing until a driver — ``ddlt
+    obs``, ``bench.py --obs``, ``--trace-dir`` — enables it, or while a
+    ``jax.profiler`` capture is live."""
     return _TRACER
 
 

@@ -35,7 +35,7 @@ an axis of size 1, i.e. replication); no spec is hand-wired here.
 from __future__ import annotations
 
 import logging
-from typing import Optional, Sequence
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -628,22 +628,26 @@ class InferenceEngine:
         (fixed batch shape is what makes the step a single executable);
         the scheduler ignores their outputs and their cache writes stay
         masked behind the slot's position."""
-        # dispatch span separate from the np.asarray readback below: on a
-        # merged timeline the gap between them IS the host-sync share of
-        # the decode step (the readback is the scheduler's one designed
-        # sync — it needs the token ids)
-        with get_tracer().span("serve/engine.decode_dispatch"):
-            toks, finite, self._cache = self._decode_jit(
+        # three spans, one step: the argument upload (each jnp.asarray is
+        # a tiny device program of its own), the jitted call, and the
+        # readback — the scheduler's one designed sync (it needs the token
+        # ids), where the host waits out the device's step
+        trace = get_tracer()
+        with trace.span("serve/engine.decode_upload"):
+            args = (
                 self.params,
                 self._cache,
                 jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(pos, jnp.int32),
                 jnp.int32(self._next_step()),
             )
-        # the finite readback piggybacks on the token sync the scheduler
-        # already pays (same computation, already materialized)
-        self.last_finite = np.asarray(finite)
-        return np.asarray(toks)
+        with trace.span("serve/engine.decode_dispatch"):
+            toks, finite, self._cache = self._decode_jit(*args)
+        with trace.span("serve/engine.decode_fetch"):
+            # the finite readback piggybacks on the token sync (same
+            # computation, already materialized)
+            self.last_finite = np.asarray(finite)
+            return np.asarray(toks)
 
     # -- fault injection / quarantine hooks --------------------------------
     def poison_slot(self, slot: int, pos: int) -> None:
@@ -1067,6 +1071,23 @@ class PagedInferenceEngine:
             <= self.allocator.available
         )
 
+    def kv_pages_held(self, written_pos: Dict[int, int]) -> Tuple[int, int]:
+        """(reserved, written) pool pages right now.  ``reserved`` is
+        ``allocator.pages_in_use``: distinct pages some slot holds
+        (refcount >= 1; a prefix page mapped by several slots is one
+        page).  ``written`` counts, of those, the distinct pages holding
+        at least one written position: for each ``slot -> n`` of
+        ``written_pos`` (n = positions of that slot's sequence already
+        in the cache, prefix hits included), the slot's first
+        ``ceil(n / page_size)`` pages.  Host bookkeeping only."""
+        ps = self.page_size
+        written = set()
+        for slot, n in written_pos.items():
+            pages = self._slot_pages.get(slot)
+            if pages:
+                written.update(pages[: -(-n // ps)])
+        return self.allocator.pages_in_use, len(written)
+
     def fits(self, prompt_len: int, max_new_tokens: int) -> bool:
         """False when the request exceeds the POOL itself — waiting for
         completions can never help; the scheduler fails it instead of
@@ -1260,30 +1281,33 @@ class PagedInferenceEngine:
         """One decode step for every slot via block-table gather.  Same
         contract as the dense engine; released slots' rows point at the
         scratch page so their (ignored) lane writes are harmless."""
-        args = (
-            self.params,
-            self._cache,
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(pos, jnp.int32),
-            jnp.asarray(self._block_tables),
-            jnp.int32(self._next_step()),
-        )
+        trace = get_tracer()
+        with trace.span("serve/engine.decode_upload"):
+            args = (
+                self.params,
+                self._cache,
+                jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(pos, jnp.int32),
+                jnp.asarray(self._block_tables),
+                jnp.int32(self._next_step()),
+            )
         logits = None
-        with get_tracer().span("serve/engine.decode_dispatch"):
+        with trace.span("serve/engine.decode_dispatch"):
             if self.capture_logits:
                 toks, logits, finite, self._cache = self._decode_jit(
                     *args, True
                 )
             else:
                 toks, finite, self._cache = self._decode_jit(*args, False)
-        # probe readback OUTSIDE the dispatch span (same contract as the
-        # dense engine): the logits device->host sync must not be billed
-        # to dispatch, or the dispatch-vs-readback gap on the merged
-        # timeline reads as ~0 exactly when capture_logits is on
-        if logits is not None:
-            self.last_logits = np.asarray(logits)
-        self.last_finite = np.asarray(finite)
-        return np.asarray(toks)
+        # every device->host read in ONE span of its own (same contract
+        # as the dense engine): the logits probe must not be billed to
+        # dispatch, or the dispatch-vs-readback split on the timeline
+        # reads as ~0 exactly when capture_logits is on
+        with trace.span("serve/engine.decode_fetch"):
+            if logits is not None:
+                self.last_logits = np.asarray(logits)
+            self.last_finite = np.asarray(finite)
+            return np.asarray(toks)
 
     # -- fault injection / quarantine hooks --------------------------------
     def poison_slot(self, slot: int, pos: int) -> None:

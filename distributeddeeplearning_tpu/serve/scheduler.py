@@ -34,13 +34,21 @@ What it records is the whole point of serving benchmarks:
 - aggregate generated tokens/s and mean slot occupancy (how close the
   engine runs to its throughput ceiling),
 - ``prefill_compiles``: prefill shapes compiled DURING the run (each one
-  was a mid-run jit stall; warmup should drive it to 0).
+  was a mid-run jit stall; warmup should drive it to 0),
+- ``admission_turns`` / ``kv_pages_reserved_sum`` / ``kv_pages_written_sum``:
+  why the head of the queue stayed queued (slot, pages, HBM forecast),
+  counted where the decision is taken, and how much of the worst-case
+  page reservation is ever written.
 
 Every percentile block routes through the obs histogram
-(:func:`..obs.registry.summarize`), the run emits request-lifecycle
-spans/events on the obs tracer (no-ops unless a driver enabled it), and
-aggregate counters/histograms feed the process metrics registry once per
-``run()``.
+(:func:`..obs.registry.summarize`), and aggregate counters/histograms
+feed the process metrics registry once per ``run()``.  One loop turn is
+covered by spans on the obs tracer (no-ops unless a driver enabled it or
+a ``jax.profiler`` capture is live): ``serve/poll``, ``serve/admission``
+(around ``serve/admit``), ``serve/prefill_chunk``, ``serve/decode_step``
+(the engine splits it into ``serve/engine.decode_upload`` /
+``decode_dispatch`` / ``decode_fetch``) and ``serve/emit``; request-scoped
+spans and the lifecycle events carry ``uid`` and ``trace``.
 
 Resilience (PR 7) — the scheduler is also the serving stack's blast-radius
 boundary; every failure mode is scoped to ONE request, never the batch:
@@ -87,6 +95,7 @@ new decode-phase-only ``decode_tokens_per_sec``.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -298,6 +307,29 @@ class ServeReport:
     # private pages demoted by the preemption path (victims resume
     # without re-prefilling their generated history)
     tier_preempt_spilled_pages: int = 0
+    # why admission refused (counted where the decision is taken).  A
+    # "queued" turn is a loop turn whose admission block was entered with
+    # a non-empty queue (after that turn's poll).  Of those, a turn counts
+    # under ONE "blocked_*" key when it ended with the head of the queue
+    # not admitted — the first reason that held: "blocked_pages" =
+    # ``engine.can_admit`` was false (worst-case page reservation: prompt
+    # + whole output budget) and nothing could be preempted, whether the
+    # head then waited or was shed; "blocked_hbm" = the HBM ledger's
+    # forecast refused it likewise; "blocked_slots" = neither, but no
+    # slot was free (also when earlier heads took the last slots this
+    # turn).  A queued turn in none of the three admitted (or failed)
+    # every request it looked at.
+    admission_turns: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # sampled once per attempted decode step, beside slot occupancy:
+    # "reserved" = ``allocator.pages_in_use``, distinct pool pages held
+    # (refcount >= 1) by requests in a slot, decoding or mid-prefill — a
+    # prefix page shared by several requests is ONE page; "written" = of
+    # those, distinct pages holding at least one written position: the
+    # first ceil(n / page_size) pages of each slot, n = the request's
+    # ``next_pos`` (decoding) or prefill offset (mid-prefill, prefix-hit
+    # pages included).  Both 0 on the dense engine (no pages).
+    kv_pages_reserved_sum: int = 0
+    kv_pages_written_sum: int = 0
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -716,6 +748,17 @@ class ContinuousBatchingScheduler:
         tpot_registry_hist = _reg.histogram("serve.tpot_s")
         occ_sum = 0.0
         occ_n = 0               # attempted decode steps (incl. failed)
+        # admission accounting (ServeReport.admission_turns /
+        # kv_pages_*_sum): host integers bumped where the decision is
+        # taken; dense engines have no pages to count
+        admission_turns = {
+            "queued": 0, "blocked_slots": 0, "blocked_pages": 0,
+            "blocked_hbm": 0,
+        }
+        kv_pages_held = getattr(engine, "kv_pages_held", None)
+        kv_reserved_sum = 0
+        kv_written_sum = 0
+        no_span = contextlib.nullcontext()
         n_decode_steps = 0      # exact count
         generated_count = 0     # exact token total (results may be windowed)
         prompt_tokens = 0
@@ -1269,12 +1312,17 @@ class ContinuousBatchingScheduler:
                 if watchdog is not None and n_decode_steps > 0:
                     watchdog.tick(n_decode_steps)
                 if more and not draining:
-                    fresh = poll()
-                    if fresh is None:
-                        more = False  # source closed: finish what we hold
-                    else:
-                        for req in fresh:
-                            intake(req)
+                    # an idle live loop polls a thousand times a second:
+                    # spans of those would flush the flight recorder's
+                    # ring (the last moments before a fault) with nothing
+                    idle = not (active or pending or prefilling)
+                    with no_span if idle else trace.span("serve/poll"):
+                        fresh = poll()
+                        if fresh is None:
+                            more = False  # source closed: finish what we hold
+                        else:
+                            for req in fresh:
+                                intake(req)
                 if (
                     not draining
                     and should_drain is not None
@@ -1354,206 +1402,232 @@ class ContinuousBatchingScheduler:
                             # release a finished request takes)
                             complete(slot, st, "deadline")
 
-                # Admit prompts into free slots — mid-flight: slots released in
-                # the previous iteration take new work while the rest decode on.
-                # Paged engines additionally gate on free PAGES: a request that
-                # could strand mid-decode is left queued (backpressure) until
-                # completions free its reservation.
-                # priority preemption on SLOT pressure: a higher-class
-                # head stuck behind zero free slots cuts the lowest-class
-                # active decode (losslessly, budget permitting) instead
-                # of waiting out the victim's full token budget.  One cut
-                # per iteration — pressure relief is gradual by design.
-                # Page/HBM pressure is handled inside the admission loop
-                # below, where the blocked resource is known.
-                if (
-                    pending and not free and not draining
-                    and self._pending_reload is None
-                ):
-                    head_rank = self._class_rank.get(pending[0].priority)
-                    if head_rank is not None:
-                        victim = self._preemption_victim(active, head_rank)
-                        if victim is not None:
-                            preempt_slot(victim, active[victim])
-
-                # spill/prefetch pump: one pass per iteration retires
-                # landed prefetches and keeps a free-page cushion by
-                # demoting the coldest reclaimable prefix pages — the
-                # designed D2H copy runs HERE, off the admission path,
-                # instead of synchronously inside alloc's evict hook
-                if tier is not None:
-                    self._tier_pump(engine, hbm_ledger)
-
-                hbm_committed = None  # ledger walk amortized per iteration
-                while (
-                    pending and not draining and free
-                    # reload pending: hold admission so the active set
-                    # drains to the idle barrier (queued requests are
-                    # served by the NEW weights after the swap)
-                    and self._pending_reload is None
-                ):
-                    req = pending[0]
-                    budget = budget_of(req)
-                    m = meta[req.uid]
-                    if req.uid in self._cancelled:
-                        pending.popleft()
-                        self._cancelled.discard(req.uid)
-                        fail_request(req, None, reason="cancelled")
-                        continue
+                # One span over the turn's whole admission block (entered only
+                # with a non-empty queue): slot-pressure preemption, the tier
+                # pump's page cushion, the ladder below, down to serve/admit.
+                # ``blocked`` names why the head stayed queued this turn — the
+                # first reason that held — for ServeReport.admission_turns.
+                blocked = None
+                if pending:
+                    admission_turns["queued"] += 1
+                    admission = trace.span(
+                        "serve/admission", pending=len(pending)
+                    )
+                else:
+                    admission = no_span
+                with admission:
+                    # Admit prompts into free slots — mid-flight: slots
+                    # released in the previous iteration take new work while
+                    # the rest decode on.  Paged engines additionally gate on
+                    # free PAGES: a request that could strand mid-decode is
+                    # left queued (backpressure) until completions free its
+                    # reservation.
+                    # priority preemption on SLOT pressure: a higher-class
+                    # head stuck behind zero free slots cuts the lowest-class
+                    # active decode (losslessly, budget permitting) instead
+                    # of waiting out the victim's full token budget.  One cut
+                    # per iteration — pressure relief is gradual by design.
+                    # Page/HBM pressure is handled inside the admission loop
+                    # below, where the blocked resource is known.
                     if (
-                        m.deadline_at is not None
-                        and time.perf_counter() > m.deadline_at
+                        pending and not free and not draining
+                        and self._pending_reload is None
                     ):
-                        # expired while queued: never admitted, no tokens
-                        pending.popleft()
-                        fail_request(req, None, reason="deadline")
-                        continue
-                    if chunked:
-                        if not engine.fits(len(req.prompt), budget):
-                            # exceeds the POOL — waiting can never admit it
-                            pending.popleft()
-                            fail_request(req, RuntimeError(
-                                f"request needs "
-                                f"{engine.required_pages(len(req.prompt), budget)}"
-                                f" pages, pool holds {engine.num_pages}"
-                            ))
-                            continue
-                        if not engine.can_admit(len(req.prompt), budget):
-                            # PAGE pressure: with restores in flight the
-                            # page accounting is mid-transition — fence
-                            # them (admit gates until the prefetch
-                            # LANDS) before cutting a victim against a
-                            # transient reading
-                            if tier is not None and engine.tier_inflight():
-                                engine.drain_tier()
-                                continue
-                            # cut a strictly-lower-class
-                            # decode (its pages release) and re-check;
-                            # no victim -> shed the head if it is
-                            # lowest-class and the policy allows
-                            victim = self._preemption_victim(
-                                active, self._class_rank[req.priority]
-                            )
+                        head_rank = self._class_rank.get(pending[0].priority)
+                        if head_rank is not None:
+                            victim = self._preemption_victim(active, head_rank)
                             if victim is not None:
                                 preempt_slot(victim, active[victim])
-                                continue
-                            # ONE shed per iteration, then yield to the
-                            # decode step: shedding relieves pressure for
-                            # the head, it must not cascade through the
-                            # whole queue against one instantaneous
-                            # reading while in-flight completions are a
-                            # few steps from freeing the pages
-                            if maybe_shed(req):
-                                break
-                            if active or prefilling:
-                                break  # completions will free pages
-                            # nothing in flight can free pages: fail loudly
-                            # instead of spinning forever
+
+                    # spill/prefetch pump: one pass per iteration retires
+                    # landed prefetches and keeps a free-page cushion by
+                    # demoting the coldest reclaimable prefix pages — the
+                    # designed D2H copy runs HERE, ahead of the ladder,
+                    # instead of synchronously inside alloc's evict hook
+                    if tier is not None:
+                        self._tier_pump(engine, hbm_ledger)
+
+                    hbm_committed = None  # ledger walk amortized per iteration
+                    while (
+                        pending and not draining and free
+                        # reload pending: hold admission so the active set
+                        # drains to the idle barrier (queued requests are
+                        # served by the NEW weights after the swap)
+                        and self._pending_reload is None
+                    ):
+                        req = pending[0]
+                        budget = budget_of(req)
+                        m = meta[req.uid]
+                        if req.uid in self._cancelled:
                             pending.popleft()
-                            fail_request(req, RuntimeError(
-                                "page pool exhausted with no requests in "
-                                "flight (pages leaked?)"
-                            ))
+                            self._cancelled.discard(req.uid)
+                            fail_request(req, None, reason="cancelled")
                             continue
-                    if hbm_ledger is not None:
-                        # predicted-headroom backpressure (obs/ledger.py):
-                        # free pages are necessary but not sufficient —
-                        # the ledger forecasts COMMITTED HBM across every
-                        # owner (params, other engines, quant scales),
-                        # so admission waits while in-flight work holds
-                        # the headroom instead of discovering the OOM
-                        # mid-decode
-                        extra = admit_bytes(len(req.prompt), budget)
-                        if extra:
-                            # the committed walk (a pytree traversal of
-                            # every registered provider) runs at most
-                            # once per scheduler iteration; admissions
-                            # within the iteration add their worst-case
-                            # reservation on top, so a burst can never
-                            # over-admit against one stale reading
-                            if (
-                                hbm_committed is None
-                                and hbm_ledger.capacity_bytes is not None
-                            ):
-                                hbm_committed = hbm_ledger.committed_bytes()
-                            if not hbm_ledger.admit_ok(
-                                extra, committed=hbm_committed
-                            ):
-                                # HBM-forecast pressure: same ladder as
-                                # page pressure — fence in-flight
-                                # prefetches first (landing frees host
-                                # slots and settles the forecast), then
-                                # preempt strictly lower, then shed a
-                                # lowest-class head, then block on
-                                # in-flight completions
-                                if (
-                                    tier is not None
-                                    and engine.tier_inflight()
-                                ):
+                        if (
+                            m.deadline_at is not None
+                            and time.perf_counter() > m.deadline_at
+                        ):
+                            # expired while queued: never admitted, no tokens
+                            pending.popleft()
+                            fail_request(req, None, reason="deadline")
+                            continue
+                        if chunked:
+                            if not engine.fits(len(req.prompt), budget):
+                                # exceeds the POOL — waiting can never admit it
+                                pending.popleft()
+                                fail_request(req, RuntimeError(
+                                    "request needs " + str(
+                                        engine.required_pages(
+                                            len(req.prompt), budget
+                                        )
+                                    ) + f" pages, pool holds {engine.num_pages}"
+                                ))
+                                continue
+                            if not engine.can_admit(len(req.prompt), budget):
+                                # PAGE pressure: with restores in flight the
+                                # page accounting is mid-transition — fence
+                                # them (admit gates until the prefetch
+                                # LANDS) before cutting a victim against a
+                                # transient reading
+                                if tier is not None and engine.tier_inflight():
                                     engine.drain_tier()
-                                    hbm_committed = None
                                     continue
+                                # cut a strictly-lower-class
+                                # decode (its pages release) and re-check;
+                                # no victim -> shed the head if it is
+                                # lowest-class and the policy allows
                                 victim = self._preemption_victim(
                                     active, self._class_rank[req.priority]
                                 )
                                 if victim is not None:
                                     preempt_slot(victim, active[victim])
-                                    # the cut released committed bytes;
-                                    # the stale walk must not block the
-                                    # re-check
-                                    hbm_committed = None
                                     continue
-                                # one shed per iteration (same pacing
-                                # rule as the page ladder above)
+                                # ONE shed per iteration, then yield to the
+                                # decode step: shedding relieves pressure for
+                                # the head, it must not cascade through the
+                                # whole queue against one instantaneous
+                                # reading while in-flight completions are a
+                                # few steps from freeing the pages
                                 if maybe_shed(req):
+                                    blocked = "blocked_pages"
                                     break
                                 if active or prefilling:
-                                    # completions release committed bytes
+                                    # completions will free pages
+                                    blocked = "blocked_pages"
                                     break
+                                # nothing in flight can free pages: fail loudly
+                                # instead of spinning forever
                                 pending.popleft()
                                 fail_request(req, RuntimeError(
-                                    f"predicted HBM headroom exhausted: the "
-                                    f"request would commit {extra} more bytes "
-                                    "past the ledger capacity with nothing in "
-                                    "flight to release any"
+                                    "page pool exhausted with no requests in "
+                                    "flight (pages leaked?)"
                                 ))
                                 continue
-                            if hbm_committed is not None:
-                                hbm_committed += extra
-                    pending.popleft()
-                    slot = free.pop()
-                    # arrival-based: in live mode the loop may be hours
-                    # old when this request arrived
-                    queue_wait = round(time.perf_counter() - m.arrival, 6)
-                    if chunked:
+                        if hbm_ledger is not None:
+                            # predicted-headroom backpressure (obs/ledger.py):
+                            # free pages are necessary but not sufficient —
+                            # the ledger forecasts COMMITTED HBM across every
+                            # owner (params, other engines, quant scales),
+                            # so admission waits while in-flight work holds
+                            # the headroom instead of discovering the OOM
+                            # mid-decode
+                            extra = admit_bytes(len(req.prompt), budget)
+                            if extra:
+                                # the committed walk (a pytree traversal of
+                                # every registered provider) runs at most
+                                # once per scheduler iteration; admissions
+                                # within the iteration add their worst-case
+                                # reservation on top, so a burst can never
+                                # over-admit against one stale reading
+                                if (
+                                    hbm_committed is None
+                                    and hbm_ledger.capacity_bytes is not None
+                                ):
+                                    hbm_committed = hbm_ledger.committed_bytes()
+                                if not hbm_ledger.admit_ok(
+                                    extra, committed=hbm_committed
+                                ):
+                                    # HBM-forecast pressure: same ladder as
+                                    # page pressure — fence in-flight
+                                    # prefetches first (landing frees host
+                                    # slots and settles the forecast), then
+                                    # preempt strictly lower, then shed a
+                                    # lowest-class head, then block on
+                                    # in-flight completions
+                                    if (
+                                        tier is not None
+                                        and engine.tier_inflight()
+                                    ):
+                                        engine.drain_tier()
+                                        hbm_committed = None
+                                        continue
+                                    victim = self._preemption_victim(
+                                        active, self._class_rank[req.priority]
+                                    )
+                                    if victim is not None:
+                                        preempt_slot(victim, active[victim])
+                                        # the cut released committed bytes;
+                                        # the stale walk must not block the
+                                        # re-check
+                                        hbm_committed = None
+                                        continue
+                                    # one shed per iteration (same pacing
+                                    # rule as the page ladder above)
+                                    if maybe_shed(req):
+                                        blocked = "blocked_hbm"
+                                        break
+                                    if active or prefilling:
+                                        # completions release committed bytes
+                                        blocked = "blocked_hbm"
+                                        break
+                                    pending.popleft()
+                                    fail_request(req, RuntimeError(
+                                        f"predicted HBM headroom exhausted: the "
+                                        f"request would commit {extra} more bytes "
+                                        "past the ledger capacity with nothing in "
+                                        "flight to release any"
+                                    ))
+                                    continue
+                                if hbm_committed is not None:
+                                    hbm_committed += extra
+                        pending.popleft()
+                        slot = free.pop()
+                        # arrival-based: in live mode the loop may be hours
+                        # old when this request arrived
+                        queue_wait = round(time.perf_counter() - m.arrival, 6)
+                        if chunked:
+                            try:
+                                with trace.span(
+                                    "serve/admit", uid=req.uid,
+                                    prompt_len=len(req.prompt),
+                                    trace=req.trace_id,
+                                ):
+                                    task = engine.prefill_begin(
+                                        slot, req.prompt, budget
+                                    )
+                            except Exception as exc:  # noqa: BLE001 — per-request
+                                release(slot)
+                                fail_request(req, exc, queue_wait)
+                                free.append(slot)
+                                continue
+                            prefilling.append((task, req, budget, queue_wait))
+                            continue
                         try:
                             with trace.span(
-                                "serve/admit", uid=req.uid,
+                                "serve/prefill", uid=req.uid,
                                 prompt_len=len(req.prompt),
                                 trace=req.trace_id,
                             ):
-                                task = engine.prefill_begin(
-                                    slot, req.prompt, budget
-                                )
-                        except Exception as exc:  # noqa: BLE001 — per-request
-                            release(slot)
+                                first = engine.prefill(slot, req.prompt)
+                        except Exception as exc:  # noqa: BLE001 — per request
                             fail_request(req, exc, queue_wait)
                             free.append(slot)
                             continue
-                        prefilling.append((task, req, budget, queue_wait))
-                        continue
-                    try:
-                        with trace.span(
-                            "serve/prefill", uid=req.uid,
-                            prompt_len=len(req.prompt),
-                            trace=req.trace_id,
-                        ):
-                            first = engine.prefill(slot, req.prompt)
-                    except Exception as exc:  # noqa: BLE001 — isolate per request
-                        fail_request(req, exc, queue_wait)
-                        free.append(slot)
-                        continue
-                    activate(slot, req, budget, first, queue_wait)
+                        activate(slot, req, budget, first, queue_wait)
+                    if blocked is None and pending and not free:
+                        blocked = "blocked_slots"
+                    if blocked is not None:
+                        admission_turns[blocked] += 1
 
                 # Advance ONE chunk of the oldest in-flight prefill, then fall
                 # through to decode — the chunked-prefill interleave: running
@@ -1624,6 +1698,16 @@ class ContinuousBatchingScheduler:
                         ))
                 occ_sum += len(active) / slots
                 occ_n += 1
+                if kv_pages_held is not None:
+                    # positions written so far, per slot that holds pages
+                    written_pos = {
+                        slot: st.next_pos for slot, st in active.items()
+                    }
+                    for task, _req, _budget, _wait in prefilling:
+                        written_pos[task.slot] = task.offset
+                    reserved, written = kv_pages_held(written_pos)
+                    kv_reserved_sum += reserved
+                    kv_written_sum += written
                 decode_step = n_decode_steps + 1  # 1-based, the fault clock
                 if plan:
                     stall = plan.take_decode_stall(decode_step)
@@ -1691,91 +1775,97 @@ class ContinuousBatchingScheduler:
                     keep_buf[:] = spec.draft_tokens + 1
                     rollback_needed = False
 
-                # NaN quarantine: engines report per-slot logit finiteness
-                # from the SAME jitted step (no extra sync).  A poisoned slot
-                # is scrubbed and fails alone — the batch decodes on.
-                finite = (
-                    res.finite if res is not None
-                    else getattr(engine, "last_finite", None)
-                )
-                # spec mode defers completions until AFTER the batched
-                # rollback: complete() releases the slot (paged: block
-                # table row back to SCRATCH), and a rollback dispatched
-                # after that would zero the dustbin instead of the freed
-                # pages' rejected-draft tail
-                finished: List = []
-                for slot, st in list(active.items()):
-                    if finite is not None and not finite[slot]:
-                        quarantined += 1
-                        scrub = getattr(engine, "scrub_slot", None)
-                        if scrub is not None:
-                            # zero the slot's decode-written region so the
-                            # NaN cannot leak to the next occupant via the
-                            # 0-weight * NaN-value softmax path (in spec
-                            # mode this also covers the step's whole
-                            # draft/verify write horizon, so the batched
-                            # rollback can skip the slot)
-                            scrub(slot, len(st.req.prompt))
-                        trace.event(
-                            "serve/request_quarantined", uid=st.req.uid,
-                            step=decode_step, trace=st.req.trace_id,
-                        )
-                        # black-box trigger: freeze the flight-recorder
-                        # ring (the last-N spans/events/metric deltas
-                        # BEFORE the poison surfaced) — the fleet worker
-                        # ships these dumps home with its report
-                        get_recorder().dump(
-                            "decode_quarantine", registry=get_registry(),
-                            uid=st.req.uid, step=decode_step,
-                        )
-                        finished.append((
-                            slot, st, "error",
-                            "non-finite logits (quarantined at decode "
-                            f"step {decode_step})",
-                        ))
-                        continue
-                    if res is None:
-                        toks = [int(out[slot])]
-                    else:
-                        # accepted drafts + the verifier's bonus token,
-                        # cut at EOS (the tail past an accepted EOS was
-                        # speculation over a finished sequence)
-                        emitted = int(res.accepted[slot]) + 1
-                        toks = [int(t) for t in res.tokens[slot, :emitted]]
-                        if self.eos_id is not None and self.eos_id in toks:
-                            toks = toks[: toks.index(self.eos_id) + 1]
-                        spec_drafted += int(dlen_buf[slot])
-                        spec_accepted += int(res.accepted[slot])
-                        spec_committed += len(toks)
-                        spec_slot_steps += 1
-                        keep_buf[slot] = len(toks)
-                        if len(toks) <= spec.draft_tokens:
-                            rollback_needed = True
-                    decode_tokens += len(toks)
-                    for tok in toks:
-                        st.generated.append(tok)
-                        if on_token is not None:
-                            on_token(st.req.uid, tok)
-                    st.next_pos += len(toks)
-                    reason = self._finished(st)
-                    if reason is not None:
-                        finished.append((slot, st, reason, None))
-                if res is not None and rollback_needed:
-                    # ONE batched dispatch zeroes every slot's rejected
-                    # tail (positions >= pos + keep) — the jitted form of
-                    # scrub_slot(slot, from_pos), pinned equivalent in
-                    # tests/test_spec.py; MUST run before the completions
-                    # below release their slots
-                    spec.rollback(pos_buf, keep_buf)
-                for slot, st, reason, err in finished:
-                    complete(slot, st, reason, error=err)
+                # the rest of the turn in one span: quarantine check,
+                # on_token, budget/EOS, finish, release
+                with trace.span("serve/emit", active=len(active)):
+                    # NaN quarantine: engines report per-slot logit finiteness
+                    # from the SAME jitted step (no extra sync).  A poisoned slot
+                    # is scrubbed and fails alone — the batch decodes on.
+                    finite = (
+                        res.finite if res is not None
+                        else getattr(engine, "last_finite", None)
+                    )
+                    # spec mode defers completions until AFTER the batched
+                    # rollback: complete() releases the slot (paged: block
+                    # table row back to SCRATCH), and a rollback dispatched
+                    # after that would zero the dustbin instead of the freed
+                    # pages' rejected-draft tail
+                    finished: List = []
+                    for slot, st in list(active.items()):
+                        if finite is not None and not finite[slot]:
+                            quarantined += 1
+                            scrub = getattr(engine, "scrub_slot", None)
+                            if scrub is not None:
+                                # zero the slot's decode-written region so the
+                                # NaN cannot leak to the next occupant via the
+                                # 0-weight * NaN-value softmax path (in spec
+                                # mode this also covers the step's whole
+                                # draft/verify write horizon, so the batched
+                                # rollback can skip the slot)
+                                scrub(slot, len(st.req.prompt))
+                            trace.event(
+                                "serve/request_quarantined", uid=st.req.uid,
+                                step=decode_step, trace=st.req.trace_id,
+                            )
+                            # black-box trigger: freeze the flight-recorder
+                            # ring (the last-N spans/events/metric deltas
+                            # BEFORE the poison surfaced) — the fleet worker
+                            # ships these dumps home with its report
+                            get_recorder().dump(
+                                "decode_quarantine", registry=get_registry(),
+                                uid=st.req.uid, step=decode_step,
+                            )
+                            finished.append((
+                                slot, st, "error",
+                                "non-finite logits (quarantined at decode "
+                                f"step {decode_step})",
+                            ))
+                            continue
+                        if res is None:
+                            toks = [int(out[slot])]
+                        else:
+                            # accepted drafts + the verifier's bonus token,
+                            # cut at EOS (the tail past an accepted EOS was
+                            # speculation over a finished sequence)
+                            emitted = int(res.accepted[slot]) + 1
+                            toks = [int(t) for t in res.tokens[slot, :emitted]]
+                            if self.eos_id is not None and self.eos_id in toks:
+                                toks = toks[: toks.index(self.eos_id) + 1]
+                            spec_drafted += int(dlen_buf[slot])
+                            spec_accepted += int(res.accepted[slot])
+                            spec_committed += len(toks)
+                            spec_slot_steps += 1
+                            keep_buf[slot] = len(toks)
+                            if len(toks) <= spec.draft_tokens:
+                                rollback_needed = True
+                        decode_tokens += len(toks)
+                        for tok in toks:
+                            st.generated.append(tok)
+                            if on_token is not None:
+                                on_token(st.req.uid, tok)
+                        st.next_pos += len(toks)
+                        reason = self._finished(st)
+                        if reason is not None:
+                            finished.append((slot, st, reason, None))
+                    if res is not None and rollback_needed:
+                        # ONE batched dispatch zeroes every slot's rejected
+                        # tail (positions >= pos + keep) — the jitted form of
+                        # scrub_slot(slot, from_pos), pinned equivalent in
+                        # tests/test_spec.py; MUST run before the completions
+                        # below release their slots
+                        spec.rollback(pos_buf, keep_buf)
+                    for slot, st, reason, err in finished:
+                        complete(slot, st, reason, error=err)
 
-                if on_step is not None:
-                    on_step(decode_step)
+                    if on_step is not None:
+                        on_step(decode_step)
 
-                if self.step_cap is not None and n_decode_steps >= self.step_cap:
-                    capped = True
-                    break
+                    if (
+                        self.step_cap is not None
+                        and n_decode_steps >= self.step_cap
+                    ):
+                        capped = True
+                        break
 
             if capped:
                 # deadline semantics for smoke runs: everything still running
@@ -1819,6 +1909,9 @@ class ContinuousBatchingScheduler:
             slot_occupancy_mean=(
                 round(occ_sum / occ_n, 4) if occ_n else 0.0
             ),
+            admission_turns=admission_turns,
+            kv_pages_reserved_sum=kv_reserved_sum,
+            kv_pages_written_sum=kv_written_sum,
             finish_reasons=finish_reasons,
             errors=error_count,
             queue_wait_s=_percentiles(
