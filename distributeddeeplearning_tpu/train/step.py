@@ -30,6 +30,7 @@ from distributeddeeplearning_tpu.parallel.sharding import (
     param_shardings,
     replicated,
 )
+from distributeddeeplearning_tpu.utils.metrics import label_in_topk
 
 PyTree = Any
 Metrics = Dict[str, jax.Array]
@@ -62,10 +63,7 @@ def topk_correct(logits: jax.Array, labels: jax.Array, k: int) -> jax.Array:
     """Fraction of examples whose label is in the top-k logits — parity with
     ``accuracy(output, target, topk=(1,5))`` (``imagenet_pytorch_horovod.py:149-163``).
     ``logits`` [..., classes] against ``labels`` [...]: any leading dims."""
-    k = min(k, logits.shape[-1])  # top-5 on a <5-class head degrades gracefully
-    _, top = jax.lax.top_k(logits.astype(jnp.float32), k)
-    hit = (top == labels[..., None]).any(axis=-1)
-    return hit.mean()
+    return label_in_topk(logits, labels, k).mean()
 
 
 def classification_metrics(logits: jax.Array, labels: jax.Array, loss: jax.Array) -> Metrics:

@@ -46,15 +46,46 @@ class AverageMeter:
         return self.sum / self.count if self.count else 0.0
 
 
+def _total_order_key(x: jnp.ndarray) -> jnp.ndarray:
+    """int32 keys that order as XLA's ``top_k`` and sort order float32:
+    totally, with -0.0 below 0.0 and NaN beyond the infinities.  They are the
+    sign-magnitude bits with the magnitude of the negatives flipped."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+def label_in_topk(logits: jnp.ndarray, labels: jnp.ndarray, k: int) -> jnp.ndarray:
+    """Whether each label is among the k largest of its ``logits`` row:
+    ``logits`` [..., classes] against ``labels`` [...] gives bool [...].
+
+    Membership is that of ``top_k`` (``jax.lax``) on the float32 logits, ties
+    included (lowest index first), but nothing is sorted: the label is ranked by
+    counting the classes ahead of it, one fused compare-and-reduce pass over
+    the class axis whatever ``k`` is (``top_k`` lowers to a full sort of the
+    row on the TPU: 83% of the LM train step at 50,000 classes).  The label's
+    logit is picked by a masked max over the array that is counted, not by a
+    gather: a sharded class axis partitions as a plain reduction, the max
+    fuses into the loss's own max pass, and XLA cannot hand back another
+    rounding of the logit (PERF.md, PR 27).  jit-safe (static k).
+    """
+    k = min(k, logits.shape[-1])  # top-5 on a <5-class head degrades gracefully
+    x = logits.astype(jnp.float32)
+    label = labels[..., None].astype(jnp.int32)
+    index = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    at_label = _total_order_key(
+        jnp.max(jnp.where(index == label, x, -jnp.inf), axis=-1, keepdims=True)
+    )
+    key = _total_order_key(x)
+    ahead = (key > at_label) | ((key == at_label) & (index < label))
+    return ahead.sum(axis=-1, dtype=jnp.int32) < k
+
+
 def topk_correct(logits: jnp.ndarray, labels: jnp.ndarray, k: int) -> jnp.ndarray:
     """Number of examples whose true label is within the top-k logits.
 
     jit-safe (static k); used inside eval steps.
     """
-    k = min(k, logits.shape[-1])
-    _, top_idx = jax.lax.top_k(logits, k)
-    hit = jnp.any(top_idx == labels[:, None], axis=-1)
-    return jnp.sum(hit.astype(jnp.float32))
+    return jnp.sum(label_in_topk(logits, labels, k), dtype=jnp.float32)
 
 
 def accuracy_topk(

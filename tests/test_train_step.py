@@ -12,6 +12,7 @@ import numpy as np
 import optax
 import pytest
 
+from distributeddeeplearning_tpu.analysis.program_audit import primitive_counts
 from distributeddeeplearning_tpu.data.synthetic import synthetic_batch
 from distributeddeeplearning_tpu.models import get_model
 from distributeddeeplearning_tpu.parallel import MeshSpec, create_mesh, shard_batch
@@ -20,9 +21,12 @@ from distributeddeeplearning_tpu.train.state import create_train_state, sgd_mome
 from distributeddeeplearning_tpu.train.step import (
     build_eval_step,
     build_train_step,
+    classification_metrics,
     cross_entropy_loss,
+    place_state,
     topk_correct,
 )
+from distributeddeeplearning_tpu.utils.metrics import label_in_topk
 
 IMG = (32, 32, 3)
 NCLS = 11
@@ -124,6 +128,166 @@ def test_topk_accuracy():
     labels = jnp.array([1, 2])
     assert float(topk_correct(logits, labels, 1)) == pytest.approx(0.5)
     assert float(topk_correct(logits, labels, 3)) == pytest.approx(1.0)
+
+
+TOPK_CLASSES = 11
+
+
+def _topk_case(shape, tie, dtype):
+    """(logits, labels) for one case.  ``tie``: "none" leaves the logits
+    continuous; else they are quantised to half-integers, so most rows tie
+    (and some hold -0.0 beside 0.0), and each label is moved to the lowest,
+    a middle or the highest index that ties with it."""
+    rng = np.random.default_rng(7)
+    if shape == "2d":
+        x = rng.normal(size=(40, TOPK_CLASSES))
+        y = rng.integers(0, TOPK_CLASSES, size=(40,))
+    else:
+        x = rng.normal(size=(4, 11, TOPK_CLASSES))
+        y = rng.integers(0, TOPK_CLASSES, size=(4, 11))
+    if tie != "none":
+        x = np.round(x * 2) / 2
+        tied = x == np.take_along_axis(x, y[..., None], -1)
+        assert (tied.sum(-1) > 1).mean() > 0.5  # most labels sit on a tie
+        ranks = np.cumsum(tied, -1)
+        pick = {"lowest": np.ones_like(y), "highest": tied.sum(-1),
+                "middle": (tied.sum(-1) + 1) // 2}[tie]
+        y = np.argmax(tied & (ranks == pick[..., None]), -1)
+    logits = jnp.asarray(x, dtype)
+    labels = jnp.asarray(y, jnp.int32)
+    if shape == "3d-slice":
+        # the LM call: shifted logits against shifted tokens, kept 3-D
+        return logits[:, :-1], labels[:, 1:]
+    return logits, labels
+
+
+@pytest.mark.parametrize("tie", ["none", "lowest", "middle", "highest"])
+@pytest.mark.parametrize("shape", ["2d", "3d", "3d-slice"])
+@pytest.mark.parametrize("k", [1, 5, TOPK_CLASSES, TOPK_CLASSES + 3])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_topk_correct_matches_lax_top_k(dtype, k, shape, tie):
+    """The compare-and-count body decides every position as ``lax.top_k``
+    does, ties included, and returns the same mean."""
+    logits, labels = _topk_case(shape, tie, dtype)
+    _, top = jax.lax.top_k(
+        logits.astype(jnp.float32), min(k, TOPK_CLASSES)
+    )
+    want = (top == labels[..., None]).any(-1)
+    np.testing.assert_array_equal(
+        np.asarray(label_in_topk(logits, labels, k)), np.asarray(want)
+    )
+    got = topk_correct(logits, labels, k)
+    assert got.shape == () and got.dtype == jnp.float32
+    assert float(got) == float(want.mean())
+
+
+def test_topk_correct_orders_specials_as_lax_top_k():
+    """NaN, the infinities and -0.0 against 0.0 rank as in ``top_k``."""
+    nan, inf = float("nan"), float("inf")
+    rows = jnp.array([[nan, 1.0, 2.0, nan], [inf, inf, -inf, 0.0],
+                      [-inf] * 4, [-nan, -0.0, 0.0, -0.0]])
+    for label in range(4):
+        labels = jnp.full((4,), label, jnp.int32)
+        for k in (1, 2, 3):
+            _, top = jax.lax.top_k(rows, k)
+            np.testing.assert_array_equal(
+                np.asarray(label_in_topk(rows, labels, k)),
+                np.asarray((top == label).any(-1)),
+            )
+
+
+def test_train_metrics_never_sort():
+    """``lax.top_k`` lowers to a full sort of the class axis on the TPU: 83%
+    of the LM step at 50,000 classes (PERF.md, PR 27).  Neither metrics
+    function of the train path may bring it back."""
+    logits = jnp.zeros((2, 6, 13), jnp.bfloat16)
+    tokens = jnp.zeros((2, 6), jnp.int32)
+
+    def lm_metrics(logits, tokens):  # as workloads/transformer.lm_metrics
+        return topk_correct(logits[:, :-1], tokens[:, 1:], 1)
+
+    def sorts(fn, *args):
+        return {"top_k", "sort"} & set(
+            primitive_counts(jax.make_jaxpr(fn)(*args).jaxpr)
+        )
+
+    # the walk sees inside a nested jit, under the names the guard forbids
+    nested = jax.jit(lambda x: (jax.lax.top_k(x, 1)[1], jnp.sort(x)))
+    assert sorts(lambda x: nested(x), logits) == {"top_k", "sort"}
+    assert not sorts(lm_metrics, logits, tokens)
+    assert not sorts(
+        classification_metrics, logits[:, 0], tokens[:, 0], jnp.float32(0.0)
+    )
+
+
+def _lm_step(devices, fsdp, tokens):
+    """One step of a tiny LM under the transformer workload's fsdp rules
+    (the head's vocabulary axis sharded over ``fsdp``); returns its metrics."""
+    from distributeddeeplearning_tpu.models.pipelined_transformer import (
+        forward,
+        init_params,
+        next_token_loss,
+    )
+    from distributeddeeplearning_tpu.train.state import TrainState
+
+    mesh = create_mesh(MeshSpec(fsdp=fsdp), devices=devices)
+    params = init_params(
+        jax.random.key(5), num_layers=2, d_model=32, num_heads=2, d_ff=64,
+        vocab_size=64, max_len=tokens.shape[1],
+    )
+    # a head far from its 0.02 init: logits spread, so top1/top5 are not ~0
+    params["head"] = params["embed"].T * 40.0
+
+    def apply_fn(variables, tokens, train=True, mutable=None, rngs=None):
+        out = forward(variables["params"], tokens, num_heads=2)
+        return (out, {}) if mutable is not None else out
+
+    tx = optax.sgd(0.1)
+    state = TrainState(
+        step=jnp.zeros((), jnp.int32), params=params,
+        opt_state=tx.init(params), batch_stats={}, apply_fn=apply_fn, tx=tx,
+    )
+    rules = [("layers", "pipe"), ("vocab", "fsdp"), ("width", "fsdp")]
+    logical_axes = {
+        "embed": ("vocab", None), "pos": None, "head": (None, "vocab"),
+        "blocks": {
+            "qkv": ("layers", None, "width"), "proj": ("layers", "width", None),
+            "w_in": ("layers", None, "width"), "w_out": ("layers", "width", None),
+            "ln1": ("layers", None), "ln2": ("layers", None),
+        },
+    }
+
+    def lm_loss(logits, labels, *, label_smoothing=0.0):
+        return next_token_loss(logits, labels)
+
+    def lm_metrics(logits, tokens, loss):
+        return {"loss": loss,
+                "top1": topk_correct(logits[:, :-1], tokens[:, 1:], 1),
+                "top5": topk_correct(logits[:, :-1], tokens[:, 1:], 5)}
+
+    step = build_train_step(
+        mesh, state, compute_dtype=jnp.float32, rules=rules,
+        logical_axes=logical_axes, loss_fn=lm_loss, metrics_fn=lm_metrics,
+    )
+    state = place_state(mesh, state, rules=rules, logical_axes=logical_axes)
+    if fsdp > 1:
+        assert "fsdp" in state.params["head"].sharding.spec[1:]
+    _, metrics = step(state, shard_batch(mesh, {"input": tokens, "label": tokens}))
+    return {name: float(value) for name, value in metrics.items()}
+
+
+def test_topk_with_sharded_vocab_equals_single_device():
+    """The label's logit is picked and the classes ahead of it are counted
+    across the shards of the vocabulary axis: same top1/top5 as one device."""
+    rng = np.random.default_rng(11)
+    tokens = rng.integers(0, 64, (8, 16)).astype(np.int32)
+    tokens[:, 1::2] = tokens[:, 0:-1:2]  # a copy task: some predictions hit
+    sharded = _lm_step(jax.devices(), 4, tokens)
+    single = _lm_step(jax.devices()[:1], 1, tokens)
+    assert 0.0 < single["top1"] < single["top5"] < 1.0
+    assert sharded["top1"] == single["top1"]
+    assert sharded["top5"] == single["top5"]
+    np.testing.assert_allclose(sharded["loss"], single["loss"], rtol=1e-5)
 
 
 def test_bert_with_dropout_trains(mesh8):
