@@ -11,6 +11,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 DRIVERS = {"serve": "serve_driver", "train": "train_driver"}
+_FAMILIES = {}  # file -> module: a family is loaded once in a process
 
 
 def _json(*parts):
@@ -51,14 +52,55 @@ def metrics_of(manifest: dict, section: str, cell_name: str):
             if "workloads" not in m or cell_name in m["workloads"]]
 
 
+def _module_at(path: str, prefix: str, name: str):
+    """The module in the file at `path` (names may hold `.` and `-`)."""
+    spec = importlib.util.spec_from_file_location(
+        prefix + name.replace(".", "_").replace("-", "_"), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def load_reader(metric_name: str):
     """A per-layer metric is a small reader of its own, found by name."""
     path = os.path.join(HERE, "layer_metrics", metric_name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "layer_metric_" + metric_name.replace(".", "_").replace("-", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return _module_at(path, "layer_metric_", metric_name).read
+
+
+def load_family(cfg: dict, needs=()):
+    """Everything that depends on the model's architecture sits behind one
+    module, `families/<family>.py`, found by the name the configuration's file
+    gives under `family` (the contract is `families/opt.py`'s docstring).
+    `needs` are the names this cell's driver will ask it for: a family that
+    lacks one stops here, before any device is touched."""
+    name = cfg.get("family")
+    if not name:
+        raise SystemExit("the configuration's file names no `family`: it has "
+                         "to, there is no default (see benchmarks/families/)")
+    folder = os.path.join(HERE, "families")
+    path = os.path.join(folder, name + ".py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"family {name!r}: no file benchmarks/families/{name}.py")
+    module = _FAMILIES.get(path)
+    if module is None:
+        if folder not in sys.path:
+            sys.path.insert(0, folder)  # a family imports its own files by name
+        module = _FAMILIES[path] = _module_at(path, "benchmark_family_", name)
+    for need in needs:
+        if not hasattr(module, need):
+            raise SystemExit(
+                f"family {name!r} (benchmarks/families/{name}.py) gives no "
+                f"`{need}`, which this cell's driver needs")
+    return module
+
+
+def family_of(ctx):
+    """The family of a reader's `ctx`. The drivers always put it there. A ctx
+    made by hand before the seam existed carries none (the repo's
+    `tests/test_trace_capture.py`, which a benchmark PR may not edit) and is
+    read as `opt`, the one family there was; a configuration never is."""
+    family = getattr(ctx, "family", None)
+    return family if family is not None else load_family({"family": "opt"})
 
 
 def require_chips(chips: int, rehearsal: bool):

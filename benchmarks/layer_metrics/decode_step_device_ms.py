@@ -2,14 +2,13 @@
 the `XLA Modules` line of the profiler trace."""
 import trace_reduce
 
-PROGRAM = r"jit__decode_fn"
-
 
 def read(ctx):
     if ctx.events is None:
         return None
     seconds, calls = trace_reduce.op_seconds(
-        ctx.events, ctx.trace_lo, ctx.trace_hi, PROGRAM, line="modules")
+        ctx.events, ctx.trace_lo, ctx.trace_hi, ctx.family.PROGRAMS["decode"],
+        line="modules")
     if not calls:
         return None
     return 1e3 * seconds / calls

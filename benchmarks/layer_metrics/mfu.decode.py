@@ -4,22 +4,20 @@ matmul parameters + attention over each token's live context) over the
 program's device time (`XLA Modules` events) and the peak. A later change that
 takes the flash-decode kernel off the path leaves its roofline silent; this
 share still bounds the claim."""
-import flops
 import harness
 import peaks
 import trace_reduce
-
-PROGRAM = r"jit__decode_fn"
 
 
 def read(ctx):
     if ctx.events is None:
         return None
     seconds, calls = trace_reduce.op_seconds(
-        ctx.events, ctx.trace_lo, ctx.trace_hi, PROGRAM, line="modules")
+        ctx.events, ctx.trace_lo, ctx.trace_hi, ctx.family.PROGRAMS["decode"],
+        line="modules")
     if not calls:
         return None
-    work = sum(flops.serve_token_flops(ctx.cfg, c)
+    work = sum(ctx.family.serve_token_flops(ctx.cfg, c)
                for c in harness.decoded_contexts_in_trace(ctx))
     if not work:
         return None

@@ -1,7 +1,6 @@
 """Model step, whole: 2 x matmul parameters x (prompt tokens computed +
 tokens generated in the window) over the window and the chips' bf16 peak.
 Attention over the live context is left out, so this is a floor."""
-import flops
 import peaks
 
 
@@ -20,4 +19,5 @@ def read(ctx):
     if not tokens:
         return None
     peak = peaks.peaks_for(ctx.device_kind)["bf16_flops"]
-    return 100.0 * 2.0 * flops.matmul_params(ctx.cfg) * tokens / seconds / (ctx.chips * peak)
+    return (100.0 * 2.0 * ctx.family.matmul_params(ctx.cfg) * tokens / seconds
+            / (ctx.chips * peak))

@@ -1,7 +1,6 @@
 """Model step, whole: analytic forward+backward FLOPs per token (6 x matmul
 parameters + causal attention; recomputed work not counted) x tokens per
 second over the chips' bf16 peak."""
-import flops
 import peaks
 
 
@@ -10,5 +9,5 @@ def read(ctx):
     if not rate:
         return None
     peak = peaks.peaks_for(ctx.device_kind)["bf16_flops"]
-    per_token = flops.train_token_flops(ctx.cfg, ctx.mix["seq_len"])
+    per_token = ctx.family.train_token_flops(ctx.cfg, ctx.mix["seq_len"])
     return 100.0 * per_token * rate / (ctx.chips * peak)

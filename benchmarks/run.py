@@ -3,8 +3,10 @@
     python benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 It finds the cell in BENCHMARK.json, its configuration, its traffic mix and
-its per-layer metrics by name, and picks the driver by the mix's `kind`. One
-process holds the chip. The last line of standard output is the result.
+its per-layer metrics by name, picks the driver by the mix's `kind`, and finds
+everything that depends on the model's architecture by the `family` the
+configuration's file names. One process holds the chip. The last line of
+standard output is the result.
 """
 import time
 
@@ -48,14 +50,21 @@ def main(argv=None):
         cfg = _merge(cfg, over.get("config", {}))
         mix = _merge(mix, over.get("traffic", {}))
         limits = _merge(limits, over.get("limits", {}))
+        # stand-in peaks for the rehearsal's device, so that the readers of a
+        # share of the peak run end to end off the chip too
+        import peaks
+
+        peaks.PEAKS.update(over.get("peaks", {}))
     if mix["kind"] not in harness.DRIVERS:
         raise SystemExit(f"no driver for traffic kind {mix['kind']!r}")
     driver = __import__(harness.DRIVERS[mix["kind"]])
+    family = harness.load_family(cfg, needs=driver.NEEDS)
     devices = harness.require_chips(cell["chips"], bool(args.rehearsal))
     harness.enable_compile_cache()
     result = driver.run(
-        manifest=manifest, cell=cell, cfg=cfg, mix=mix, limits=limits,
-        args=args, devices=devices, t_process_start=T_PROCESS_START,
+        manifest=manifest, cell=cell, cfg=cfg, family=family, mix=mix,
+        limits=limits, args=args, devices=devices,
+        t_process_start=T_PROCESS_START,
     )
     if args.rehearsal:
         # a run off the chip prints nothing under a device metric's name

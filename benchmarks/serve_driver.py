@@ -1,6 +1,6 @@
 """Drives a serve cell: an open loop of requests, each due at its own time,
-through the program's `ContinuousBatchingScheduler.run` over a
-`PagedInferenceEngine`, in one process and one thread.
+through the scheduler's `run` over the engine that the configuration's family
+builds (`families/<family>.py`: `build_serve`), in one process and one thread.
 
 End-to-end numbers are the harness's own, by its own clock: a request's first
 token is timed from when the request was DUE, tails are over all requests due
@@ -15,25 +15,9 @@ import harness
 import traffic_gen
 
 
-def _build(cfg, params):
-    from distributeddeeplearning_tpu.serve.engine import PagedInferenceEngine
-    from distributeddeeplearning_tpu.serve.scheduler import (
-        ContinuousBatchingScheduler,
-    )
-
-    geo = cfg["serving"]
-    engine = PagedInferenceEngine(
-        params,
-        num_heads=cfg["num_attention_heads"],
-        batch_slots=geo["batch_slots"],
-        max_seq=geo["max_seq"],
-        page_size=geo["page_size"],
-        num_pages=geo["kv_pages"],
-        prefill_chunk=geo["prefill_chunk"],
-        decode_kernel=geo["decode_kernel"],
-        prefix_cache=geo["prefix_cache"],
-    )
-    return engine, ContinuousBatchingScheduler(engine, eos_id=None)
+#: what this driver and the serve cells' whole-step readers ask of a family
+NEEDS = ("make_params", "build_serve", "served_token_gaps", "matmul_params",
+         "serve_token_flops", "PROGRAMS")
 
 
 def _warm_up(engine, scheduler, schedule, mix, vocab):
@@ -65,10 +49,10 @@ def _warm_up(engine, scheduler, schedule, mix, vocab):
         raise RuntimeError(f"warm-up requests did not finish: {bad}")
 
 
-def run(*, manifest, cell, cfg, mix, limits, args, devices, t_process_start):
+def run(*, manifest, cell, cfg, family, mix, limits, args, devices,
+        t_process_start):
     import jax
 
-    import weights
     from distributeddeeplearning_tpu.serve.scheduler import Request
 
     compiles = harness.CompileCounter()
@@ -78,9 +62,9 @@ def run(*, manifest, cell, cfg, mix, limits, args, devices, t_process_start):
     schedule = traffic_gen.serve_schedule(mix, vocab_size=vocab, seed=args.seed,
                                           seconds=seconds)
     with jax.default_device(devices[0]):
-        params = jax.block_until_ready(weights.make_params(args.seed, cfg))
+        params = jax.block_until_ready(family.make_params(args.seed, cfg))
     phases["weights_s"] = time.perf_counter() - t_process_start
-    engine, scheduler = _build(cfg, params)
+    engine, scheduler = family.build_serve(cfg, params)
     phases["engine_s"] = time.perf_counter() - t_process_start
     _warm_up(engine, scheduler, schedule, mix, vocab)
     phases["warm_up_s"] = time.perf_counter() - t_process_start
@@ -150,8 +134,9 @@ def run(*, manifest, cell, cfg, mix, limits, args, devices, t_process_start):
     # -- per-layer numbers (traced run) ---------------------------------------
     events = tracer.events()
     ctx = harness.context(
-        cell=cell, cfg=cfg, mix=mix, chips=len(devices), seconds=seconds,
-        device_kind=device["kind"], events=events, enclosing_mark="bench/scheduler.run", tracer=tracer, t0=t0,
+        cell=cell, cfg=cfg, family=family, mix=mix, chips=len(devices),
+        seconds=seconds, device_kind=device["kind"], events=events,
+        enclosing_mark="bench/scheduler.run", tracer=tracer, t0=t0,
         schedule=schedule, released=released, token_times=token_times,
         done=done, finished=finished, report=report, engine_stats={
             "prefix_hit_tokens": engine.prefix_hit_tokens,
@@ -172,8 +157,8 @@ def run(*, manifest, cell, cfg, mix, limits, args, devices, t_process_start):
         "compiles_in_window": {"value": compiles_in_window, "limit": 0},
         "failed_requests": {"value": failed, "limit": 0},
     }
-    compared, stand_ins = _compare(cfg, mix, limits, params, finished, by_uid,
-                                   args.seed, bool(args.control))
+    compared, stand_ins = _compare(cfg, family, mix, limits, params, finished,
+                                   by_uid, args.seed, bool(args.control))
     checks.update(compared)
     result["correct"] = harness.judge(checks)
     harness.judge_stand_ins(result, checks, stand_ins)
@@ -188,14 +173,12 @@ def run(*, manifest, cell, cfg, mix, limits, args, devices, t_process_start):
     return result
 
 
-def _compare(cfg, mix, limits, params, finished, by_uid, seed, control):
+def _compare(cfg, family, mix, limits, params, finished, by_uid, seed, control):
     """A sample of the finished requests, drawn from the seed, with the
-    longest in it: the reference runs once over each prompt with its served
-    tokens; the number compared is the widest gap by which a served token's
-    logit lies below the reference's best."""
+    longest in it: the family's reference runs once over each prompt with its
+    served tokens; the number compared is the widest gap by which a served
+    token's logit lies below the reference's best."""
     import jax.numpy as jnp
-
-    import reference
 
     if not finished:
         return {"token_gap_max": {"value": None,
@@ -213,9 +196,8 @@ def _compare(cfg, mix, limits, params, finished, by_uid, seed, control):
         seq = (prompt + served)[:width]
         tokens = np.zeros(width, np.int32)
         tokens[: len(seq)] = seq
-        g, low, std = reference.served_token_gaps(
-            params, jnp.asarray(tokens),
-            num_heads=cfg["num_attention_heads"],
+        g, low, std = family.served_token_gaps(
+            params, jnp.asarray(tokens), cfg,
             precision=limits["control_precision"] if control else "float32")
         lo, hi = len(prompt) - 1, len(seq) - 1  # positions that predict served tokens
         gaps.append(np.asarray(g)[lo:hi])

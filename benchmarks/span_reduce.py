@@ -16,6 +16,7 @@ metric is left out.
 import collections
 import sys
 
+import harness
 import trace_reduce
 
 MAX_CLOCK_GAP_S = 1e-3
@@ -28,7 +29,6 @@ DECODE_FETCH = "serve/engine.decode_fetch"
 #: time is attributed to (`serve/decode_step` is its three engine spans)
 TURN_SPANS = ("serve/poll", "serve/admission", PREFILL_CHUNK, DECODE_UPLOAD,
               DECODE_DISPATCH, DECODE_FETCH, "serve/emit")
-DECODE_PROGRAM = "jit__decode_fn"  # as `decode_step_device_ms` finds it
 
 #: start and end in seconds on the trace's clock, clipped to the window;
 #: `whole` is false for a span the window's edge cut
@@ -173,19 +173,23 @@ def overlap_s(intervals, segments):
     return out
 
 
-def plane_shift(ctx, spans):
+def plane_shift(ctx, spans, decode_program=None):
     """Seconds to add to device 0's times to put them on the host plane's
-    clock. The profiler aligns the two planes only to about a millisecond
-    (a decode program has been seen to start 0.9 ms before the span that
-    dispatches it), which matters where a turn's idle time is 5 ms. Causality
+    clock; `decode_program` is the decode program's name in the trace, by
+    default the `PROGRAMS["decode"]` of the ctx's family. The profiler aligns
+    the two planes only to about a millisecond (a decode program has been
+    seen to start 0.9 ms before the span that dispatches it), which matters
+    where a turn's idle time is 5 ms. Causality
     bounds the shift from both sides: a decode program cannot start before
     its `serve/engine.decode_dispatch` span does (`lower`), nor end after the
     `serve/engine.decode_fetch` span that reads its result (`upper`). The
     shift is the value between the two that is nearest to 0, and 0 where they
     cross. Returns (shift, lower, upper)."""
+    if decode_program is None:
+        decode_program = harness.family_of(ctx).PROGRAMS["decode"]
     device = ctx.events["devices"][min(ctx.events["devices"])]
     programs = sorted((a, a + d) for name, a, d in device["modules"]
-                      if DECODE_PROGRAM in name)
+                      if decode_program in name)
     lower, upper = -float("inf"), float("inf")
     dispatches = [s for s in spans if s.name == DECODE_DISPATCH and s.whole]
     fetches = [s for s in spans if s.name == DECODE_FETCH and s.whole]

@@ -31,8 +31,9 @@ def drive(cell_name, driver, seed=11, seconds=2.0, control=0):
     args = argparse.Namespace(seed=seed, seconds=seconds, trace=0, control=control)
     devices = harness.require_chips(1, rehearsal=True)
     limits = run_module._merge(harness.load_limits(cell_name), over["limits"])
-    return driver.run(manifest=manifest, cell=cell, cfg=cfg, mix=mix,
-                      limits=limits, args=args, devices=devices,
+    family = harness.load_family(cfg, needs=driver.NEEDS)
+    return driver.run(manifest=manifest, cell=cell, cfg=cfg, family=family,
+                      mix=mix, limits=limits, args=args, devices=devices,
                       t_process_start=time.perf_counter())
 
 
@@ -72,13 +73,14 @@ def test_serve_altered_token_is_not_correct(monkeypatch):
 
 
 def _broken_build(monkeypatch, wrap):
-    sound = train_driver.build
+    family = harness.load_family({"family": "opt"})
+    sound = family.build_train
 
-    def build(cfg, job, devices, params):
+    def build_train(cfg, job, devices, params):
         mesh, step, state = sound(cfg, job, devices, params)
         return mesh, wrap(step), state
 
-    monkeypatch.setattr(train_driver, "build", build)
+    monkeypatch.setattr(family, "build_train", build_train)
 
 
 def test_train_sound_run_is_correct_and_control_is_not():

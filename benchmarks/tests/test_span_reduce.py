@@ -13,6 +13,7 @@ import trace_reduce as tr
 
 EPOCH = 5000.0       # the tracer's epoch on the perf_counter clock
 OFFSET = -4999.25    # trace clock = perf_counter + OFFSET
+DECODE = "jit__decode_fn"  # the decode program, as the recorded trace names it
 
 # (name, start, end, depth) on the TRACE's clock, in seconds: one turn that
 # carries a prefill chunk, then the next turn's first 12 ms
@@ -93,7 +94,7 @@ def test_turn_table_of_the_one_whole_turn(ctx):
 
 def idle_by_span(ctx, spans):
     """As the `idle_unattributed.serve` reader wires it."""
-    idle = sr.shifted_idle(ctx, sr.plane_shift(ctx, spans)[0])
+    idle = sr.shifted_idle(ctx, sr.plane_shift(ctx, spans, DECODE)[0])
     return sr.idle_by_span(idle, spans, sr.innermost_segments(spans))
 
 
@@ -147,11 +148,11 @@ def test_a_device_plane_that_leads_is_shifted_back(ctx):
     the idle time lands in the same spans."""
     spans = sr.program_spans(ctx)
     before = idle_by_span(ctx, spans)
-    assert sr.plane_shift(ctx, spans)[0] == 0.0
+    assert sr.plane_shift(ctx, spans, DECODE)[0] == 0.0
     for device in ctx.events["devices"].values():
         for line in device:
             device[line] = [(n, a - 0.0009, d) for n, a, d in device[line]]
-    shift, lower, upper = sr.plane_shift(ctx, spans)
+    shift, lower, upper = sr.plane_shift(ctx, spans, DECODE)
     assert shift == lower == pytest.approx(0.0009 - 0.000034, abs=2e-6)
     assert upper == pytest.approx(0.0009 + 0.00228, abs=2e-5)
     total, named, by_span = idle_by_span(ctx, spans)

@@ -1,6 +1,7 @@
 """Drives a train cell: the step that the program's `build_train_step`
-returns, built the way `workloads/transformer.main` builds it, in the
-harness's own loop (not `Trainer.fit`, which cannot be stopped after N seconds
+returns, built by the configuration's family (`families/<family>.py`:
+`build_train`) the way the workload's `main` builds it, in the harness's own
+loop (not `Trainer.fit`, which cannot be stopped after N seconds
 without a change to the program).
 
 Set-up builds ONE object, the compiled step with its state, drives it from the
@@ -15,6 +16,9 @@ import harness
 import traffic_gen
 
 CHECK_STEPS = 3
+#: what this driver and the train cells' whole-step reader ask of a family
+NEEDS = ("make_params", "param_shapes", "build_train", "loss_and_grads",
+         "split_layers", "train_token_flops", "PROGRAMS")
 
 
 def _schedule_lr(job, count):
@@ -26,89 +30,15 @@ def _schedule_lr(job, count):
     return job["base_lr"] * max(1.0 - (count - warm) / rest, 0.0)
 
 
-def build(cfg, job, devices, params):
-    """(mesh, step, state): the program's train step over `devices`."""
-    import jax
-    import jax.numpy as jnp
-
-    from distributeddeeplearning_tpu.models.pipelined_transformer import (
-        forward, next_token_loss)
-    from distributeddeeplearning_tpu.parallel import MeshSpec, create_mesh
-    from distributeddeeplearning_tpu.train.schedule import (
-        warmup_linear_decay_schedule)
-    from distributeddeeplearning_tpu.train.state import TrainState, adamw
-    from distributeddeeplearning_tpu.train.step import (
-        build_train_step, place_state, topk_correct)
-
-    heads = cfg["num_attention_heads"]
-    fsdp = job.get("fsdp", 1)
-    mesh = create_mesh(MeshSpec(fsdp=fsdp), devices=devices)
-    dtype = jnp.bfloat16 if job["compute_dtype"] == "bfloat16" else jnp.float32
-    attention, attention_fn = job["attention"], None
-    if attention == "flash" and mesh.devices.size > 1:
-        from distributeddeeplearning_tpu.ops import make_flash_attention
-
-        attention_fn = make_flash_attention(mesh=mesh, causal=True)
-    remat = bool(job.get("remat", False))
-
-    def apply_fn(variables, tokens, train=True, mutable=None, rngs=None):
-        p = jax.tree_util.tree_map(
-            lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating)
-            else a, variables["params"])
-        out = forward(p, tokens, num_heads=heads, attention=attention,
-                      attention_fn=attention_fn, remat=remat).astype(jnp.float32)
-        return (out, {}) if mutable is not None else out
-
-    schedule = warmup_linear_decay_schedule(
-        job["base_lr"], job["total_steps"], warmup_fraction=job["warmup_fraction"])
-    tx = adamw(schedule, weight_decay=job["weight_decay"],
-               grad_clip_norm=job["grad_clip_norm"])
-    abstract = not isinstance(jax.tree_util.tree_leaves(params)[0], jax.Array)
-    state = TrainState(
-        step=jax.ShapeDtypeStruct((), jnp.int32) if abstract
-        else jnp.zeros((), jnp.int32),
-        params=params,
-        opt_state=jax.eval_shape(tx.init, params) if abstract else tx.init(params),
-        batch_stats={}, apply_fn=apply_fn, tx=tx,
-    )
-    rules = [("layers", "pipe"), ("vocab", "fsdp"), ("width", "fsdp")]
-    logical_axes = {
-        "embed": ("vocab", None), "pos": None, "head": (None, "vocab"),
-        "blocks": {
-            "qkv": ("layers", None, "width"), "proj": ("layers", "width", None),
-            "w_in": ("layers", None, "width"), "w_out": ("layers", "width", None),
-            "ln1": ("layers", None), "ln2": ("layers", None),
-        },
-    }
-
-    def lm_loss(logits, labels, *, label_smoothing=0.0):
-        return next_token_loss(logits, labels)
-
-    def lm_metrics(logits, tokens, loss):
-        return {"loss": loss.astype(jnp.float32),
-                "top1": topk_correct(logits[:, :-1], tokens[:, 1:], 1),
-                "perplexity": jnp.exp(loss).astype(jnp.float32)}
-
-    step = build_train_step(
-        mesh, state, schedule=schedule, compute_dtype=dtype, rules=rules,
-        logical_axes=logical_axes, loss_fn=lm_loss, metrics_fn=lm_metrics,
-        rng=jax.random.key(1),
-    )
-    if not abstract:
-        state = place_state(mesh, state, rules=rules, logical_axes=logical_axes)
-    return mesh, step, state
-
-
-def aot_compile(cfg, job, devices):
+def aot_compile(cfg, family, job, devices):
     """Compile the step for described devices (tools/aot_compile.py)."""
     import jax
     import jax.numpy as jnp
 
-    import weights
     from distributeddeeplearning_tpu.parallel.sharding import batch_sharding
 
-    params = weights.param_shapes(cfg)
-    mesh, step, state = build(cfg, job, devices, params)
+    params = family.param_shapes(cfg)
+    mesh, step, state = family.build_train(cfg, job, devices, params)
     rows = job["rows_per_chip"] * len(devices)
     toks = jax.ShapeDtypeStruct((rows, job["seq_len"]), jnp.int32,
                                 sharding=batch_sharding(mesh))
@@ -126,11 +56,11 @@ def _adam_state(opt_state):
     return found[0]
 
 
-def run(*, manifest, cell, cfg, mix, limits, args, devices, t_process_start):
+def run(*, manifest, cell, cfg, family, mix, limits, args, devices,
+        t_process_start):
     import jax
     import jax.numpy as jnp
 
-    import weights
     from distributeddeeplearning_tpu.parallel import shard_batch
 
     job = mix
@@ -141,9 +71,9 @@ def run(*, manifest, cell, cfg, mix, limits, args, devices, t_process_start):
     tokens_per_step = rows * job["seq_len"]
 
     phases = {"imports_s": time.perf_counter() - t_process_start}
-    params = jax.block_until_ready(weights.make_params(args.seed, cfg))
+    params = jax.block_until_ready(family.make_params(args.seed, cfg))
     phases["weights_s"] = time.perf_counter() - t_process_start
-    mesh, step, state = build(cfg, job, devices, params)
+    mesh, step, state = family.build_train(cfg, job, devices, params)
     del params  # the step donates its state: the reference makes its own copy
 
     def feed(k):
@@ -201,8 +131,9 @@ def run(*, manifest, cell, cfg, mix, limits, args, devices, t_process_start):
     }
     events = tracer.events()
     ctx = harness.context(
-        cell=cell, cfg=cfg, mix=job, chips=len(devices), seconds=seconds,
-        device_kind=device["kind"], events=events, enclosing_mark="bench/train loop", tracer=tracer, t0=t0,
+        cell=cell, cfg=cfg, family=family, mix=job, chips=len(devices),
+        seconds=seconds, device_kind=device["kind"], events=events,
+        enclosing_mark="bench/train loop", tracer=tracer, t0=t0,
         rows=rows, tokens_per_step=tokens_per_step, done_steps=done_steps,
         window_s=window_s,
     )
@@ -217,8 +148,8 @@ def run(*, manifest, cell, cfg, mix, limits, args, devices, t_process_start):
         "last_loss_finite": {"value": 0.0 if last_loss is not None and
                              np.isfinite(last_loss) else 1.0, "limit": 0},
     }
-    compared, stand_ins = _compare(cfg, job, limits, args.seed, rows, losses,
-                                   kept, bool(args.control))
+    compared, stand_ins = _compare(cfg, family, job, limits, args.seed, rows,
+                                   losses, kept, bool(args.control))
     checks.update(compared)
     result["correct"] = harness.judge(checks)
     harness.judge_stand_ins(result, checks, stand_ins)
@@ -230,22 +161,11 @@ def run(*, manifest, cell, cfg, mix, limits, args, devices, t_process_start):
     return result
 
 
-def _split_layers(tree):
-    """name -> array, the stacked block leaves split per layer."""
-    out = {}
-    for name in ("embed", "pos", "head"):
-        out[name] = tree[name]
-    for name, leaf in tree["blocks"].items():
-        for layer in range(leaf.shape[0]):
-            out[f"blocks.{name}.{layer}"] = leaf[layer]
-    return out
-
-
-def _norms(tree):
+def _norms(family, tree):
     import jax.numpy as jnp
 
     return {k: float(jnp.sqrt(jnp.sum(jnp.square(v.astype(jnp.float32)))))
-            for k, v in _split_layers(tree).items()}
+            for k, v in family.split_layers(tree).items()}
 
 
 def worst_leaf_gap(prog: dict, ref: dict, leave_out=()):
@@ -262,18 +182,18 @@ def worst_leaf_gap(prog: dict, ref: dict, leave_out=()):
     return worst, at
 
 
-def reference_steps(cfg, job, seed, rows, precision="float32", rows_used=None):
-    """Three steps of the plain reference from the seed: (losses, clipped
-    first gradient, parameters' change after the three). `rows_used` plants
+def reference_steps(cfg, family, job, seed, rows, precision="float32",
+                    rows_used=None):
+    """Three steps of the family's plain reference from the seed: (losses,
+    clipped first gradient, parameters' change after the three). `rows_used` plants
     the fault "half of the batch left out, the mean taken over the rest"."""
     import jax
     import jax.numpy as jnp
 
     import reference
-    import weights
 
-    heads, vocab = cfg["num_attention_heads"], cfg["vocab_size"]
-    params0 = weights.make_params(seed, cfg)
+    vocab = cfg["vocab_size"]
+    params0 = family.make_params(seed, cfg)
     params = params0
     mu = jax.tree_util.tree_map(jnp.zeros_like, params)
     nu = jax.tree_util.tree_map(jnp.zeros_like, params)
@@ -283,8 +203,8 @@ def reference_steps(cfg, job, seed, rows, precision="float32", rows_used=None):
                                        rows=rows)
         if rows_used is not None:
             toks = toks[:rows_used]
-        loss, grads = reference.loss_and_grads(
-            params, jnp.asarray(toks), num_heads=heads, precision=precision)
+        loss, grads = family.loss_and_grads(
+            params, jnp.asarray(toks), cfg, precision=precision)
         params, mu, nu, clipped = reference.adamw_step(
             params, mu, nu, grads, k, _schedule_lr(job, k),
             b1=0.9, b2=0.999, eps=1e-6, weight_decay=job["weight_decay"],
@@ -293,7 +213,7 @@ def reference_steps(cfg, job, seed, rows, precision="float32", rows_used=None):
         if k == 0:
             first_grad = clipped
     change = jax.tree_util.tree_map(jnp.subtract, params, params0)
-    return losses, _norms(first_grad), _norms(change)
+    return losses, _norms(family, first_grad), _norms(family, change)
 
 
 def gaps(prog, ref):
@@ -314,20 +234,18 @@ def gaps(prog, ref):
     return out
 
 
-def _compare(cfg, job, limits, seed, rows, losses, kept, control):
+def _compare(cfg, family, job, limits, seed, rows, losses, kept, control):
     import jax
     import jax.numpy as jnp
 
-    import weights
-
     b1 = 0.9
     grad1 = jax.tree_util.tree_map(lambda m: m / (1.0 - b1), kept["mu1"])
-    params0 = weights.make_params(seed, cfg)
+    params0 = family.make_params(seed, cfg)
     change = jax.tree_util.tree_map(jnp.subtract, kept["params3"], params0)
-    prog = (losses, _norms(grad1), _norms(change))
+    prog = (losses, _norms(family, grad1), _norms(family, change))
     del grad1, change, params0
     kept.clear()
-    ref = reference_steps(cfg, job, seed, rows)
+    ref = reference_steps(cfg, family, job, seed, rows)
     out = {}
     numbers = gaps(prog, ref)
     for name, value in numbers.items():
@@ -343,7 +261,8 @@ def _compare(cfg, job, limits, seed, rows, losses, kept, control):
         # job's, and with half of the batch left out
         for label, kw in (("control", {"precision": limits["control_precision"]}),
                           ("halfbatch", {"rows_used": rows // 2})):
-            stood = gaps(reference_steps(cfg, job, seed, rows, **kw), ref)
+            stood = gaps(reference_steps(cfg, family, job, seed, rows, **kw),
+                         ref)
             stand_ins[label] = {name: value for name, value in stood.items()
                                 if not name.endswith("_at")}
     return out, stand_ins
