@@ -370,6 +370,16 @@ def build_parser() -> argparse.ArgumentParser:
     serve_p.add_argument("--checkpoint-dir", default=None,
                          help="orbax checkpoint dir (train/checkpoint.py); "
                          "restores the latest step's params")
+    serve_p.add_argument("--model-config", default=None, metavar="JSON",
+                         help="serve a decoder that mixes window and full "
+                         "attention layers with sparse experts "
+                         "(models/hybrid_moe_transformer.py), at the sizes "
+                         "of this configuration file under the model's "
+                         "published keys (benchmarks/configs/"
+                         "mimo-v2-flash.json is one), seeded weights; needs "
+                         "--kv-layout paged --no-prefix-cache, and refuses "
+                         "the int8 pool, the host tier, --speculative and "
+                         "--replicas > 1")
     serve_p.add_argument("--prefill-attention", default="flash",
                          choices=("flash", "dense"),
                          help="prompt-pass attention (decode is always "
@@ -1546,6 +1556,40 @@ def _cmd_serve(args) -> int:
     # restored model's real vocab/position table, not the dim flags.
     params = None
     ckpt_vocab = ckpt_max_len = None
+    served_model = None
+    if args.model_config:
+        # another architecture through the same engine and scheduler: the
+        # engine takes it as one description and refuses, by name, what it
+        # cannot serve for it yet; what never reaches the engine stops here
+        for flag, bad in (
+            ("--kv-layout dense", args.kv_layout != "paged"),
+            ("--checkpoint-dir", bool(args.checkpoint_dir)),
+            ("--quantize-weights", args.quantize_weights is not None),
+            ("--speculative", args.speculative),
+            ("--replicas > 1", args.replicas > 1),
+        ):
+            if bad:
+                print(f"--model-config cannot run with {flag} yet",
+                      file=sys.stderr)
+                return 1
+        import jax.numpy as jnp
+
+        from distributeddeeplearning_tpu.models import (
+            hybrid_moe_transformer as hybrid,
+        )
+        from distributeddeeplearning_tpu.serve.served_model import (
+            hybrid_model,
+        )
+
+        with open(args.model_config) as f:
+            model_cfg = _json.load(f)
+        spec = hybrid.spec_from_config(model_cfg)
+        served_model = hybrid_model(spec)
+        params = hybrid.init_params(
+            jax.random.key(args.seed), spec,
+            dtype=jnp.dtype(model_cfg.get("storage_dtype", "bfloat16")),
+        )
+        ckpt_vocab = spec.vocab_size
     if args.checkpoint_dir:
         if args.num_heads is None:
             # a wrong-but-dividing default would reshape K/V into the
@@ -1842,21 +1886,28 @@ def _cmd_serve(args) -> int:
         # single-mesh: the block-table gather crosses the page axis, so
         # the paged pool does not shard over devices (the dense layout
         # remains the multi-chip path)
-        engine, mesh = PagedInferenceEngine(
-            params,
-            num_heads=num_heads,
-            batch_slots=args.batch_slots,
-            max_seq=max_seq,
-            page_size=args.page_size,
-            num_pages=args.kv_pages,
-            prefill_chunk=args.prefill_chunk,
-            temperature=args.temperature,
-            top_k=args.top_k,
-            cache_dtype=cache_dtype,
-            rng=jax.random.key(args.seed),
-            prefix_cache=not args.no_prefix_cache,
-            decode_kernel=args.decode_kernel,
-        ), None
+        from distributeddeeplearning_tpu.serve.served_model import Refused
+
+        try:
+            engine, mesh = PagedInferenceEngine(
+                params,
+                num_heads=None if served_model is not None else num_heads,
+                model=served_model,
+                batch_slots=args.batch_slots,
+                max_seq=max_seq,
+                page_size=args.page_size,
+                num_pages=args.kv_pages,
+                prefill_chunk=args.prefill_chunk,
+                temperature=args.temperature,
+                top_k=args.top_k,
+                cache_dtype=cache_dtype,
+                rng=jax.random.key(args.seed),
+                prefix_cache=not args.no_prefix_cache,
+                decode_kernel=args.decode_kernel,
+            ), None
+        except Refused as exc:
+            print(f"[serve] {exc}", file=sys.stderr)
+            return 1
     elif args.speculative:
         # spec is single-mesh (the verify/rollback programs carry no
         # sharding annotations) — build the dense engine unmeshed

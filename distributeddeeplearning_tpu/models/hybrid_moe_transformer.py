@@ -1,0 +1,525 @@
+"""A decoder whose layers are of two attention kinds, with sparse experts.
+
+The pure-function model behind the paged serving engine for architectures
+that mix **window** and **full** attention layers in one stack (each kind
+with its own KV head count, rotary base and optional learned sink), use
+grouped-query attention whose keys and values differ in width, rotate only
+a leading part of every head, normalise with RMS norm, and run a gated FFN
+that in most layers is a **mixture of experts** of which this chip holds a
+stated subset.
+
+Everything about the architecture is in one hashable :class:`HybridSpec`;
+parameters are a plain pytree with one dict per layer (no stacking: every
+weight is a buffer of its own, so no step slices a stack of them).  There
+is **one block function**, :func:`block`, which takes the layer's index and
+an *attention callback*; the two forwards the paged engine needs,
+:func:`forward_prefill_chunk` and :func:`forward_decode`, differ only in
+the callbacks they hand it (how a layer's cache is written and read).
+
+Numerics: weights and cache in the parameters' dtype (bfloat16 in
+serving), every matmul accumulating in float32, the residual stream, the
+norms, the softmax and the whole router in float32.
+
+The cache is of two kinds under one pytree (``serve.kv_cache.
+init_hybrid_cache``): full layers own a paged pool ``[pages, page_size,
+kv_heads * width]`` (heads folded into the minor axis, so a bfloat16 page
+tiles without padding) addressed through block tables; window layers own a
+ring ``[slots, window, kv_heads * width]`` written at ``pos mod window`` and
+masked by absolute position, so their bytes do not grow with the sequence.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from distributeddeeplearning_tpu.ops import flash_decode as _fd
+
+PyTree = Any
+FULL, WINDOW = 0, 1
+DENSE, EXPERTS = 0, 1
+#: what the expert layers count in a decode step (the order of the step's
+#: small integer vector): pairs (token, expert) the router made over live
+#: lanes, pairs that landed on held experts, the fullest held expert's
+#: tokens and the held experts touched, each summed over the expert layers
+EXPERT_COUNTS = ("pairs_total", "pairs_here", "tokens_max", "experts_touched")
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridSpec:
+    """The architecture, as static numbers (hashable: a jit static)."""
+
+    vocab_size: int
+    d_model: int
+    num_q_heads: int
+    k_dim: int                      # width of a query/key head
+    v_dim: int                      # width of a value head
+    rotary_dim: int                 # leading dims of a head that rotate
+    kv_heads_full: int
+    kv_heads_window: int
+    window: int
+    theta_full: float
+    theta_window: float
+    sink_full: bool
+    sink_window: bool
+    value_scale: float
+    eps: float
+    attn_kinds: Tuple[int, ...]     # per layer: FULL or WINDOW
+    ffn_kinds: Tuple[int, ...]      # per layer: DENSE or EXPERTS
+    d_ff: int                       # dense FFN width
+    d_expert: int                   # one expert's width
+    num_experts: int                # the router's outputs
+    experts_per_token: int
+    experts_held: Tuple[int, ...]   # ids of the experts this chip holds
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+
+    def __post_init__(self):
+        if len(self.attn_kinds) != len(self.ffn_kinds):
+            raise ValueError("attn_kinds and ffn_kinds differ in length")
+        for name, kv in (("full", self.kv_heads_full),
+                         ("window", self.kv_heads_window)):
+            if self.num_q_heads % kv:
+                raise ValueError(
+                    f"{self.num_q_heads} query heads not divisible by the "
+                    f"{name} layers' {kv} KV heads"
+                )
+        if self.rotary_dim % 2 or self.rotary_dim > self.k_dim:
+            raise ValueError(f"rotary_dim {self.rotary_dim} must be even "
+                             f"and at most k_dim {self.k_dim}")
+        if any(not 0 <= e < self.num_experts for e in self.experts_held) or (
+                len(set(self.experts_held)) != len(self.experts_held)):
+            raise ValueError("experts_held must be distinct ids below "
+                             f"num_experts {self.num_experts}")
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.attn_kinds)
+
+    def kv_heads(self, kind: int) -> int:
+        return self.kv_heads_window if kind == WINDOW else self.kv_heads_full
+
+    def theta(self, kind: int) -> float:
+        return self.theta_window if kind == WINDOW else self.theta_full
+
+    def has_sink(self, kind: int) -> bool:
+        return self.sink_window if kind == WINDOW else self.sink_full
+
+    def layers_of(self, kind: int) -> Tuple[int, ...]:
+        return tuple(i for i, k in enumerate(self.attn_kinds) if k == kind)
+
+    def index_in_kind(self, layer: int) -> int:
+        """The layer's index among the layers of its attention kind (its
+        place in that kind's cache)."""
+        kind = self.attn_kinds[layer]
+        return sum(1 for k in self.attn_kinds[:layer] if k == kind)
+
+
+def spec_from_config(cfg: dict) -> HybridSpec:
+    """A :class:`HybridSpec` from a configuration under its published keys
+    (``hybrid_layer_pattern``, ``swa_num_key_value_heads``, ...); with
+    ``layers_kept`` the layers run are those of the published patterns.
+    ``n_routed_experts`` counts the experts held here; ``experts_held``
+    their ids and ``n_routed_experts_published`` the router's width (both
+    default to "all of them")."""
+    held = cfg.get("experts_held")
+    n_router = cfg.get("n_routed_experts_published", cfg["n_routed_experts"])
+    if held is None:
+        held = list(range(cfg["n_routed_experts"]))
+    layers = cfg["num_hidden_layers"]
+    # a file may keep the published per-layer patterns whole and say which
+    # of the published layers it runs
+    kept = cfg.get("layers_kept", range(layers))
+    return HybridSpec(
+        vocab_size=cfg["vocab_size"],
+        d_model=cfg["hidden_size"],
+        num_q_heads=cfg["num_attention_heads"],
+        k_dim=cfg["head_dim"],
+        v_dim=cfg["v_head_dim"],
+        rotary_dim=int(cfg["head_dim"] * cfg["partial_rotary_factor"]),
+        kv_heads_full=cfg["num_key_value_heads"],
+        kv_heads_window=cfg["swa_num_key_value_heads"],
+        window=cfg["sliding_window"],
+        theta_full=float(cfg["rope_theta"]),
+        theta_window=float(cfg["swa_rope_theta"]),
+        sink_full=bool(cfg["add_full_attention_sink_bias"]),
+        sink_window=bool(cfg["add_swa_attention_sink_bias"]),
+        value_scale=float(cfg["attention_value_scale"]),
+        eps=float(cfg["layernorm_epsilon"]),
+        attn_kinds=tuple(cfg["hybrid_layer_pattern"][i] for i in kept),
+        ffn_kinds=tuple(cfg["moe_layer_freq"][i] for i in kept),
+        d_ff=cfg["intermediate_size"],
+        d_expert=cfg["moe_intermediate_size"],
+        num_experts=n_router,
+        experts_per_token=cfg["num_experts_per_tok"],
+        experts_held=tuple(held),
+        norm_topk=bool(cfg["norm_topk_prob"]),
+        routed_scale=float(cfg.get("routed_scaling_factor") or 1.0),
+    )
+
+
+def layer_shapes(spec: HybridSpec, layer: int) -> Dict[str, tuple]:
+    """name -> shape of one layer's weights."""
+    kind, d = spec.attn_kinds[layer], spec.d_model
+    hq, hkv = spec.num_q_heads, spec.kv_heads(kind)
+    out = {
+        "ln1": (d,), "wq": (d, hq * spec.k_dim), "wk": (d, hkv * spec.k_dim),
+        "wv": (d, hkv * spec.v_dim), "wo": (hq * spec.v_dim, d), "ln2": (d,),
+    }
+    if spec.has_sink(kind):
+        out["sink"] = (hq,)
+    if spec.ffn_kinds[layer] == DENSE:
+        out.update(wg=(d, spec.d_ff), wu=(d, spec.d_ff), wd=(spec.d_ff, d))
+    else:
+        held, fe = len(spec.experts_held), spec.d_expert
+        out.update(router=(d, spec.num_experts),
+                   router_bias=(spec.num_experts,),
+                   wg=(held, d, fe), wu=(held, d, fe), wd=(held, fe, d))
+    return out
+
+
+def init_params(rng: jax.Array, spec: HybridSpec, *, dtype=jnp.float32,
+                std: float = 0.02) -> PyTree:
+    """Seeded weights: normal(0, std), norm scales 1, the sink logits and
+    the router's correction bias small normals (so that a test can tell
+    selecting by ``s + b`` from weighing by ``s``)."""
+    keys = iter(jax.random.split(rng, 2 + 16 * spec.num_layers))
+
+    def nrm(shape):
+        return (jax.random.normal(next(keys), shape, jnp.float32) * std
+                ).astype(dtype)
+
+    layers = []
+    for layer in range(spec.num_layers):
+        p = {}
+        for name, shape in layer_shapes(spec, layer).items():
+            p[name] = (jnp.ones(shape, dtype) if name in ("ln1", "ln2")
+                       else nrm(shape))
+        layers.append(p)
+    return {
+        "embed": nrm((spec.vocab_size, spec.d_model)),
+        "layers": layers,
+        "final_norm": jnp.ones((spec.d_model,), dtype),
+        "head": nrm((spec.d_model, spec.vocab_size)),
+    }
+
+
+# -- the block's parts ---------------------------------------------------------
+
+
+def _mm(a, w):
+    """``a @ w`` in the weights' dtype, accumulated in float32."""
+    return jnp.dot(a.astype(w.dtype), w, preferred_element_type=jnp.float32)
+
+
+def rms_norm(x, scale, eps: float):
+    x32 = x.astype(jnp.float32)
+    ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return x32 * jax.lax.rsqrt(ms + eps) * scale.astype(jnp.float32)
+
+
+def rotary(x, positions, *, rotary_dim: int, theta: float):
+    """Rotate the first ``rotary_dim`` dims of every head of ``x``
+    [T, H, D] by its position (half-split layout: dim ``i`` pairs with
+    ``i + rotary_dim/2``); the other dims pass through."""
+    half = rotary_dim // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
+    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:rotary_dim]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x32[..., rotary_dim:]], -1
+    )
+
+
+#: plain grouped-query attention with an optional sink (``ops.flash_decode``)
+attend = _fd.gqa_attend
+
+
+def gated_ffn(p, h):
+    """``(silu(h Wg) * h Wu) Wd`` at the dense width."""
+    a = jax.nn.silu(_mm(h, p["wg"])) * _mm(h, p["wu"])
+    return _mm(a, p["wd"])
+
+
+def route(p, h32, *, spec: HybridSpec):
+    """The router over ALL experts, in float32: ``s = sigmoid(h Wr)``; the
+    ``experts_per_token`` experts with the largest ``s + b`` are chosen
+    (the correction bias selects and does not weigh); the weights are
+    ``s[chosen]`` over their sum.  Returns (ids [T, k], weights [T, k])."""
+    s = jax.nn.sigmoid(jnp.dot(
+        h32, p["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(
+        s + p["router_bias"].astype(jnp.float32), spec.experts_per_token)
+    w = jnp.take_along_axis(s, chosen, axis=-1)
+    if spec.norm_topk:
+        w = w / w.sum(-1, keepdims=True)
+    return chosen, w * spec.routed_scale
+
+
+def expert_layer(p, h32, *, spec: HybridSpec, live=None):
+    """The share of a mixture-of-experts FFN that the experts held here
+    give: dropless, static shapes.
+
+    Every token is routed over all ``num_experts``; the pairs (token,
+    expert) that land on a held expert are sorted by expert and run as
+    three grouped matrix products (``jax.lax.ragged_dot`` over ``[held, d,
+    width]`` weights); pairs for absent experts (and those of lanes that
+    ``live`` [T] masks out) sort behind every group, so they take part in
+    no product and add nothing.
+
+    A grouped product costs its rows whether or not a group claims them,
+    and of the ``T * k`` pairs only ``held / num_experts`` are expected
+    here.  So the products run over the first ``rows`` sorted pairs, four
+    times that expectation, when the pairs here fit in them, and over all
+    ``T * k`` when they do not: nothing is ever dropped, and the cost
+    follows the load.  Returns ``(y [T, d] float32, counts)`` with
+    ``counts`` the :data:`EXPERT_COUNTS` of this layer (int32 [4])."""
+    T, d = h32.shape
+    k, held = spec.experts_per_token, len(spec.experts_held)
+    chosen, w = route(p, h32, spec=spec)
+    local = np.full(spec.num_experts, held, np.int32)  # absent -> past the end
+    local[list(spec.experts_held)] = np.arange(held, dtype=np.int32)
+    group = jnp.asarray(local)[chosen]  # [T, k]
+    if live is not None:
+        group = jnp.where(live[:, None], group, held)
+    group = group.reshape(T * k)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    here = sizes.sum()
+    cdt = p["wg"].dtype
+    x = h32.astype(cdt)
+    weight = w.reshape(T * k)
+
+    def products(rows):
+        """The first ``rows`` sorted pairs through their experts, each
+        weighted, added into its token's row of [T, d]."""
+        first = order[:rows]
+        xs = x[first // k]  # the pairs' tokens, grouped by expert
+        a = jax.nn.silu(
+            jax.lax.ragged_dot(xs, p["wg"], sizes,
+                               preferred_element_type=jnp.float32)
+        ) * jax.lax.ragged_dot(xs, p["wu"], sizes,
+                               preferred_element_type=jnp.float32)
+        y = jax.lax.ragged_dot(a.astype(cdt), p["wd"], sizes,
+                               preferred_element_type=jnp.float32)
+        # rows past the last group belong to no expert: whatever they hold, 0
+        y = jnp.where((jnp.arange(rows) < here)[:, None],
+                      y * weight[first, None], 0.0)
+        return jnp.zeros((T, d), jnp.float32).at[first // k].add(y)
+
+    expected = -(-T * k * held // spec.num_experts)
+    few = min(T * k, -(-4 * expected // 8) * 8)
+    if few < T * k:
+        y = jax.lax.cond(here <= few, lambda: products(few),
+                         lambda: products(T * k))
+    else:
+        y = products(T * k)
+    made = jnp.int32(T * k) if live is None else live.sum().astype(jnp.int32) * k
+    counts = jnp.stack(
+        [made, here, sizes.max(), (sizes > 0).sum().astype(jnp.int32)])
+    return y, counts
+
+
+def block(p, x, positions, *, spec: HybridSpec, layer: int, attention,
+          live=None):
+    """One layer on ``x`` [T, d] (float32 residual) at ``positions`` [T].
+
+    ``attention(q [T, Hq, dk], k [T, Hkv, dk], v [T, Hkv, dv], sink)`` ->
+    ``ctx [T, Hq, dv]`` is the caller's: it writes the layer's cache and
+    reads what the queries may see.  Returns ``(x, counts)`` with
+    ``counts`` the expert layer's (None in a dense layer)."""
+    kind = spec.attn_kinds[layer]
+    T = x.shape[0]
+    hq, hkv = spec.num_q_heads, spec.kv_heads(kind)
+    cdt = p["wq"].dtype
+    h = rms_norm(x, p["ln1"], spec.eps)
+    rot = dict(rotary_dim=spec.rotary_dim, theta=spec.theta(kind))
+    q = rotary(_mm(h, p["wq"]).reshape(T, hq, spec.k_dim), positions, **rot)
+    k = rotary(_mm(h, p["wk"]).reshape(T, hkv, spec.k_dim), positions, **rot)
+    v = spec.value_scale * _mm(h, p["wv"]).reshape(T, hkv, spec.v_dim)
+    ctx = attention(q.astype(cdt), k.astype(cdt), v.astype(cdt),
+                    p["sink"] if spec.has_sink(kind) else None)
+    x = x + _mm(ctx.reshape(T, hq * spec.v_dim), p["wo"])
+    h = rms_norm(x, p["ln2"], spec.eps)
+    if spec.ffn_kinds[layer] == DENSE:
+        return x + gated_ffn(p, h), None
+    y, counts = expert_layer(p, h, spec=spec, live=live)
+    return x + y, counts
+
+
+def _logits(params, x, spec):
+    return _mm(rms_norm(x, params["final_norm"], spec.eps), params["head"])
+
+
+def _stack(spec, params, x, positions, attention_of, live=None):
+    """The layers in their published order; ``attention_of(layer)`` gives
+    each its callback.  Returns (x, the expert layers' counts summed)."""
+    total = jnp.zeros(len(EXPERT_COUNTS), jnp.int32)
+    for layer, p in enumerate(params["layers"]):
+        x, counts = block(p, x, positions, spec=spec, layer=layer,
+                          attention=attention_of(layer), live=live)
+        if counts is not None:
+            total = total + counts
+    return x, total
+
+
+# -- the whole forward (tests, the shares-add-up check) ---------------------------
+
+
+def forward(params, tokens, *, spec: HybridSpec):
+    """Next-token logits [s, vocab] of one sequence ``tokens`` [s], with
+    no cache: every layer attends over the sequence itself."""
+    s = tokens.shape[0]
+    pos = jnp.arange(s)
+    causal = pos[None, :] <= pos[:, None]
+    in_window = pos[None, :] > pos[:, None] - spec.window
+
+    def attention_of(layer):
+        kind = spec.attn_kinds[layer]
+        visible = causal & in_window if kind == WINDOW else causal
+        return lambda q, k, v, sink: attend(q, k, v, visible, sink)
+
+    x = params["embed"][tokens].astype(jnp.float32)
+    x, _ = _stack(spec, params, x, pos, attention_of)
+    return _logits(params, x, spec)
+
+
+# -- the two forwards of the paged engine ------------------------------------------
+
+
+def ring_positions(last, window: int):
+    """The absolute position each ring index holds when the newest written
+    position is ``last`` (scalar or [B]): index ``r`` holds the largest
+    ``p <= last`` with ``p mod window == r``; negative = never written."""
+    last = jnp.asarray(last)[..., None]
+    r = jnp.arange(window)
+    return last - jnp.mod(last - r, window)
+
+
+def forward_decode(params, token, cache, pos, block_tables, live, *,
+                   spec: HybridSpec, page_size: int, kernel: str = "gather"):
+    """One token for every slot: ``token``/``pos`` [B], ``block_tables``
+    [B, nb] (full layers' pages), ``live`` [B] bool (a lane that is not
+    live writes to the scratch page and leaves its ring as it was, and its
+    token is routed to no expert).  Returns ``(logits [B, vocab] float32,
+    cache, counts)`` with ``counts`` the :data:`EXPERT_COUNTS` over the
+    step's expert layers (int32 [4])."""
+    B = token.shape[0]
+    W = spec.window
+    rows = jnp.arange(B)
+    page = block_tables[rows, pos // page_size]
+    off = pos % page_size
+    slot_pos = ring_positions(pos, W)  # [B, W]
+    cache = {name: list(leaves) for name, leaves in cache.items()}
+
+    def attention_of(layer):
+        kind, i = spec.attn_kinds[layer], spec.index_in_kind(layer)
+
+        def full(q, k, v, sink):
+            k_pool = cache["k_full"][i].at[page, off].set(k.reshape(B, -1))
+            v_pool = cache["v_full"][i].at[page, off].set(v.reshape(B, -1))
+            cache["k_full"][i], cache["v_full"][i] = k_pool, v_pool
+            return _fd.decode_attention_gqa_paged(
+                q, k_pool, v_pool, pos, block_tables, page_size=page_size,
+                kernel=kernel, sink=sink)
+
+        def window(q, k, v, sink):
+            hkv = k.shape[1]
+            r = pos % W
+            rings = []
+            for name, new in (("k_win", k), ("v_win", v)):
+                ring = cache[name][i]
+                new = jnp.where(live[:, None], new.reshape(B, -1), ring[rows, r])
+                cache[name][i] = ring = ring.at[rows, r].set(new)
+                rings.append(ring.reshape(B, W, hkv, -1))
+            return jax.vmap(
+                lambda q1, k1, v1, p1: attend(
+                    q1[None], k1, v1, (p1 >= 0)[None], sink)[0]
+            )(q, *rings, slot_pos)
+
+        return window if kind == WINDOW else full
+
+    x = params["embed"][token].astype(jnp.float32)
+    x, counts = _stack(spec, params, x, pos, attention_of, live=live)
+    cache = {name: tuple(leaves) for name, leaves in cache.items()}
+    return _logits(params, x, spec), cache, counts
+
+
+def forward_prefill_chunk(params, tokens, cache, block_table, offset, slot,
+                          real, *, spec: HybridSpec, page_size: int,
+                          kernel: str = "gather"):
+    """One chunk of one sequence's prompt: ``tokens`` [1, C] at positions
+    ``[offset, offset + C)`` of which the first ``real`` are the prompt's
+    (the rest pad the chunk to a compiled width), in ``slot``.
+
+    A full layer writes the chunk's K/V into its pages (``block_table``
+    [nb]) and attends over the pages a few at a time, up to the chunk's
+    end, so its cost follows the live context.  A window layer
+    attends to the ring's positions and to the chunk itself, then leaves the
+    chunk's last ``window`` real positions in the ring.  Returns ``(logits
+    [1, 1, vocab] of the last real position, cache)``: the one row a serving
+    engine samples from."""
+    b, C = tokens.shape
+    if b != 1:
+        raise ValueError(f"chunked prefill is per-sequence, got batch {b}")
+    W = spec.window
+    nb = block_table.shape[0]
+    posns = offset + jnp.arange(C)
+    page_idx = posns // page_size
+    pages = jnp.where(page_idx < nb,
+                      block_table[jnp.minimum(page_idx, nb - 1)], 0)
+    offs = posns % page_size
+    held_pos = ring_positions(offset - 1, W)  # [W] what the ring holds now
+    # the chunk's last W real positions go into the ring
+    tail = real - W + jnp.arange(W)
+    tail_ok = tail >= 0
+    tail_src = jnp.maximum(tail, 0)
+    tail_dst = jnp.mod(offset + tail, W)
+    in_chunk = (posns[None, :] <= posns[:, None]) & (
+        posns[None, :] > posns[:, None] - W)
+    on_ring = (held_pos[None, :] >= 0) & (held_pos[None, :] > posns[:, None] - W)
+    cache = {name: list(leaves) for name, leaves in cache.items()}
+
+    def attention_of(layer):
+        kind, i = spec.attn_kinds[layer], spec.index_in_kind(layer)
+
+        def full(q, k, v, sink):
+            k_pool = cache["k_full"][i].at[pages, offs].set(k.reshape(C, -1))
+            v_pool = cache["v_full"][i].at[pages, offs].set(v.reshape(C, -1))
+            cache["k_full"][i], cache["v_full"][i] = k_pool, v_pool
+            return _fd.chunk_attention_gqa_paged(
+                q, k_pool, v_pool, block_table, posns, page_size=page_size,
+                sink=sink)
+
+        def window(q, k, v, sink):
+            hkv = k.shape[1]
+            k_ring, v_ring = cache["k_win"][i], cache["v_win"][i]
+            keys = jnp.concatenate(
+                [k_ring[slot].reshape(W, hkv, -1), k], axis=0)
+            vals = jnp.concatenate(
+                [v_ring[slot].reshape(W, hkv, -1), v], axis=0)
+            ctx = attend(q, keys, vals,
+                         jnp.concatenate([on_ring, in_chunk], axis=1), sink)
+            for name, ring, new in (("k_win", k_ring, k), ("v_win", v_ring, v)):
+                rows = jnp.where(tail_ok[:, None],
+                                 new.reshape(C, -1)[tail_src],
+                                 ring[slot, tail_dst])
+                cache[name][i] = ring.at[slot, tail_dst].set(rows)
+            return ctx
+
+        return window if kind == WINDOW else full
+
+    x = params["embed"][tokens[0]].astype(jnp.float32)
+    # the rows that pad the chunk reach no expert
+    x, _ = _stack(spec, params, x, posns, attention_of,
+                  live=jnp.arange(C) < real)
+    x = jax.lax.dynamic_slice_in_dim(x, real - 1, 1, axis=0)
+    cache = {name: tuple(leaves) for name, leaves in cache.items()}
+    return _logits(params, x, spec)[None], cache

@@ -112,6 +112,25 @@ def _sqrt_dim(hd: int):
 # --------------------------------------------------------------------------
 
 
+def _fold_block(s, v, m_ref, l_ref, acc_ref, at=()):
+    """Fold one history block into the running softmax kept in scratch:
+    masked scores ``s`` [rows, block] and values ``v`` [block, dv] update
+    the maximum ``m``, the denominator ``l`` and the weighted sum ``acc``
+    at index ``at`` of their refs (one head of the multi-head kernel; the
+    whole of the grouped-query one).  Both kernels' one softmax body."""
+    col = (*at, slice(None), slice(0, 1))
+    m_prev = m_ref[col]
+    m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_cur)
+    corr = jnp.exp(m_prev - m_cur)
+    l_ref[col] = l_ref[col] * corr + p.sum(axis=-1, keepdims=True)
+    acc_ref[(*at, ...)] = acc_ref[(*at, ...)] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    m_ref[col] = m_cur
+
+
 def _kernel(tables_ref, maxpos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
             ko_ref, vo_ref, pos_ref, o_ref, m_ref, l_ref, acc_ref, *,
             block: int, num_heads: int, hd: int, quantized: bool,
@@ -181,18 +200,7 @@ def _kernel(tables_ref, maxpos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                 preferred_element_type=jnp.float32,
             ) / math.sqrt(hd)  # [nq, block]
             s = jnp.where(visible, s, NEG_BIG)
-            m_prev = m_ref[hh, :, :1]
-            m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - m_cur)
-            corr = jnp.exp(m_prev - m_cur)
-            l_ref[hh, :, :1] = (
-                l_ref[hh, :, :1] * corr + p.sum(axis=-1, keepdims=True)
-            )
-            acc_ref[hh] = acc_ref[hh] * corr + jax.lax.dot_general(
-                p, vf, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_ref[hh, :, :1] = m_cur
+            _fold_block(s, vf, m_ref, l_ref, acc_ref, at=(hh,))
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
@@ -724,3 +732,222 @@ def _verify_dense_math(q4, k_seq, v_seq, posmat, hd):
     scores = jnp.where(visible[:, :, None, :], scores, NEG_BIG)
     attn = jax.nn.softmax(scores, axis=-1).astype(v_seq.dtype)
     return jnp.einsum("bqhs,bshd->bqhd", attn, v_seq)
+
+
+# --------------------------------------------------------------------------
+# Grouped-query attention whose keys and values differ in width, over a
+# pool whose KV heads are folded into the minor axis: ``k`` pages [P, block,
+# kv_heads * dk], ``v`` pages [P, block, kv_heads * dv] (a bfloat16 page
+# tiles without padding whatever ``kv_heads`` is).  The model side is
+# ``models.hybrid_moe_transformer``; nothing above this line runs for it and
+# nothing below runs for the multi-head models above.
+# --------------------------------------------------------------------------
+
+
+def gqa_attend(q, keys, vals, visible, sink=None):
+    """Plain grouped-query attention: ``q`` [T, Hq, dk] over ``keys`` [S,
+    Hkv, dk] / ``vals`` [S, Hkv, dv]; query head ``h`` reads KV head ``h //
+    (Hq / Hkv)``; ``visible`` [T, S] says which keys a query sees.
+    ``sink`` [Hq]: a learned logit per head that joins the softmax as one
+    more column and is then dropped, so it adds ``exp(sink)`` to the
+    denominator and nothing else.  Returns [T, Hq, dv] float32."""
+    T, hq, dk = q.shape
+    hkv = keys.shape[1]
+    qg = q.reshape(T, hkv, hq // hkv, dk)
+    s = jnp.einsum("thgd,shd->hgts", qg, keys,
+                   preferred_element_type=jnp.float32) / math.sqrt(dk)
+    s = jnp.where(visible[None, None], s, NEG_BIG)
+    m = s.max(-1, keepdims=True)
+    if sink is not None:
+        sk = sink.astype(jnp.float32).reshape(hkv, hq // hkv, 1, 1)
+        m = jnp.maximum(m, sk)
+    p = jnp.exp(s - m)
+    denom = p.sum(-1, keepdims=True)
+    if sink is not None:
+        denom = denom + jnp.exp(sk - m)
+    p = (p / denom).astype(vals.dtype)
+    out = jnp.einsum("hgts,shd->thgd", p, vals,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(T, hq, vals.shape[-1])
+
+
+def _kernel_gqa(tables_ref, maxpos_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
+                l_ref, acc_ref, *, block: int, scale: float):
+    """One (slot, history-block) grid step over ALL query heads at once.
+
+    ``q_ref`` [1, Hq, kv_heads * dk] holds the slot's query heads
+    block-diagonally: row ``h`` carries its query in the columns of the KV
+    head it reads and zeros elsewhere, so ONE matmul against the folded
+    page ``k_ref`` [1, block, kv_heads * dk] gives every head's scores
+    [Hq, block] with no lane slicing of a 192-wide head out of the page.
+    ``v_ref`` [1, block, kv_heads * dv]; the accumulator [Hq, kv_heads * dv]
+    holds every KV head's values for every row, and the caller keeps each
+    row's own head (a 128-aligned slice).  The extra multiply-adds are
+    ``kv_heads`` times the needed ones and still far below the page's
+    read time.  All rows sit at the slot's one position, ``maxpos_ref[b]``,
+    which is also the block-skip bound."""
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    maxpos = maxpos_ref[b]
+
+    @pl.when(j * block <= maxpos)
+    def _compute():
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [Hq, block]
+        cols = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(cols <= maxpos, s, NEG_BIG)
+        _fold_block(s, v_ref[0], m_ref, l_ref, acc_ref)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        l = jnp.maximum(l_ref[:, :1], 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _pallas_gqa_paged(q, k_pool, v_pool, pos, tables, *, block: int):
+    """The GQA kernel call: ``q`` [B, Hq, dk] at ``pos`` [B] against the
+    folded pools through ``tables`` [B, nb].  Returns [B, Hq, dv] f32."""
+    B, hq, dk = q.shape
+    hkv = k_pool.shape[-1] // dk
+    dv = v_pool.shape[-1] // hkv
+    g = hq // hkv
+    nb = tables.shape[1]
+    eye = jnp.eye(hkv, dtype=q.dtype)
+    q_bd = (q.reshape(B, hkv, g, 1, dk) * eye[None, :, None, :, None]).reshape(
+        B, hq, hkv * dk)
+    pos = pos.astype(jnp.int32)
+
+    def page(bb, j, tbl, mp):
+        # blocks past the slot's newest position repeat its last page, so
+        # they are skipped without a read of their own
+        return (tbl[bb, jnp.minimum(j, mp[bb] // block)], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, nb),
+        in_specs=[
+            pl.BlockSpec((1, hq, hkv * dk), lambda bb, j, tbl, mp: (bb, 0, 0)),
+            pl.BlockSpec((1, block, hkv * dk), page),
+            pl.BlockSpec((1, block, hkv * dv), page),
+        ],
+        out_specs=pl.BlockSpec(
+            (1, hq, hkv * dv), lambda bb, j, tbl, mp: (bb, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((hq, 128), jnp.float32),
+            pltpu.VMEM((hq, 128), jnp.float32),
+            pltpu.VMEM((hq, hkv * dv), jnp.float32),
+        ],
+    )
+    compiler_params = None
+    if not _use_interpret():
+        compiler_params = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        )
+    out = pl.pallas_call(
+        functools.partial(_kernel_gqa, block=block, scale=1.0 / math.sqrt(dk)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, hq, hkv * dv), jnp.float32),
+        compiler_params=compiler_params,
+        interpret=_use_interpret(),
+        name=f"flash_decode_decode_gqa_{jnp.dtype(k_pool.dtype).name}",
+    )(tables, pos, q_bd, k_pool, v_pool)
+    # each row keeps the values of the KV head it reads
+    out = out.reshape(B, hkv, g, hkv, dv)
+    return jnp.stack([out[:, h, :, h] for h in range(hkv)], axis=1).reshape(
+        B, hq, dv)
+
+
+def decode_attention_gqa_paged(
+    q, k_pool, v_pool, pos, block_tables, *, page_size: int,
+    kernel: str = "gather", sink=None,
+):
+    """Single-token grouped-query decode attention over the folded paged
+    pool: ``q`` [B, Hq, dk] at ``pos`` [B]; ``k_pool`` [P, ps, Hkv * dk],
+    ``v_pool`` [P, ps, Hkv * dv], already holding the current token's
+    write.  The Pallas kernel streams each slot's pages up to its own
+    position; every other choice gathers the block tables' whole history
+    (the reference the kernel is pinned against, not a serving path at a
+    long ``max_seq``).  Returns [B, Hq, dv] float32."""
+    B, hq, dk = q.shape
+    if flash_impl(kernel) == "pallas":
+        if sink is not None:
+            raise NotImplementedError(
+                "the grouped-query decode kernel takes no attention sink")
+        return _pallas_gqa_paged(q, k_pool, v_pool, pos, block_tables,
+                                 block=page_size)
+    hkv = k_pool.shape[-1] // dk
+    s = block_tables.shape[1] * page_size
+    keys = k_pool[block_tables].reshape(B, s, hkv, dk)
+    vals = v_pool[block_tables].reshape(B, s, hkv, -1)
+    visible = jnp.arange(s)[None, :] <= pos[:, None]
+    return jax.vmap(
+        lambda q1, k1, v1, vis: gqa_attend(q1[None], k1, v1, vis[None], sink)[0]
+    )(q, keys, vals, visible)
+
+
+#: pages of history a chunk's attention reads at a time
+HISTORY_PAGES = 4
+
+
+def chunk_attention_gqa_paged(
+    q, k_pool, v_pool, block_table, posns, *, page_size: int, sink=None,
+):
+    """Chunked-prefill grouped-query attention of ``q`` [C, Hq, dk] at
+    positions ``posns`` [C] (ascending) over ONE sequence's pages
+    (``block_table`` [nb]), the chunk's own K/V already written.  The
+    history is read :data:`HISTORY_PAGES` pages at a time with a running
+    softmax, and only up to the chunk's last position: the cost follows
+    the live context, not the block table's length, and no [C, max_seq]
+    score matrix exists.  Returns [C, Hq, dv] float32."""
+    C, hq, dk = q.shape
+    hkv = k_pool.shape[-1] // dk
+    dv = v_pool.shape[-1] // hkv
+    g = hq // hkv
+    history_pages = HISTORY_PAGES
+    kb = history_pages * page_size
+    nb = block_table.shape[0]
+    blocks_max = -(-nb // history_pages)
+    table = jnp.pad(block_table, (0, blocks_max * history_pages - nb))
+    n_blocks = jnp.minimum(posns[-1] // kb + 1, blocks_max)
+    qg = q.reshape(C, hkv, g, dk)
+
+    def body(i, carry):
+        m, l, acc = carry
+        ids = jax.lax.dynamic_slice_in_dim(table, i * history_pages,
+                                           history_pages)
+        keys = k_pool[ids].reshape(kb, hkv, dk)
+        vals = v_pool[ids].reshape(kb, hkv, dv)
+        s = jnp.einsum("chgd,khd->hgck", qg, keys,
+                       preferred_element_type=jnp.float32) / math.sqrt(dk)
+        kpos = i * kb + jnp.arange(kb)
+        s = jnp.where(kpos[None, :] <= posns[:, None], s, NEG_BIG)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.exp(s - m_new[..., None])
+        corr = jnp.exp(m - m_new)
+        l = l * corr + p.sum(-1)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "hgck,khd->hgcd", p.astype(vals.dtype), vals,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    init = (jnp.full((hkv, g, C), -jnp.inf, jnp.float32),
+            jnp.zeros((hkv, g, C), jnp.float32),
+            jnp.zeros((hkv, g, C, dv), jnp.float32))
+    m, l, acc = jax.lax.fori_loop(0, n_blocks, body, init)
+    if sink is not None:
+        sk = sink.astype(jnp.float32).reshape(hkv, g, 1)
+        m_new = jnp.maximum(m, sk)
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jnp.exp(sk - m_new)
+        acc = acc * corr[..., None]
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return out.transpose(2, 0, 1, 3).reshape(C, hq, dv)

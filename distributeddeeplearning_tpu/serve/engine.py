@@ -46,9 +46,7 @@ from distributeddeeplearning_tpu.obs.ledger import get_ledger
 from distributeddeeplearning_tpu.obs.trace import get_tracer
 from distributeddeeplearning_tpu.models.pipelined_transformer import (
     forward_decode,
-    forward_decode_paged,
     forward_prefill,
-    forward_prefill_chunk,
 )
 from distributeddeeplearning_tpu.ops.flash_attention import auto_block_tiles
 from distributeddeeplearning_tpu.ops.flash_decode import (
@@ -66,12 +64,16 @@ from distributeddeeplearning_tpu.serve.kv_cache import (
     cache_bytes,
     cache_sharding,
     init_cache,
-    init_paged_cache,
     insert_sequence,
     page_bytes,
     pages_for,
+    slot_state_bytes,
 )
 from distributeddeeplearning_tpu.serve.kv_tier import HostPageTier
+from distributeddeeplearning_tpu.serve.served_model import (
+    ServedModel,
+    opt_model,
+)
 
 logger = logging.getLogger("ddlt.serve.engine")
 
@@ -101,11 +103,10 @@ def _ledger_kv_scales(engine):
 def _leaf_subset_page_bytes(cache, *, scales: bool) -> int:
     """Per-page bytes of just the value (or just the scale) leaves —
     the committed-bytes granule for the paged pool's ledger owners."""
-    return sum(
-        leaf.size // leaf.shape[0] * leaf.dtype.itemsize
-        for key, leaf in cache.items()
+    return page_bytes({
+        key: leaf for key, leaf in cache.items()
         if key.endswith("_scale") == scales
-    )
+    })
 
 
 def _ledger_host_tier_bytes(engine):
@@ -130,9 +131,14 @@ def _register_engine_owners(engine, ledger=None) -> None:
     paged = getattr(engine, "kv_layout", "dense") == "paged"
     if paged:
         val_pb = _leaf_subset_page_bytes(engine._cache, scales=False)
+        # per-slot state (a two-kind cache's window rings) is committed
+        # whole from the start; pages as they are handed out
+        held = slot_state_bytes(engine._cache)
         ledger.register(
             "kv_pages", engine, _ledger_kv_values,
-            committed=lambda e, pb=val_pb: e.allocator.pages_in_use * pb,
+            committed=lambda e, pb=val_pb, held=held: (
+                e.allocator.pages_in_use * pb + held
+            ),
         )
     else:
         ledger.register("kv_pages", engine, _ledger_kv_values)
@@ -747,13 +753,25 @@ class PagedInferenceEngine:
     axis never shards (the block-table gather must stay chip-local), so
     TP splits weights and the cache's HEAD dim through the partition-rule
     layout table while page addressing stays on-chip.
+
+    The model comes through one description, ``model`` (a
+    :class:`~.served_model.ServedModel`: its two forwards, its cache
+    layout, its programs' names, what it refuses); ``num_heads`` alone
+    binds the OPT block (``served_model.opt_model``), as before.  Where
+    the model's cache holds state per slot beside the pages (window
+    attention layers' rings), admission still counts pages only, the
+    chunk program is also told the slot and the chunk's real length, the
+    decode program which lanes are live, and what the decode step counts
+    (``model.count_step``) comes back with the step's one fetch into
+    ``step_counters``.
     """
 
     def __init__(
         self,
         params,
         *,
-        num_heads: int,
+        num_heads: Optional[int] = None,
+        model: Optional[ServedModel] = None,
         batch_slots: int,
         max_seq: int,
         page_size: int = 64,
@@ -771,9 +789,36 @@ class PagedInferenceEngine:
         host_pages: int = 0,
         tier_policy: str = "lru",
     ):
-        _, num_layers, head_dim = _validate_model_dims(
-            params, num_heads=num_heads, max_seq=max_seq, top_k=top_k
-        )
+        if model is None:
+            if num_heads is None:
+                raise ValueError("give num_heads (the OPT block) or a model")
+            _validate_model_dims(
+                params, num_heads=num_heads, max_seq=max_seq, top_k=top_k
+            )
+            model = opt_model(params, num_heads=num_heads)
+        else:
+            if top_k is not None and top_k < 1:
+                raise ValueError(f"top_k must be >= 1, got {top_k}")
+            if model.max_positions is not None and max_seq > model.max_positions:
+                raise ValueError(
+                    f"max_seq {max_seq} exceeds the model's "
+                    f"{model.max_positions} positions"
+                )
+            # refused by name, before any device work
+            if prefix_cache:
+                model.refuse(
+                    "prefix_cache",
+                    "a shared prefix would need the window layers' last "
+                    "positions at the prefix's end; pass prefix_cache=False",
+                )
+            if cache_dtype is not None and np.dtype(cache_dtype) == np.int8:
+                model.refuse("int8_pool")
+            if host_pages:
+                model.refuse("host_tier")
+            if mesh is not None and mesh.devices.size > 1:
+                model.refuse("tensor_mesh")
+        self.model = model
+        num_layers = model.num_layers
         # see InferenceEngine: "flash" streams pages through
         # ops.flash_decode (in-tile int8 dequant — the QUANT_r15 speed
         # lever), "gather" is the legacy block-table-gather read
@@ -793,6 +838,9 @@ class PagedInferenceEngine:
         self.max_seq = max_seq
         self.page_size = page_size
         self.prefill_chunk = prefill_chunk
+        # the narrowest compiled chunk (the model's): a prompt's remainder
+        # runs in the smallest power-of-two multiple of it that holds it
+        self.prefill_chunk_floor = min(model.chunk_floor, prefill_chunk)
         self.pad_id = pad_id
         # exposed for the spec decoder's greedy-only guard
         self.temperature = float(temperature)
@@ -813,7 +861,7 @@ class PagedInferenceEngine:
                     f"num_heads {num_heads} not divisible by the mesh's "
                     f"tensor axis ({self.tp}) — TP shards attention heads"
                 )
-        self.vocab_size = params["head"].shape[1]
+        self.vocab_size = model.vocab_size
         if cache_dtype is None:
             cache_dtype = params["embed"].dtype
         self.kv_dtype = np.dtype(cache_dtype).name
@@ -842,12 +890,10 @@ class PagedInferenceEngine:
         self.num_pages = num_pages
         self.allocator = PageAllocator(num_pages)
         self._prefix_enabled = prefix_cache
-        self._cache = init_paged_cache(
+        self._cache = model.init_cache(
             num_pages=num_pages,
-            num_layers=num_layers,
             page_size=page_size,
-            num_heads=num_heads,
-            head_dim=head_dim,
+            batch_slots=batch_slots,
             dtype=cache_dtype,
         )
         self._page_bytes = page_bytes(self._cache)
@@ -895,6 +941,12 @@ class PagedInferenceEngine:
             (batch_slots, self.blocks_per_slot), SCRATCH_PAGE, np.int32
         )
         self._slot_pages: dict = {}
+        # lanes whose block-table row is installed (the prompt is fully
+        # written): what a model with per-slot state is told each step
+        self._live = np.zeros(batch_slots, bool)
+        # what decode steps counted since reset_stats(), under the names
+        # ServeReport carries them by (empty for a model that counts none)
+        self.step_counters: Dict[str, float] = {}
 
         # stats the scheduler/bench surface
         self.prefill_compiles = 0
@@ -919,19 +971,18 @@ class PagedInferenceEngine:
 
         dec_kernel = self.decode_kernel
 
-        def _chunk_fn(params, cache, tokens, block_table, offset):
-            return forward_prefill_chunk(
-                params, tokens, cache, block_table, offset,
-                num_heads=num_heads, page_size=page_size,
-                kernel=dec_kernel, mesh=mesh,
+        def _chunk_fn(params, cache, tokens, block_table, offset,
+                      *slot_args):
+            return model.prefill_chunk(
+                params, tokens, cache, block_table, offset, *slot_args,
+                page_size=page_size, kernel=dec_kernel, mesh=mesh,
             )
 
         def _decode_fn(params, cache, tokens, pos, block_tables, step,
-                       with_logits):
-            logits, cache = forward_decode_paged(
-                params, tokens, cache, pos, block_tables,
-                num_heads=num_heads, page_size=page_size,
-                kernel=dec_kernel, mesh=mesh,
+                       with_logits, *slot_args):
+            logits, cache, *counts = model.decode(
+                params, tokens, cache, pos, block_tables, *slot_args,
+                page_size=page_size, kernel=dec_kernel, mesh=mesh,
             )
             # per-slot health verdict (NaN quarantine) — one [slots] bool
             finite = jnp.isfinite(logits).all(axis=-1)
@@ -939,29 +990,20 @@ class PagedInferenceEngine:
             # never materializes a [B, vocab] output it would discard —
             # logits stay a fusable intermediate of the sampler; the
             # probe variant (True) compiles separately on first use
+            # what the step counted (model.count_step) rides in the same
+            # outputs, behind the verdict: no fetch of its own
             if with_logits:
-                return _sample(logits, step), logits, finite, cache
-            return _sample(logits, step), finite, cache
+                return _sample(logits, step), logits, finite, *counts, cache
+            return _sample(logits, step), finite, *counts, cache
 
-        def _scrub_fn(cache, page_ids, from_offs):
-            # zero offsets >= from_offs[i] of page page_ids[i], every
-            # leaf; untouched lanes point at the scratch page with
-            # from_offs = page_size (an empty mask) so one compiled
-            # program covers every (slot, from_pos) combination
-            zero = (
-                jnp.arange(page_size)[None, :] >= from_offs[:, None]
-            )  # [nb, ps]
-            out = {}
-            for key, leaf in cache.items():
-                rows = leaf[page_ids]  # [nb, L, ps, ...]
-                m = zero.reshape(
-                    (zero.shape[0], 1, page_size)
-                    + (1,) * (rows.ndim - 3)
-                )
-                out[key] = leaf.at[page_ids].set(
-                    jnp.where(m, jnp.zeros((), leaf.dtype), rows)
-                )
-            return out
+        def _scrub_fn(cache, page_ids, from_offs, *slot_args):
+            return model.scrub(
+                cache, page_ids, from_offs, *slot_args, page_size=page_size
+            )
+
+        # a trace names a program after its function
+        _chunk_fn.__name__ = model.programs["prefill_chunk"]
+        _decode_fn.__name__ = model.programs["decode"]
 
         # one compiled chunk program per chunk shape (<= log2(chunk) of
         # them: full chunks plus power-of-two final-chunk buckets); all
@@ -1030,6 +1072,7 @@ class PagedInferenceEngine:
         self.prompt_tokens_seen = 0
         self.pages_peak = 0
         self.prefix_hit_tokens_host = 0
+        self.step_counters = {}
         if self.tier is not None:
             self.tier.reset_stats()
 
@@ -1046,14 +1089,19 @@ class PagedInferenceEngine:
         off = 0
         while off < prompt_len:
             rem = prompt_len - off
-            C = (
-                self.prefill_chunk
-                if rem >= self.prefill_chunk
-                else prompt_bucket(rem, self.prefill_chunk)
-            )
+            C = self._chunk_width(rem)
             shapes.add(C)
             off += min(rem, C)
         return shapes
+
+    def _chunk_width(self, rem: int) -> int:
+        """The compiled width the next chunk of a prompt with ``rem``
+        tokens left runs at: full chunks, then a power-of-two bucket (from
+        the model's floor) for the remainder, which bounds the compiled
+        chunk shapes."""
+        if rem >= self.prefill_chunk:
+            return self.prefill_chunk
+        return prompt_bucket(rem, self.prefill_chunk, self.prefill_chunk_floor)
 
     def required_pages(self, prompt_len: int, max_new_tokens: int) -> int:
         """Pages a request needs end-to-end: its prompt plus its token
@@ -1194,13 +1242,7 @@ class PagedInferenceEngine:
             raise ValueError("prefill task already complete")
         length = len(task.prompt)
         rem = length - task.offset
-        # full chunks, then a power-of-two bucket for the remainder —
-        # bounds compiled chunk shapes to log2(prefill_chunk) + 1
-        C = (
-            self.prefill_chunk
-            if rem >= self.prefill_chunk
-            else prompt_bucket(rem, self.prefill_chunk)
-        )
+        C = self._chunk_width(rem)
         real = min(rem, C)
         if C not in self._seen_chunk_shapes:
             self._seen_chunk_shapes.add(C)
@@ -1214,6 +1256,10 @@ class PagedInferenceEngine:
         # these pages until the prompt is fully written
         table = np.full(self.blocks_per_slot, SCRATCH_PAGE, np.int32)
         table[: len(task.pages)] = task.pages
+        slot_args = (
+            (jnp.int32(task.slot), jnp.int32(real))
+            if self.model.slot_state else ()
+        )
         with get_tracer().span(
             "serve/engine.chunk_dispatch", chunk=C, offset=task.offset
         ):
@@ -1223,6 +1269,7 @@ class PagedInferenceEngine:
                 jnp.asarray(tokens),
                 jnp.asarray(table),
                 jnp.int32(task.offset),
+                *slot_args,
             )
         chunk_start = task.offset
         task.offset += real
@@ -1249,8 +1296,10 @@ class PagedInferenceEngine:
         # prompt fully written: NOW the slot's decode row may see the pages
         self._block_tables[task.slot] = SCRATCH_PAGE
         self._block_tables[task.slot, : len(task.pages)] = task.pages
+        self._live[task.slot] = True
         last = jax.lax.dynamic_index_in_dim(
-            logits, real - 1, axis=1, keepdims=False
+            logits, 0 if self.model.last_logits_only else real - 1,
+            axis=1, keepdims=False,
         )  # [1, vocab] — last REAL position of the final chunk
         if self.capture_logits:
             self.last_prefill_logits = np.asarray(last)[0]
@@ -1291,14 +1340,19 @@ class PagedInferenceEngine:
                 jnp.asarray(self._block_tables),
                 jnp.int32(self._next_step()),
             )
+            slot_args = (
+                (jnp.asarray(self._live),) if self.model.slot_state else ()
+            )
         logits = None
         with trace.span("serve/engine.decode_dispatch"):
             if self.capture_logits:
-                toks, logits, finite, self._cache = self._decode_jit(
-                    *args, True
+                toks, logits, finite, *counts, self._cache = (
+                    self._decode_jit(*args, True, *slot_args)
                 )
             else:
-                toks, finite, self._cache = self._decode_jit(*args, False)
+                toks, finite, *counts, self._cache = self._decode_jit(
+                    *args, False, *slot_args
+                )
         # every device->host read in ONE span of its own (same contract
         # as the dense engine): the logits probe must not be billed to
         # dispatch, or the dispatch-vs-readback split on the timeline
@@ -1307,7 +1361,20 @@ class PagedInferenceEngine:
             if logits is not None:
                 self.last_logits = np.asarray(logits)
             self.last_finite = np.asarray(finite)
-            return np.asarray(toks)
+            toks = np.asarray(toks)
+            if counts:
+                # what the model's step counted: its own reducer names the
+                # report's fields, the engine only adds
+                step = self.model.count_step(
+                    np.asarray(counts[0]), np.asarray(pos)[self._live])
+                for name, value in step.items():
+                    self.step_counters[name] = (
+                        self.step_counters.get(name, 0) + value)
+                # the step's own addends at the step's time, so that a
+                # traced window can be counted by itself (recorded only
+                # while the tracer is on or a capture is live)
+                trace.event("serve/engine.step_counts", **step)
+            return toks
 
     # -- fault injection / quarantine hooks --------------------------------
     def poison_slot(self, slot: int, pos: int) -> None:
@@ -1324,12 +1391,7 @@ class PagedInferenceEngine:
             raise ValueError(f"slot {slot} holds no pages to poison")
         page = pages[pos // self.page_size]
         off = pos % self.page_size
-        c = dict(self._cache)
-        if "k_scale" in c:  # int8 K can't hold NaN — poison the f32 scales
-            c["k_scale"] = c["k_scale"].at[page, :, off].set(jnp.nan)
-        else:
-            c["k"] = c["k"].at[page, :, off].set(jnp.nan)
-        self._cache = c
+        self._cache = self.model.poison(self._cache, page, off)
 
     def scrub_slot(self, slot: int, from_pos: int = 0) -> None:
         """Zero the slot's cache from logical position ``from_pos`` on,
@@ -1368,17 +1430,22 @@ class PagedInferenceEngine:
         for idx in range(start, len(pages)):
             ids[idx] = pages[idx]
             offs[idx] = max(0, from_pos - idx * ps)
+        slot_args = (jnp.int32(slot),) if self.model.slot_state else ()
         self._cache = self._scrub_jit(
-            self._cache, jnp.asarray(ids), jnp.asarray(offs)
+            self._cache, jnp.asarray(ids), jnp.asarray(offs), *slot_args
         )
 
     def release(self, slot: int) -> None:
         """Return the slot's pages to the pool.  Prefix-registered pages
         drop to the reclaimable LRU (future hits resurrect them); private
-        pages go straight back to the free list."""
+        pages go straight back to the free list.  Per-slot state (a window
+        layer's ring) is dropped from view with them: the lane stops being
+        live, and the next occupant's positions mask whatever the ring
+        still holds."""
         for page in self._slot_pages.pop(slot, []):
             self.allocator.decref(page)
         self._block_tables[slot] = SCRATCH_PAGE
+        self._live[slot] = False
 
     # -- host page tier ----------------------------------------------------
     def _tier_evict_hook(self, key, page: int) -> bool:

@@ -195,11 +195,86 @@ def page_bytes(cache: Cache) -> int:
     """Bytes of ONE page across k+v and all layers — the HBM granule the
     allocator hands out (``cache_bytes == (num_pages+1) * page_bytes``).
     Sums EVERY pool leaf, so the int8 layout's per-page scale bytes are
-    charged to the page they belong to."""
+    charged to the page they belong to.  In a cache of two kinds
+    (:func:`init_hybrid_cache`) only the full layers' leaves are paged;
+    the window layers' rings are :func:`slot_state_bytes`."""
     return sum(
         leaf.size // leaf.shape[0] * leaf.dtype.itemsize
-        for leaf in jax.tree_util.tree_leaves(cache)
+        for leaf in paged_leaves(cache)
     )
+
+
+def paged_leaves(cache: Cache) -> List[jax.Array]:
+    """The leaves laid out ``[pages, ...]``, which the allocator's pages
+    index (every leaf, but for a two-kind cache's rings)."""
+    return jax.tree_util.tree_leaves(
+        {k: v for k, v in cache.items() if k not in RING_LEAVES}
+    )
+
+
+def slot_state_bytes(cache: Cache) -> int:
+    """Bytes held per SLOT rather than per page, summed over the slots:
+    the window layers' rings of a two-kind cache (0 for every other
+    layout).  They are committed whole from the start and never grow."""
+    return cache_bytes({k: v for k, v in cache.items() if k in RING_LEAVES})
+
+
+# --------------------------------------------------------------------------
+# A cache of two kinds under one pytree, for models that mix full and
+# window attention layers (``models.hybrid_moe_transformer``).  Full layers
+# keep every position: each owns a paged pool, addressed through the same
+# block tables and the same PageAllocator as above.  Window layers need the
+# last ``window`` positions only: each owns a RING of ``window`` positions a
+# slot, written at ``pos mod window`` and masked by absolute position, so
+# its bytes do not grow with the sequence.  KV heads are folded into the
+# minor axis (``[.., kv_heads * width]``): a bfloat16 page or ring tiles
+# (16, 128) without padding whatever the head count, where a trailing
+# ``(4, 192)`` would pad to ``(16, 256)``.  K and V differ in width, so each
+# has a leaf of its own per layer (a tuple of per-layer arrays: every layer's
+# buffer is updated in place on its own).
+# --------------------------------------------------------------------------
+
+#: the per-slot leaves of a two-kind cache
+RING_LEAVES = ("k_win", "v_win")
+
+
+def init_hybrid_cache(
+    *,
+    num_pages: int,
+    page_size: int,
+    batch_slots: int,
+    window: int,
+    full_layers: int,
+    window_layers: int,
+    kv_heads_full: int,
+    kv_heads_window: int,
+    k_dim: int,
+    v_dim: int,
+    dtype: Any = jnp.bfloat16,
+) -> Cache:
+    """``{"k_full", "v_full"}``: per full layer ``[pages + 1, page_size,
+    kv_heads_full * width]`` (page 0 the scratch page); ``{"k_win",
+    "v_win"}``: per window layer ``[batch_slots, window, kv_heads_window *
+    width]``."""
+    if num_pages < 1:
+        raise ValueError(f"num_pages must be >= 1, got {num_pages}")
+    if _is_int8(dtype):
+        raise ValueError("a cache of two kinds has no int8 layout")
+
+    def leaves(n, lead, heads, width):
+        return tuple(
+            jnp.zeros((lead[0], lead[1], heads * width), dtype)
+            for _ in range(n)
+        )
+
+    pool = (num_pages + 1, page_size)
+    ring = (batch_slots, window)
+    return {
+        "k_full": leaves(full_layers, pool, kv_heads_full, k_dim),
+        "v_full": leaves(full_layers, pool, kv_heads_full, v_dim),
+        "k_win": leaves(window_layers, ring, kv_heads_window, k_dim),
+        "v_win": leaves(window_layers, ring, kv_heads_window, v_dim),
+    }
 
 
 def pages_for(tokens: int, page_size: int) -> int:

@@ -38,7 +38,10 @@ What it records is the whole point of serving benchmarks:
 - ``admission_turns`` / ``kv_pages_reserved_sum`` / ``kv_pages_written_sum``:
   why the head of the queue stayed queued (slot, pages, HBM forecast),
   counted where the decision is taken, and how much of the worst-case
-  page reservation is ever written.
+  page reservation is ever written,
+- ``expert_*`` / ``*_positions_held_sum``: what an engine whose model has
+  expert layers and a cache of two kinds counted over the run's decode
+  steps (``engine.step_counters``; all 0 for any other model).
 
 Every percentile block routes through the obs histogram
 (:func:`..obs.registry.summarize`), and aggregate counters/histograms
@@ -330,6 +333,26 @@ class ServeReport:
     # pages included).  Both 0 on the dense engine (no pages).
     kv_pages_reserved_sum: int = 0
     kv_pages_written_sum: int = 0
+    # what the engine's decode steps counted over this run
+    # (``PagedInferenceEngine.step_counters``; 0 where the model has no
+    # expert layers / one kind of cache).  Over live lanes only:
+    # "pairs_total" = (token, expert) pairs the routers made, summed over
+    # steps and expert layers; "pairs_here" = of those, the pairs on an
+    # expert this chip holds.  The three ``expert_*_sum`` are sums over
+    # decode steps: of the fullest held expert's tokens (mean over the
+    # step's expert layers), of the mean tokens a held expert (likewise),
+    # and of the held experts that got any token (summed over the step's
+    # expert layers: how many experts' weights the step had to read).
+    # ``*_positions_held_sum``: summed a decode step over live slots, the
+    # positions ONE layer of the kind holds for the slot: a full layer
+    # every position, a window layer at most the window.
+    expert_pairs_total: int = 0
+    expert_pairs_here: int = 0
+    expert_tokens_max_sum: float = 0.0
+    expert_tokens_mean_sum: float = 0.0
+    experts_touched_sum: int = 0
+    window_positions_held_sum: int = 0
+    full_positions_held_sum: int = 0
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -697,6 +720,7 @@ class ContinuousBatchingScheduler:
         # truthiness check
         plan = faults_mod.get_plan()
         compiles_before = getattr(engine, "prefill_compiles", 0)
+        counted_before = dict(getattr(engine, "step_counters", {}))
         # admission HBM forecast: resolved once per run (honors test-time
         # set_ledger swaps); duck-typed engines without admit_bytes opt
         # out implicitly
@@ -1912,6 +1936,12 @@ class ContinuousBatchingScheduler:
             admission_turns=admission_turns,
             kv_pages_reserved_sum=kv_reserved_sum,
             kv_pages_written_sum=kv_written_sum,
+            **{
+                name: value - counted_before.get(name, 0)
+                for name, value in getattr(
+                    engine, "step_counters", {}
+                ).items()
+            },
             finish_reasons=finish_reasons,
             errors=error_count,
             queue_wait_s=_percentiles(

@@ -89,6 +89,10 @@ class SpeculativeDecoder:
             raise ValueError(
                 f"draft_tokens must be >= 1, got {draft_tokens}"
             )
+        model = getattr(engine, "model", None)
+        if model is not None:
+            # the verify programs are the OPT block's
+            model.refuse("verify")
         if getattr(engine, "kv_dtype", "float32") != "float32":
             raise ValueError(
                 "speculative decoding requires the f32 KV cache — the "

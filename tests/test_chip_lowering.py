@@ -94,6 +94,26 @@ def test_flash_decode_forms_lower_for_tpu(heads, hd, page, pool):
     )
 
 
+@pytest.mark.parametrize(
+    "hq,hkv,dk,dv,page", [(64, 4, 192, 128, 128), (16, 2, 96, 64, 64)]
+)
+def test_gqa_decode_kernel_lowers_for_tpu(hq, hkv, dk, dv, page):
+    """The grouped-query form over a pool whose KV heads are folded into the
+    minor axis, keys and values of different widths (bfloat16, as served)."""
+    pages = SLOTS * BLOCKS + 1
+    text = _lower_for_tpu(
+        functools.partial(
+            fd.decode_attention_gqa_paged, page_size=page, kernel="pallas"
+        ),
+        _sds((SLOTS, hq, dk), jnp.bfloat16),
+        _sds((pages, page, hkv * dk), jnp.bfloat16),
+        _sds((pages, page, hkv * dv), jnp.bfloat16),
+        _sds((SLOTS,), jnp.int32),
+        _sds((SLOTS, BLOCKS), jnp.int32),
+    )
+    assert "flash_decode_decode_gqa_bfloat16" in text
+
+
 @pytest.mark.parametrize("heads,hd", [(12, 64), (8, 128)])
 def test_flash_attention_forward_and_grad_lower_for_tpu(heads, hd):
     """The trained geometry: batch 8, seq 2048, bf16, causal — forward,
