@@ -375,6 +375,40 @@ def forward_decode(params, token, cache, pos, *, num_heads: int,
     return _mm(x, params["head"]), new_cache
 
 
+def _scan_pool(blocks, x, cache, layer):
+    """Walk the layers over a PAGED pool, in place: the one way the paged
+    forwards (decode, chunk, verify) touch it.
+
+    Every leaf ``[pages, L, page_size, ...]`` is viewed as rows ``[pages *
+    L, page_size, ...]``, layer ``l`` of physical page ``p`` at row ``p * L
+    + l`` (no data moves where the leaf lies row-major on the device; see
+    ``serve.kv_cache.init_paged_cache``).  The rows ride ``lax.scan`` as its
+    CARRY, never as scanned input or stacked output and never transposed,
+    so a layer's ``.at[rows, offs].set`` writes the donated pool where it
+    lies and a call moves the positions it writes, not the pool.
+
+    ``layer(p, rows_of, x, k, v, k_s, v_s) -> (x, k, v, k_s, v_s)`` is one
+    block over the rows (``k_s``/``v_s`` None for an f32 pool);
+    ``rows_of(pages)`` maps physical page ids to this layer's rows.
+    Returns ``(x, new_cache)`` in the pool's own shape."""
+    L = cache["k"].shape[1]
+    names = ("k", "v", "k_scale", "v_scale")
+    pool = tuple(
+        cache[n].reshape((-1,) + cache[n].shape[2:]) if n in cache else None
+        for n in names
+    )
+
+    def body(carry, xs):
+        p, l = xs
+        return layer(p, lambda pages: pages * L + l, *carry), None
+
+    (x, *pool), _ = jax.lax.scan(body, (x, *pool), (blocks, jnp.arange(L)))
+    return x, {
+        n: rows.reshape(cache[n].shape)
+        for n, rows in zip(names, pool) if rows is not None
+    }
+
+
 def _block_decode_paged(
     p, x, k_l, v_l, pos, block_tables, *, num_heads: int, page_size: int,
     k_s=None, v_s=None, kernel: str = "gather", mesh=None,
@@ -446,6 +480,11 @@ def forward_decode_paged(
     reconstructs exactly the dense ``[B, S, h, hd]`` key/value sequence,
     padded with masked positions up to ``nb * page_size``.
 
+    The pool is updated IN PLACE (:func:`_scan_pool`): layer ``l`` of
+    physical page ``p`` is row ``p * L + l`` of the pool's row view, so a
+    layer runs under ``block_tables * L + l`` and writes one position a
+    slot into the donated pool, whatever its size.
+
     Int8 pool (``{"k_scale", "v_scale"}`` present, [pages, L, page_size,
     h] f32): same program with quantize-on-write and the dequant read
     fused into attention — at history granularity under ``kernel=
@@ -454,32 +493,15 @@ def forward_decode_paged(
     the 8-bit grid (``bench.py --quant`` reports agreement rate and MAE).
     """
     x = params["embed"][token] + params["pos"][pos]  # [B, d]
-    quantized = quantized_cache(cache)
 
-    def body(carry, xs):
-        p, k_l, v_l, k_s, v_s = xs
-        carry, k_l, v_l, k_s, v_s = _block_decode_paged(
-            p, carry, k_l, v_l, pos, block_tables,
+    def layer(p, rows_of, x, k_l, v_l, k_s, v_s):
+        return _block_decode_paged(
+            p, x, k_l, v_l, pos, rows_of(block_tables),
             num_heads=num_heads, page_size=page_size, k_s=k_s, v_s=v_s,
             kernel=kernel, mesh=mesh,
         )
-        return carry, (k_l, v_l, k_s, v_s)
 
-    xs = (
-        params["blocks"],
-        jnp.moveaxis(cache["k"], 1, 0),
-        jnp.moveaxis(cache["v"], 1, 0),
-        jnp.moveaxis(cache["k_scale"], 1, 0) if quantized else None,
-        jnp.moveaxis(cache["v_scale"], 1, 0) if quantized else None,
-    )
-    x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(body, x, xs)
-    new_cache = {
-        "k": jnp.moveaxis(k_new, 0, 1),
-        "v": jnp.moveaxis(v_new, 0, 1),
-    }
-    if quantized:
-        new_cache["k_scale"] = jnp.moveaxis(ks_new, 0, 1)
-        new_cache["v_scale"] = jnp.moveaxis(vs_new, 0, 1)
+    x, new_cache = _scan_pool(params["blocks"], x, cache, layer)
     return _mm(x, params["head"]), new_cache
 
 
@@ -503,6 +525,9 @@ def forward_prefill_chunk(
     overflow the block table (final-chunk padding) are routed to the
     scratch page; their outputs are garbage and the caller ignores them.
 
+    The pool is updated IN PLACE (:func:`_scan_pool`): layer ``l`` writes
+    page ``p`` at row ``p * L + l``, overflow at the scratch page's row ``l``.
+
     Int8 pool: the chunk's K/V quantize on write (per-position-per-head
     scales) and the page gather dequantizes into attention — so chunk
     token ``i`` attends to the same cache-roundtripped history a later
@@ -512,7 +537,6 @@ def forward_prefill_chunk(
     if b != 1:
         raise ValueError(f"chunked prefill is per-sequence, got batch {b}")
     nb = block_table.shape[0]
-    s = nb * page_size
     posns = offset + jnp.arange(C)  # [C] logical positions
     page_idx = posns // page_size
     in_range = page_idx < nb
@@ -528,10 +552,9 @@ def forward_prefill_chunk(
     )  # [C, d]
     d = x.shape[-1]
     hd = d // num_heads
-    quantized = quantized_cache(cache)
 
-    def body(carry, xs):
-        p, k_l, v_l, k_s, v_s = xs
+    def layer(p, rows_of, carry, k_l, v_l, k_s, v_s):
+        rows = rows_of(pages)  # overflow -> the scratch page's own layer
         h = _layer_norm(carry, p["ln1"])
         qkv = _mm(h, p["qkv"])  # [C, 3d]
         q, k_c, v_c = jnp.split(qkv, 3, axis=-1)
@@ -541,13 +564,13 @@ def forward_prefill_chunk(
         if k_s is not None:
             kq, ks_c = _q_kv(k_c)
             vq, vs_c = _q_kv(v_c)
-            k_l = k_l.at[pages, offs].set(kq)
-            v_l = v_l.at[pages, offs].set(vq)
-            k_s = k_s.at[pages, offs].set(ks_c)
-            v_s = v_s.at[pages, offs].set(vs_c)
+            k_l = k_l.at[rows, offs].set(kq)
+            v_l = v_l.at[rows, offs].set(vq)
+            k_s = k_s.at[rows, offs].set(ks_c)
+            v_s = v_s.at[rows, offs].set(vs_c)
         else:
-            k_l = k_l.at[pages, offs].set(k_c.astype(k_l.dtype))
-            v_l = v_l.at[pages, offs].set(v_c.astype(v_l.dtype))
+            k_l = k_l.at[rows, offs].set(k_c.astype(k_l.dtype))
+            v_l = v_l.at[rows, offs].set(v_c.astype(v_l.dtype))
         # Prefill attends over the cache-roundtripped values for the own
         # chunk TOO (no exact-self overlay on int8 pools, unlike decode):
         # per-token quantization is chunk-ALIGNMENT-invariant, so a
@@ -556,7 +579,7 @@ def forward_prefill_chunk(
         # exact-own-chunk window would make the numbers depend on where
         # the chunk boundaries fell.  Both kernels preserve this.
         ctx = _fd.chunk_attention(
-            q, k_l, v_l, k_s, v_s, block_table, posns,
+            q, k_l, v_l, k_s, v_s, rows_of(block_table), posns,
             page_size=page_size, kernel=kernel, mesh=mesh,
         ).reshape(C, d).astype(carry.dtype)
         out = carry + _mm(ctx, p["proj"])
@@ -564,23 +587,9 @@ def forward_prefill_chunk(
         out = out + _mm(
             jax.nn.gelu(_mm(h, p["w_in"]), approximate=False), p["w_out"]
         )
-        return out, (k_l, v_l, k_s, v_s)
+        return out, k_l, v_l, k_s, v_s
 
-    xs = (
-        params["blocks"],
-        jnp.moveaxis(cache["k"], 1, 0),
-        jnp.moveaxis(cache["v"], 1, 0),
-        jnp.moveaxis(cache["k_scale"], 1, 0) if quantized else None,
-        jnp.moveaxis(cache["v_scale"], 1, 0) if quantized else None,
-    )
-    x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(body, x, xs)
-    new_cache = {
-        "k": jnp.moveaxis(k_new, 0, 1),
-        "v": jnp.moveaxis(v_new, 0, 1),
-    }
-    if quantized:
-        new_cache["k_scale"] = jnp.moveaxis(ks_new, 0, 1)
-        new_cache["v_scale"] = jnp.moveaxis(vs_new, 0, 1)
+    x, new_cache = _scan_pool(params["blocks"], x, cache, layer)
     return _mm(x, params["head"])[None], new_cache
 
 
@@ -689,7 +698,8 @@ def forward_verify_paged(
     attention runs over the block-table-gathered page view masked to
     ``<= pos + j`` per query.  Bit-identical to the dense verify (the
     gathered view IS the dense key sequence) and therefore to sequential
-    paged decode.  f32 pool only, like the dense verify.
+    paged decode.  f32 pool only, like the dense verify.  The pool is
+    updated in place, page ``p`` at row ``p * L + l`` (:func:`_scan_pool`).
     """
     if quantized_cache(cache):
         raise ValueError(
@@ -699,7 +709,6 @@ def forward_verify_paged(
         )
     b, K1 = tokens.shape
     nb = block_tables.shape[1]
-    s = nb * page_size
     posmat = pos[:, None] + jnp.arange(K1)[None]  # [B, K1]
     valid = jnp.arange(K1)[None] <= draft_len[:, None]
     max_len = params["pos"].shape[0]
@@ -719,18 +728,18 @@ def forward_verify_paged(
     )
     offs = jnp.where(in_range, posmat % page_size, 0)
 
-    def body(carry, xs):
-        p, k_l, v_l = xs
+    def layer(p, rows_of, carry, k_l, v_l, k_s, v_s):
+        wrows = rows_of(pages)
         h = _layer_norm(carry, p["ln1"])
         qkv = _mm(h, p["qkv"])  # [B, K1, 3d]
         q, k_c, v_c = jnp.split(qkv, 3, axis=-1)
         q = q.reshape(b, K1, num_heads, hd)
         k_c = k_c.reshape(b, K1, num_heads, hd)
         v_c = v_c.reshape(b, K1, num_heads, hd)
-        k_l = k_l.at[pages, offs].set(k_c.astype(k_l.dtype))
-        v_l = v_l.at[pages, offs].set(v_c.astype(v_l.dtype))
+        k_l = k_l.at[wrows, offs].set(k_c.astype(k_l.dtype))
+        v_l = v_l.at[wrows, offs].set(v_c.astype(v_l.dtype))
         ctx = _fd.verify_attention_paged(
-            q, k_l, v_l, block_tables, posmat,
+            q, k_l, v_l, rows_of(block_tables), posmat,
             page_size=page_size, kernel=kernel, mesh=mesh,
         ).reshape(b, K1, d).astype(carry.dtype)
         out = carry + _mm(ctx, p["proj"])
@@ -738,18 +747,9 @@ def forward_verify_paged(
         out = out + _mm(
             jax.nn.gelu(_mm(h, p["w_in"]), approximate=False), p["w_out"]
         )
-        return out, (k_l, v_l)
+        return out, k_l, v_l, k_s, v_s
 
-    xs = (
-        params["blocks"],
-        jnp.moveaxis(cache["k"], 1, 0),
-        jnp.moveaxis(cache["v"], 1, 0),
-    )
-    x, (k_new, v_new) = jax.lax.scan(body, x, xs)
-    new_cache = {
-        "k": jnp.moveaxis(k_new, 0, 1),
-        "v": jnp.moveaxis(v_new, 0, 1),
-    }
+    x, new_cache = _scan_pool(params["blocks"], x, cache, layer)
     return _mm(x, params["head"]), new_cache
 
 

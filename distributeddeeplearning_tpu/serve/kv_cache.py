@@ -171,9 +171,15 @@ def init_paged_cache(
 
     ``num_pages`` counts USABLE pages; one extra scratch page (id 0,
     :data:`SCRATCH_PAGE`) is prepended so inactive decode lanes have a safe
-    write target.  Page-major so one page is a contiguous leading-dim slice
-    and the block-table gather in ``forward_decode_paged`` is a single
-    leading-axis take.
+    write target.  Page-major so one page is a contiguous leading-dim slice:
+    the paged forwards (``models.pipelined_transformer._scan_pool``) view a
+    leaf as rows ``[(pages+1) * L, page_size, ...]``, layer ``l`` of page
+    ``p`` at row ``p * L + l``, write new positions into the donated pool
+    in place and gather a block table's rows with one leading-axis take.
+    (The view moves nothing where the leaf lies row-major on the device.
+    A TPU lays an array out by its shape alone: at ``head_dim`` 64 it
+    makes the PAGE axis the minor one, and transposes on the way in and
+    out of every program that addresses pages; ``PERF.md`` section 7.)
 
     ``dtype=jnp.int8`` adds f32 scale pools ``{"k_scale", "v_scale"}``,
     each [pages, L, page_size, h] — one scale per stored K/V vector, so
