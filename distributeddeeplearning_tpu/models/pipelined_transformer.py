@@ -79,16 +79,38 @@ def _layer_norm(x, scale):
     return (x - mu) * jax.lax.rsqrt(var + 1e-6) * scale
 
 
-def block_apply(
-    p: Dict[str, jax.Array],
-    x: jax.Array,
-    *,
-    num_heads: int,
-    attention: str = "dense",
-    attention_fn=None,
-    return_kv: bool = False,
-):
-    """One pre-LN transformer block; ``p`` leaves are per-layer ([...] no L).
+def _block(p: Dict[str, jax.Array], x: jax.Array, *, num_heads: int, attend):
+    """THE pre-LN transformer layer, under every forward of this module;
+    ``p`` leaves are per-layer (no [L] dim).
+
+    ``x`` is the residual stream with the model dim last: ``[B, d]``
+    (decode), ``[C, d]`` (chunk), ``[B, K1, d]`` (verify) or ``[b, s, d]``
+    (training, prefill); the layer does not look at which.  What differs
+    between the forwards is how keys and values are addressed, and that is
+    the callback's: ``attend(q, k, v) -> (ctx, aux)`` takes this layer's
+    projections split to heads (``x.shape[:-1] + (num_heads, hd)``), writes
+    whatever cache it owns, and returns the attended context in ``q``'s
+    shape beside whatever its forward's scan collects per layer (the
+    written cache leaves, the layer's ``(k, v)``, or None).
+
+    Returns ``(x, aux)``: a ``lax.scan`` body's ``(carry, y)``.
+    """
+    d = x.shape[-1]
+    heads = x.shape[:-1] + (num_heads, d // num_heads)
+
+    h = _layer_norm(x, p["ln1"])
+    q, k, v = jnp.split(_mm(h, p["qkv"]), 3, axis=-1)  # fused [..., 3d]
+    ctx, aux = attend(q.reshape(heads), k.reshape(heads), v.reshape(heads))
+    x = x + _mm(ctx.reshape(x.shape).astype(x.dtype), p["proj"])
+
+    h = _layer_norm(x, p["ln2"])
+    x = x + _mm(jax.nn.gelu(_mm(h, p["w_in"]), approximate=False), p["w_out"])
+    return x, aux
+
+
+def _causal_attention(attention: str, attention_fn, dtype):
+    """How a whole sequence attends over itself (training, prefill):
+    ``(q, k, v) -> ctx``, all ``[b, s, h, hd]``, for :func:`_block`.
 
     ``attention``: ``"dense"`` materializes the [b,h,s,s] score matrix with a
     tril mask; ``"flash"`` runs the causal Pallas kernel
@@ -100,39 +122,24 @@ def block_apply(
     must enforce causality itself — bind
     ``ops.make_ring_attention(mesh, causal=True)`` or
     ``ops.make_ulysses_attention(mesh, causal=True)`` for the
-    sequence-parallel decoder.
-
-    ``return_kv=True`` additionally returns this layer's key/value
-    projections as ``(k, v)`` in ``[b, s, h, hd]`` layout — the prefill
-    pass of the serving engine (``serve.engine``) captures them into the
-    KV cache so decode never recomputes the prompt.
+    sequence-parallel decoder.  ``dtype`` is the residual stream's.
     """
-    b, s, d = x.shape
-    hd = d // num_heads
-
-    h = _layer_norm(x, p["ln1"])
-    qkv = _mm(h, p["qkv"])  # [b, s, 3d]
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    kv = None
-    if return_kv:
-        kv = (k.reshape(b, s, num_heads, hd), v.reshape(b, s, num_heads, hd))
     if attention_fn is not None:
-        split4 = lambda t: t.reshape(b, s, num_heads, hd)  # noqa: E731
-        ctx = attention_fn(
-            split4(q), split4(k), split4(v), None, dtype=x.dtype
-        ).reshape(b, s, d).astype(x.dtype)
-    elif attention == "flash":
+        return lambda q, k, v: attention_fn(q, k, v, None, dtype=dtype)
+    if attention == "flash":
         from distributeddeeplearning_tpu.ops.flash_attention import (
             flash_attention,
         )
 
-        split4 = lambda t: t.reshape(b, s, num_heads, hd)  # noqa: E731
-        ctx = flash_attention(
-            split4(q), split4(k), split4(v), None, dtype=x.dtype, causal=True
-        ).reshape(b, s, d)
-    elif attention == "dense":
-        split = lambda t: t.reshape(b, s, num_heads, hd).transpose(0, 2, 1, 3)  # noqa: E731
-        q, k, v = split(q), split(k), split(v)
+        return lambda q, k, v: flash_attention(
+            q, k, v, None, dtype=dtype, causal=True
+        )
+    if attention != "dense":
+        raise ValueError(f"unknown attention {attention!r}")
+
+    def dense(q, k, v):
+        s, hd = q.shape[1], q.shape[-1]
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [b, h, s, hd]
         scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / jnp.sqrt(
             jnp.asarray(hd, jnp.float32)
         )
@@ -143,17 +150,9 @@ def block_apply(
         # would silently promote to f32 and break the scan-over-layers
         # carry contract.
         attn = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-        ctx = jnp.einsum("bhqk,bhkd->bhqd", attn, v)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, d).astype(x.dtype)
-    else:
-        raise ValueError(f"unknown attention {attention!r}")
-    x = x + _mm(ctx, p["proj"])
+        return jnp.einsum("bhqk,bhkd->bhqd", attn, v).transpose(0, 2, 1, 3)
 
-    h = _layer_norm(x, p["ln2"])
-    x = x + _mm(jax.nn.gelu(_mm(h, p["w_in"]), approximate=False), p["w_out"])
-    if return_kv:
-        return x, kv
-    return x
+    return dense
 
 
 def _stack_scan(
@@ -173,14 +172,12 @@ def _stack_scan(
     memory O(1) in depth, the long-context enabler (seq-32k needs it: 12
     saved [S, d_ff] intermediates alone are 2.25 GB bf16 at S=32k).
     """
+    causal = _causal_attention(attention, attention_fn, x.dtype)
 
     def body(carry, layer_params):
-        return (
-            block_apply(
-                layer_params, carry, num_heads=num_heads, attention=attention,
-                attention_fn=attention_fn,
-            ),
-            None,
+        return _block(
+            layer_params, carry, num_heads=num_heads,
+            attend=lambda q, k, v: (causal(q, k, v), None),
         )
 
     if remat:
@@ -214,7 +211,7 @@ def forward(
 ) -> jax.Array:
     """Next-token logits [b, s, vocab] — sequential (scan over all layers).
 
-    ``attention_fn`` (see :func:`block_apply`) plugs a causal
+    ``attention_fn`` (see :func:`_causal_attention`) plugs a causal
     sequence-parallel attention (ring / Ulysses) into every layer — the
     multi-chip long-context decoder path.  Sequential forward only: the
     SP ops shard_map over the mesh themselves, which cannot nest inside
@@ -243,81 +240,59 @@ def forward_prefill(
 
     Same math as :func:`forward` (the parity test pins it), but the layer
     scan also emits each layer's key/value projections so the caller can
-    seed a KV cache — the prefill half of the prefill/decode split.
+    seed a KV cache and decode never recomputes the prompt — the prefill
+    half of the prefill/decode split.
 
     Returns ``(logits [b, s, vocab], k, v)`` with k/v in the cache layout
     ``[b, L, s, h, hd]`` (``serve.kv_cache`` slot layout minus the slot
     padding).  ``attention="flash"`` runs the causal Pallas kernel for the
     prompt pass — the O(S²)-free long-prompt path; under a mesh the caller
     passes the per-shard form as ``attention_fn`` (see
-    :func:`block_apply`), since a bare kernel cannot be partitioned.
+    :func:`_causal_attention`), since a bare kernel cannot be partitioned.
     """
     x = _embed(params, tokens)
+    causal = _causal_attention(attention, attention_fn, x.dtype)
 
     def body(carry, layer_params):
-        h, kv = block_apply(
-            layer_params, carry, num_heads=num_heads, attention=attention,
-            attention_fn=attention_fn, return_kv=True,
+        return _block(
+            layer_params, carry, num_heads=num_heads,
+            attend=lambda q, k, v: (causal(q, k, v), (k, v)),
         )
-        return h, kv
 
     x, (k, v) = jax.lax.scan(body, x, params["blocks"])
     # scan stacks layer-major [L, b, s, h, hd]; the cache is slot-major
     return _mm(x, params["head"]), jnp.moveaxis(k, 0, 1), jnp.moveaxis(v, 0, 1)
 
 
-def _block_decode(p, x, k_l, v_l, pos, *, num_heads: int, k_s=None, v_s=None,
-                  kernel: str = "gather", mesh=None):
-    """One block's single-token decode against its cache layer.
+def _embed_at(params, tokens, positions):
+    """Token plus learned position embedding for the cached forwards, whose
+    tokens sit at ``positions`` of their own (same shape as ``tokens``).
+    Padding past the table (a final chunk, an invalid verify column) reads
+    its last row: those outputs are garbage the caller ignores."""
+    last = params["pos"].shape[0] - 1
+    return params["embed"][tokens] + params["pos"][jnp.minimum(positions, last)]
 
-    ``x``: [B, d] residual stream for the current token of every slot;
-    ``k_l``/``v_l``: [B, S, h, hd] this layer's cache; ``pos``: [B] the
-    position each slot's current token occupies.  The new token's K/V are
-    scattered into the cache *before* attention (each slot at its own
-    position — slots decode at unequal depths under continuous batching),
-    then attention runs against positions ``<= pos`` through
-    :mod:`ops.flash_decode` (``kernel="gather"`` is the legacy dense
-    read, ``"flash"`` the fused kernel/twin).  Exactly
-    :func:`block_apply`'s math restricted to one query row.
 
-    ``k_s``/``v_s`` ([B, S, h] f32, int8 cache only): per-position-per-
-    head scales.  The new token's K/V quantize on write (values + their
-    own scales) and attention reads the dequantized view — under the
-    gather kernel as a history-granular select+multiply, under the flash
-    kernel with the scales folded into the score/probability vectors (or
-    applied in-tile on TPU) so f32 history is never materialized.  Both
-    attend the EXACT current token (storage is quantized, the in-flight
-    value costs nothing to keep f32) — only stored history pays the
-    8-bit grid.
-    """
-    b, d = x.shape
-    hd = d // num_heads
+def _write_kv(leaves, at, k, v):
+    """The K/V write of the cached forwards: the new tokens' ``k``/``v``
+    (``[..., h, hd]``) into this layer's cache ``leaves = (k_l, v_l, k_s,
+    v_s)`` at index ``at``; returns the written leaves.
 
-    h = _layer_norm(x, p["ln1"])
-    qkv = _mm(h, p["qkv"])  # [b, 3d]
-    q, k_t, v_t = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(b, num_heads, hd)
-    k_t = k_t.reshape(b, num_heads, hd)
-    v_t = v_t.reshape(b, num_heads, hd)
-    rows = jnp.arange(b)
-    if k_s is not None:
-        kq, ks_t = _q_kv(k_t)
-        vq, vs_t = _q_kv(v_t)
-        k_l = k_l.at[rows, pos].set(kq)
-        v_l = v_l.at[rows, pos].set(vq)
-        k_s = k_s.at[rows, pos].set(ks_t)
-        v_s = v_s.at[rows, pos].set(vs_t)
-    else:
-        k_l = k_l.at[rows, pos].set(k_t.astype(k_l.dtype))
-        v_l = v_l.at[rows, pos].set(v_t.astype(v_l.dtype))
-    ctx = _fd.decode_attention_dense(
-        q, k_l, v_l, k_s, v_s, k_t, v_t, pos, kernel=kernel, mesh=mesh
-    ).reshape(b, d).astype(x.dtype)
-    x = x + _mm(ctx, p["proj"])
-
-    h = _layer_norm(x, p["ln2"])
-    x = x + _mm(jax.nn.gelu(_mm(h, p["w_in"]), approximate=False), p["w_out"])
-    return x, k_l, v_l, k_s, v_s
+    ``k_s``/``v_s`` (``k_l``'s shape minus ``hd``, f32) exist under the int8
+    layout only and are None otherwise.  There the new tokens quantize on
+    write (values and their own per-position-per-head scales) and only
+    stored history pays the 8-bit grid: a decode step still attends its
+    EXACT current token, which the caller hands to attention beside the
+    cache (storage is quantized, the in-flight value costs nothing to keep
+    f32)."""
+    k_l, v_l, k_s, v_s = leaves
+    if k_s is None:
+        return (k_l.at[at].set(k.astype(k_l.dtype)),
+                v_l.at[at].set(v.astype(v_l.dtype)), None, None)
+    kq, ks = _q_kv(k)
+    vq, vs = _q_kv(v)
+    return (k_l.at[at].set(kq), v_l.at[at].set(vq),
+            k_s.at[at].set(ks), v_s.at[at].set(vs))
 
 
 def forward_decode(params, token, cache, pos, *, num_heads: int,
@@ -329,13 +304,19 @@ def forward_decode(params, token, cache, pos, *, num_heads: int,
     slots at different depths); ``cache``: ``{"k", "v"}`` each
     ``[B, L, S, h, hd]`` (:mod:`serve.kv_cache` layout), plus
     ``{"k_scale", "v_scale"}`` ([B, L, S, h] f32) under the int8 layout —
-    writes quantize, reads dequantize fused into attention.
+    writes quantize (:func:`_write_kv`), reads dequantize fused into
+    attention.
+
+    Each layer scatters the new token's K/V into its cache *before*
+    attention (each slot at its own position), then attends positions
+    ``<= pos``: exactly :func:`forward`'s math restricted to one query row.
 
     ``kernel``: how attention consumes the cache (``ops.flash_decode``):
-    ``"gather"`` is the legacy dense read; ``"flash"`` the paged
-    flash-decode kernel (Pallas on TPU — in-tile dequant, f32 history
-    never in HBM; the fused-XLA twin elsewhere, bitwise identical to
-    gather for f32 caches).
+    ``"gather"`` is the legacy dense read (an int8 cache dequantized at
+    history granularity); ``"flash"`` the paged flash-decode kernel (Pallas
+    on TPU — in-tile dequant, f32 history never in HBM; the fused-XLA twin
+    elsewhere, scales folded into the score/probability vectors, bitwise
+    identical to gather for f32 caches).
 
     Returns ``(logits [B, vocab], new_cache)`` where ``new_cache`` has the
     token's K/V written at ``pos`` in every layer.  O(S·d) per token per
@@ -346,16 +327,22 @@ def forward_decode(params, token, cache, pos, *, num_heads: int,
     Jit with the cache donated (``serve.engine`` does) so the [B,L,S,h,hd]
     buffers update in place instead of doubling HBM per step.
     """
-    x = params["embed"][token] + params["pos"][pos]  # [B, d]
+    x = _embed_at(params, token, pos)  # [B, d]
     quantized = quantized_cache(cache)
 
     def body(carry, xs):
-        p, k_l, v_l, k_s, v_s = xs
-        carry, k_l, v_l, k_s, v_s = _block_decode(
-            p, carry, k_l, v_l, pos, num_heads=num_heads, k_s=k_s, v_s=v_s,
-            kernel=kernel, mesh=mesh,
-        )
-        return carry, (k_l, v_l, k_s, v_s)
+        p, *leaves = xs
+
+        def attend(q, k_t, v_t):
+            k_l, v_l, k_s, v_s = written = _write_kv(
+                leaves, (jnp.arange(pos.shape[0]), pos), k_t, v_t
+            )
+            ctx = _fd.decode_attention_dense(
+                q, k_l, v_l, k_s, v_s, k_t, v_t, pos, kernel=kernel, mesh=mesh
+            )
+            return ctx, written
+
+        return _block(p, carry, num_heads=num_heads, attend=attend)
 
     xs = (
         params["blocks"],
@@ -375,7 +362,7 @@ def forward_decode(params, token, cache, pos, *, num_heads: int,
     return _mm(x, params["head"]), new_cache
 
 
-def _scan_pool(blocks, x, cache, layer):
+def _scan_pool(blocks, x, cache, attend_of, *, num_heads: int):
     """Walk the layers over a PAGED pool, in place: the one way the paged
     forwards (decode, chunk, verify) touch it.
 
@@ -387,9 +374,11 @@ def _scan_pool(blocks, x, cache, layer):
     so a layer's ``.at[rows, offs].set`` writes the donated pool where it
     lies and a call moves the positions it writes, not the pool.
 
-    ``layer(p, rows_of, x, k, v, k_s, v_s) -> (x, k, v, k_s, v_s)`` is one
-    block over the rows (``k_s``/``v_s`` None for an f32 pool);
-    ``rows_of(pages)`` maps physical page ids to this layer's rows.
+    Each layer is :func:`_block` under ``attend_of(rows_of, leaves)``: the
+    forward's callback over this layer's rows, ``leaves = (k, v, k_s,
+    v_s)`` as :func:`_write_kv` takes and returns them (``k_s``/``v_s``
+    None for an f32 pool), which the callback hands back written as its
+    ``aux``; ``rows_of(pages)`` maps physical page ids to the layer's rows.
     Returns ``(x, new_cache)`` in the pool's own shape."""
     L = cache["k"].shape[1]
     names = ("k", "v", "k_scale", "v_scale")
@@ -399,70 +388,15 @@ def _scan_pool(blocks, x, cache, layer):
     )
 
     def body(carry, xs):
-        p, l = xs
-        return layer(p, lambda pages: pages * L + l, *carry), None
+        (x, pool), (p, l) = carry, xs
+        attend = attend_of(lambda pages: pages * L + l, pool)
+        return _block(p, x, num_heads=num_heads, attend=attend), None
 
-    (x, *pool), _ = jax.lax.scan(body, (x, *pool), (blocks, jnp.arange(L)))
+    (x, pool), _ = jax.lax.scan(body, (x, pool), (blocks, jnp.arange(L)))
     return x, {
         n: rows.reshape(cache[n].shape)
         for n, rows in zip(names, pool) if rows is not None
     }
-
-
-def _block_decode_paged(
-    p, x, k_l, v_l, pos, block_tables, *, num_heads: int, page_size: int,
-    k_s=None, v_s=None, kernel: str = "gather", mesh=None,
-):
-    """One block's single-token decode against a PAGED cache layer.
-
-    ``k_l``/``v_l``: [pages, page_size, h, hd] — this layer's slice of the
-    global page pool; ``block_tables``: [B, nb] int32 mapping each slot's
-    logical page index to a physical page (logical position ``j`` lives at
-    ``(table[j // page_size], j % page_size)``).  Same write-then-attend
-    order as :func:`_block_decode`: the new token's K/V scatter to
-    ``(table[pos // ps], pos % ps)``, then attention runs over the slot's
-    pages with positions ``<= pos`` visible — via the block-table gather
-    (``kernel="gather"``) or the paged flash-decode kernel
-    (``kernel="flash"``: pages stream directly, int8 dequant in-tile /
-    scale-folded; :mod:`ops.flash_decode`).  Released slots point every
-    table entry at the scratch page and sit at pos 0, so their writes
-    land in the dustbin and never touch a live page.
-
-    ``k_s``/``v_s`` ([pages, page_size, h] f32, int8 pool only): writes
-    quantize per head; attention reads the dequantized view with the
-    exact current token overlaid (see :func:`_block_decode`).
-    """
-    b, d = x.shape
-    hd = d // num_heads
-
-    h = _layer_norm(x, p["ln1"])
-    qkv = _mm(h, p["qkv"])  # [b, 3d]
-    q, k_t, v_t = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(b, num_heads, hd)
-    k_t = k_t.reshape(b, num_heads, hd)
-    v_t = v_t.reshape(b, num_heads, hd)
-    rows = jnp.arange(b)
-    page = block_tables[rows, pos // page_size]  # [b] physical page
-    off = pos % page_size
-    if k_s is not None:
-        kq, ks_t = _q_kv(k_t)
-        vq, vs_t = _q_kv(v_t)
-        k_l = k_l.at[page, off].set(kq)
-        v_l = v_l.at[page, off].set(vq)
-        k_s = k_s.at[page, off].set(ks_t)
-        v_s = v_s.at[page, off].set(vs_t)
-    else:
-        k_l = k_l.at[page, off].set(k_t.astype(k_l.dtype))
-        v_l = v_l.at[page, off].set(v_t.astype(v_l.dtype))
-    ctx = _fd.decode_attention_paged(
-        q, k_l, v_l, k_s, v_s, k_t, v_t, pos, block_tables,
-        page_size=page_size, kernel=kernel, mesh=mesh,
-    ).reshape(b, d).astype(x.dtype)
-    x = x + _mm(ctx, p["proj"])
-
-    h = _layer_norm(x, p["ln2"])
-    x = x + _mm(jax.nn.gelu(_mm(h, p["w_in"]), approximate=False), p["w_out"])
-    return x, k_l, v_l, k_s, v_s
 
 
 def forward_decode_paged(
@@ -475,33 +409,43 @@ def forward_decode_paged(
     returns ``(logits [B, vocab], new_cache)`` — but ``cache`` is the
     global page pool ``{"k", "v"}`` each ``[pages, L, page_size, h, hd]``
     and ``block_tables`` ([B, nb] int32) maps each slot's logical pages to
-    physical ones.  Identical math to the dense path (the bit-exactness
-    gate in ``tests/test_paged_cache.py`` pins it): the gathered page view
+    physical ones: logical position ``j`` lives at ``(table[j //
+    page_size], j % page_size)``.  Same write-then-attend order and the
+    same two kernels (under ``"flash"`` pages stream directly).  Identical
+    math to the dense path (the bit-exactness gate in
+    ``tests/test_paged_cache.py`` pins it): the gathered page view
     reconstructs exactly the dense ``[B, S, h, hd]`` key/value sequence,
-    padded with masked positions up to ``nb * page_size``.
+    padded with masked positions up to ``nb * page_size``.  Released slots
+    point every table entry at the scratch page and sit at pos 0, so their
+    writes land in the dustbin and never touch a live page.
 
-    The pool is updated IN PLACE (:func:`_scan_pool`): layer ``l`` of
-    physical page ``p`` is row ``p * L + l`` of the pool's row view, so a
-    layer runs under ``block_tables * L + l`` and writes one position a
-    slot into the donated pool, whatever its size.
-
-    Int8 pool (``{"k_scale", "v_scale"}`` present, [pages, L, page_size,
-    h] f32): same program with quantize-on-write and the dequant read
-    fused into attention — at history granularity under ``kernel=
-    "gather"``, in-tile / scale-folded under ``kernel="flash"`` (see
-    :func:`forward_decode`) — the math matches the f32 paged path up to
-    the 8-bit grid (``bench.py --quant`` reports agreement rate and MAE).
+    The pool is updated IN PLACE (:func:`_scan_pool`): a layer runs under
+    ``block_tables * L + l`` and writes one position a slot, whatever the
+    pool's size.  An int8 pool adds ``{"k_scale", "v_scale"}`` ([pages, L,
+    page_size, h] f32) and matches the f32 paged path up to the 8-bit grid
+    (``bench.py --quant`` reports agreement rate and MAE).
     """
-    x = params["embed"][token] + params["pos"][pos]  # [B, d]
+    x = _embed_at(params, token, pos)  # [B, d]
 
-    def layer(p, rows_of, x, k_l, v_l, k_s, v_s):
-        return _block_decode_paged(
-            p, x, k_l, v_l, pos, rows_of(block_tables),
-            num_heads=num_heads, page_size=page_size, k_s=k_s, v_s=v_s,
-            kernel=kernel, mesh=mesh,
-        )
+    def attend_of(rows_of, pool):
+        tables = rows_of(block_tables)
 
-    x, new_cache = _scan_pool(params["blocks"], x, cache, layer)
+        def attend(q, k_t, v_t):
+            page = tables[jnp.arange(pos.shape[0]), pos // page_size]
+            k_l, v_l, k_s, v_s = written = _write_kv(
+                pool, (page, pos % page_size), k_t, v_t
+            )
+            ctx = _fd.decode_attention_paged(
+                q, k_l, v_l, k_s, v_s, k_t, v_t, pos, tables,
+                page_size=page_size, kernel=kernel, mesh=mesh,
+            )
+            return ctx, written
+
+        return attend
+
+    x, new_cache = _scan_pool(
+        params["blocks"], x, cache, attend_of, num_heads=num_heads
+    )
     return _mm(x, params["head"]), new_cache
 
 
@@ -518,20 +462,18 @@ def forward_prefill_chunk(
     page view — chunk token ``i`` sees every cached position
     ``<= offset + i``: the whole already-prefilled history (earlier
     chunks, shared prefix pages) plus the causal part of its own chunk.
-    Exactly :func:`block_apply`'s math with the key space routed through
-    the page pool.
+    Exactly :func:`forward`'s math with the key space routed through the
+    page pool.
 
-    Returns ``(logits [1, C, vocab], new_cache)``.  Positions that
-    overflow the block table (final-chunk padding) are routed to the
-    scratch page; their outputs are garbage and the caller ignores them.
+    Returns ``(logits [1, C, vocab], new_cache)``, the pool updated in
+    place (:func:`_scan_pool`).  Positions that overflow the block table
+    (final-chunk padding) are routed to the scratch page, layer ``l``'s at
+    its row ``l``; their outputs are garbage and the caller ignores them.
 
-    The pool is updated IN PLACE (:func:`_scan_pool`): layer ``l`` writes
-    page ``p`` at row ``p * L + l``, overflow at the scratch page's row ``l``.
-
-    Int8 pool: the chunk's K/V quantize on write (per-position-per-head
-    scales) and the page gather dequantizes into attention — so chunk
-    token ``i`` attends to the same cache-roundtripped history a later
-    decode step will read, keeping prefill and decode numerics coherent.
+    Int8 pool: the chunk's K/V quantize on write (:func:`_write_kv`) and
+    the page gather dequantizes into attention — so chunk token ``i``
+    attends to the same cache-roundtripped history a later decode step
+    will read, keeping prefill and decode numerics coherent.
     """
     b, C = tokens.shape
     if b != 1:
@@ -544,52 +486,33 @@ def forward_prefill_chunk(
         in_range, block_table[jnp.minimum(page_idx, nb - 1)], 0
     )  # overflow (padding past max_seq) -> scratch page
     offs = posns % page_size
+    x = _embed_at(params, tokens[0], posns)  # [C, d]
 
-    max_len = params["pos"].shape[0]
-    x = (
-        params["embed"][tokens[0]]
-        + params["pos"][jnp.minimum(posns, max_len - 1)]
-    )  # [C, d]
-    d = x.shape[-1]
-    hd = d // num_heads
-
-    def layer(p, rows_of, carry, k_l, v_l, k_s, v_s):
+    def attend_of(rows_of, pool):
         rows = rows_of(pages)  # overflow -> the scratch page's own layer
-        h = _layer_norm(carry, p["ln1"])
-        qkv = _mm(h, p["qkv"])  # [C, 3d]
-        q, k_c, v_c = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(C, num_heads, hd)
-        k_c = k_c.reshape(C, num_heads, hd)
-        v_c = v_c.reshape(C, num_heads, hd)
-        if k_s is not None:
-            kq, ks_c = _q_kv(k_c)
-            vq, vs_c = _q_kv(v_c)
-            k_l = k_l.at[rows, offs].set(kq)
-            v_l = v_l.at[rows, offs].set(vq)
-            k_s = k_s.at[rows, offs].set(ks_c)
-            v_s = v_s.at[rows, offs].set(vs_c)
-        else:
-            k_l = k_l.at[rows, offs].set(k_c.astype(k_l.dtype))
-            v_l = v_l.at[rows, offs].set(v_c.astype(v_l.dtype))
-        # Prefill attends over the cache-roundtripped values for the own
-        # chunk TOO (no exact-self overlay on int8 pools, unlike decode):
-        # per-token quantization is chunk-ALIGNMENT-invariant, so a
-        # prefix-cache hit (which shifts the chunk offset by the shared
-        # length) produces bit-identical logits to a cold run — an
-        # exact-own-chunk window would make the numbers depend on where
-        # the chunk boundaries fell.  Both kernels preserve this.
-        ctx = _fd.chunk_attention(
-            q, k_l, v_l, k_s, v_s, rows_of(block_table), posns,
-            page_size=page_size, kernel=kernel, mesh=mesh,
-        ).reshape(C, d).astype(carry.dtype)
-        out = carry + _mm(ctx, p["proj"])
-        h = _layer_norm(out, p["ln2"])
-        out = out + _mm(
-            jax.nn.gelu(_mm(h, p["w_in"]), approximate=False), p["w_out"]
-        )
-        return out, k_l, v_l, k_s, v_s
 
-    x, new_cache = _scan_pool(params["blocks"], x, cache, layer)
+        def attend(q, k_c, v_c):
+            k_l, v_l, k_s, v_s = written = _write_kv(
+                pool, (rows, offs), k_c, v_c
+            )
+            # Prefill attends over the cache-roundtripped values for the own
+            # chunk TOO (no exact-self overlay on int8 pools, unlike decode):
+            # per-token quantization is chunk-ALIGNMENT-invariant, so a
+            # prefix-cache hit (which shifts the chunk offset by the shared
+            # length) produces bit-identical logits to a cold run — an
+            # exact-own-chunk window would make the numbers depend on where
+            # the chunk boundaries fell.  Both kernels preserve this.
+            ctx = _fd.chunk_attention(
+                q, k_l, v_l, k_s, v_s, rows_of(block_table), posns,
+                page_size=page_size, kernel=kernel, mesh=mesh,
+            )
+            return ctx, written
+
+        return attend
+
+    x, new_cache = _scan_pool(
+        params["blocks"], x, cache, attend_of, num_heads=num_heads
+    )
     return _mm(x, params["head"])[None], new_cache
 
 
@@ -638,13 +561,7 @@ def forward_verify(
     S = cache["k"].shape[2]
     posmat = pos[:, None] + jnp.arange(K1)[None]  # [B, K1]
     valid = jnp.arange(K1)[None] <= draft_len[:, None]
-    max_len = params["pos"].shape[0]
-    x = (
-        params["embed"][tokens]
-        + params["pos"][jnp.minimum(posmat, max_len - 1)]
-    )  # [B, K1, d]
-    d = x.shape[-1]
-    hd = d // num_heads
+    x = _embed_at(params, tokens, posmat)  # [B, K1, d]
     # invalid columns scatter out of bounds -> dropped (never clamped:
     # a clamped write could collide with a valid column's position)
     wpos = jnp.where(valid, posmat, S)
@@ -652,23 +569,16 @@ def forward_verify(
 
     def body(carry, xs):
         p, k_l, v_l = xs
-        h = _layer_norm(carry, p["ln1"])
-        qkv = _mm(h, p["qkv"])  # [B, K1, 3d]
-        q, k_c, v_c = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, K1, num_heads, hd)
-        k_c = k_c.reshape(b, K1, num_heads, hd)
-        v_c = v_c.reshape(b, K1, num_heads, hd)
-        k_l = k_l.at[rows, wpos].set(k_c.astype(k_l.dtype), mode="drop")
-        v_l = v_l.at[rows, wpos].set(v_c.astype(v_l.dtype), mode="drop")
-        ctx = _fd.verify_attention_dense(
-            q, k_l, v_l, posmat, kernel=kernel, mesh=mesh
-        ).reshape(b, K1, d).astype(carry.dtype)
-        out = carry + _mm(ctx, p["proj"])
-        h = _layer_norm(out, p["ln2"])
-        out = out + _mm(
-            jax.nn.gelu(_mm(h, p["w_in"]), approximate=False), p["w_out"]
-        )
-        return out, (k_l, v_l)
+
+        def attend(q, k_c, v_c):
+            k_w = k_l.at[rows, wpos].set(k_c.astype(k_l.dtype), mode="drop")
+            v_w = v_l.at[rows, wpos].set(v_c.astype(v_l.dtype), mode="drop")
+            ctx = _fd.verify_attention_dense(
+                q, k_w, v_w, posmat, kernel=kernel, mesh=mesh
+            )
+            return ctx, (k_w, v_w)
+
+        return _block(p, carry, num_heads=num_heads, attend=attend)
 
     xs = (
         params["blocks"],
@@ -711,13 +621,7 @@ def forward_verify_paged(
     nb = block_tables.shape[1]
     posmat = pos[:, None] + jnp.arange(K1)[None]  # [B, K1]
     valid = jnp.arange(K1)[None] <= draft_len[:, None]
-    max_len = params["pos"].shape[0]
-    x = (
-        params["embed"][tokens]
-        + params["pos"][jnp.minimum(posmat, max_len - 1)]
-    )  # [B, K1, d]
-    d = x.shape[-1]
-    hd = d // num_heads
+    x = _embed_at(params, tokens, posmat)  # [B, K1, d]
     rows = jnp.arange(b)[:, None]
     page_idx = posmat // page_size
     in_range = valid & (page_idx < nb)
@@ -728,28 +632,22 @@ def forward_verify_paged(
     )
     offs = jnp.where(in_range, posmat % page_size, 0)
 
-    def layer(p, rows_of, carry, k_l, v_l, k_s, v_s):
+    def attend_of(rows_of, pool):
         wrows = rows_of(pages)
-        h = _layer_norm(carry, p["ln1"])
-        qkv = _mm(h, p["qkv"])  # [B, K1, 3d]
-        q, k_c, v_c = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, K1, num_heads, hd)
-        k_c = k_c.reshape(b, K1, num_heads, hd)
-        v_c = v_c.reshape(b, K1, num_heads, hd)
-        k_l = k_l.at[wrows, offs].set(k_c.astype(k_l.dtype))
-        v_l = v_l.at[wrows, offs].set(v_c.astype(v_l.dtype))
-        ctx = _fd.verify_attention_paged(
-            q, k_l, v_l, rows_of(block_tables), posmat,
-            page_size=page_size, kernel=kernel, mesh=mesh,
-        ).reshape(b, K1, d).astype(carry.dtype)
-        out = carry + _mm(ctx, p["proj"])
-        h = _layer_norm(out, p["ln2"])
-        out = out + _mm(
-            jax.nn.gelu(_mm(h, p["w_in"]), approximate=False), p["w_out"]
-        )
-        return out, k_l, v_l, k_s, v_s
 
-    x, new_cache = _scan_pool(params["blocks"], x, cache, layer)
+        def attend(q, k_c, v_c):
+            k_l, v_l, _, _ = written = _write_kv(pool, (wrows, offs), k_c, v_c)
+            ctx = _fd.verify_attention_paged(
+                q, k_l, v_l, rows_of(block_tables), posmat,
+                page_size=page_size, kernel=kernel, mesh=mesh,
+            )
+            return ctx, written
+
+        return attend
+
+    x, new_cache = _scan_pool(
+        params["blocks"], x, cache, attend_of, num_heads=num_heads
+    )
     return _mm(x, params["head"]), new_cache
 
 

@@ -49,10 +49,10 @@ tests.
 
 Exact-current-token semantics are preserved: the int8 *decode* paths
 overlay the in-flight token's exact f32 K/V (storage is quantized, the
-attended view is exact — ``_block_decode``'s contract), folded at score /
-context granularity here; chunked prefill deliberately does NOT overlay
-(per-token quantization keeps prefill chunk-alignment-invariant, the
-prefix-cache bit-identity property).  Speculative verify is f32-only
+attended view is exact — the model's ``_write_kv`` contract), folded at
+score / context granularity here; chunked prefill deliberately does NOT
+overlay (per-token quantization keeps prefill chunk-alignment-invariant,
+the prefix-cache bit-identity property).  Speculative verify is f32-only
 upstream, so its flash path is the bitwise-identical f32 form.
 """
 
@@ -540,8 +540,8 @@ def decode_attention_paged(
 def _gather_decode_paged(
     q3, k_l, v_l, k_s, v_s, k_t, v_t, pos, block_tables, *, page_size: int
 ):
-    """Legacy paged decode attention (verbatim from
-    ``_block_decode_paged``): block-table gather reconstructing the dense
+    """Legacy paged decode attention (``forward_decode_paged``'s
+    contract): block-table gather reconstructing the dense
     [b, s, h, hd] view, dequant + own-token select at history granularity
     on int8 pools — the reference the flash paths are pinned against."""
     from distributeddeeplearning_tpu.quant.qtensor import dequantize_kv
@@ -607,7 +607,7 @@ def decode_attention_dense(
 
 
 def _gather_decode_dense(q3, k_l, v_l, k_s, v_s, k_t, v_t, pos):
-    """Legacy dense decode attention (verbatim from ``_block_decode``)."""
+    """Legacy dense decode attention (``forward_decode``'s contract)."""
     from distributeddeeplearning_tpu.quant.qtensor import dequantize_kv
 
     b, num_heads, hd = q3.shape
@@ -669,7 +669,7 @@ def chunk_attention(
 def _gather_chunk(
     q_c, k_l, v_l, k_s, v_s, block_table, posns, *, page_size: int
 ):
-    """Legacy chunk attention (verbatim from ``forward_prefill_chunk``)."""
+    """Legacy chunk attention (``forward_prefill_chunk``'s contract)."""
     from distributeddeeplearning_tpu.quant.qtensor import dequantize_kv
 
     C, num_heads, hd = q_c.shape
@@ -725,7 +725,7 @@ def verify_attention_dense(q4, k_l, v_l, posmat, *, kernel: str = "gather",
 
 
 def _verify_dense_math(q4, k_seq, v_seq, posmat, hd):
-    """The verify einsums (verbatim from ``forward_verify``)."""
+    """The verify einsums (``forward_verify``'s contract)."""
     s = k_seq.shape[1]
     scores = jnp.einsum("bqhd,bshd->bqhs", q4, k_seq) / _sqrt_dim(hd)
     visible = jnp.arange(s)[None, None, :] <= posmat[:, :, None]

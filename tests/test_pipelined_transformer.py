@@ -7,11 +7,14 @@ a few SGD steps must actually reduce the causal-LM loss.
 
 from __future__ import annotations
 
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributeddeeplearning_tpu.models import pipelined_transformer as pt
 from distributeddeeplearning_tpu.models.pipelined_transformer import (
     forward,
     forward_pipelined,
@@ -332,3 +335,51 @@ def test_zero3_rejects_indivisible_width():
             params, jnp.zeros((8, 16), jnp.int32), num_heads=2, mesh=mesh,
             num_microbatches=2, zero3_axis="fsdp",
         )
+
+
+SEAM_FORWARDS = (
+    "forward", "forward_prefill", "forward_decode", "forward_decode_paged",
+    "forward_prefill_chunk", "forward_verify", "forward_verify_paged",
+)
+
+
+def _call_at_tiny_geometry(name, params):
+    """One of the seven forwards, by name, on 2 slots of 2 pages of 4."""
+    L, hd = CFG["num_layers"], CFG["d_model"] // HEADS
+    B, PS, NB, K1 = 2, 4, 2, 3
+    dense = {n: jnp.zeros((B, L, NB * PS, HEADS, hd)) for n in ("k", "v")}
+    pool = {n: jnp.zeros((1 + B * NB, L, PS, HEADS, hd)) for n in ("k", "v")}
+    seq = jnp.zeros((B, 8), jnp.int32)
+    tok = pos = draft_len = jnp.zeros((B,), jnp.int32)
+    draft = jnp.zeros((B, K1), jnp.int32)
+    tables = 1 + jnp.arange(B * NB, dtype=jnp.int32).reshape(B, NB)
+    args, paged = {
+        "forward": ((seq,), False),
+        "forward_prefill": ((seq,), False),
+        "forward_decode": ((tok, dense, pos), False),
+        "forward_decode_paged": ((tok, pool, pos, tables), True),
+        "forward_prefill_chunk": (
+            (seq[:1, :PS], pool, tables[0], jnp.int32(PS)), True),
+        "forward_verify": ((draft, dense, pos, draft_len), False),
+        "forward_verify_paged": ((draft, pool, pos, draft_len, tables), True),
+    }[name]
+    kwargs = dict(page_size=PS) if paged else {}
+    return getattr(pt, name)(params, *args, num_heads=HEADS, **kwargs)
+
+
+@pytest.mark.parametrize("name", SEAM_FORWARDS)
+def test_every_forward_runs_the_one_layer(name, setup, monkeypatch):
+    """The layer is written once (``_block``) and a forward supplies only
+    how keys and values are addressed: every ``_layer_norm`` of a traced
+    forward is called from ``_block``, two a trace of the scan body.  A
+    forward that writes the layer out again fails here."""
+    callers = []
+    layer_norm = pt._layer_norm
+
+    def counted(x, scale):
+        callers.append(sys._getframe(1).f_code.co_name)
+        return layer_norm(x, scale)
+
+    monkeypatch.setattr(pt, "_layer_norm", counted)
+    jax.eval_shape(lambda p: _call_at_tiny_geometry(name, p), setup[0])
+    assert callers == ["_block"] * 2
