@@ -99,6 +99,47 @@ def quantize_params(
     return out
 
 
+@jax.jit
+def _round_to_bf16(tree):
+    return jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), tree)
+
+
+def bf16_matmul_params(params: PyTree) -> PyTree:
+    """The params pytree with its float32 matmul weights rounded to
+    bfloat16: the leaves :func:`quantize_params` quantizes
+    (``BLOCK_MATMUL_LEAVES`` of ``blocks``, and ``head``), each
+    ``leaf.astype(bfloat16)``, in ONE jitted call over all of them.
+
+    A TPU runs a default-precision float32 product as one bf16 pass with
+    float32 accumulation and rounds the weight operand on every call; a
+    tree rounded once here hands ``qmatmul`` the operand that pass reads
+    (``serve/engine.py`` decides where that holds).  Everything else
+    passes through as the very object it was: QTensor and bf16 leaves,
+    ``embed``, ``pos``, the LayerNorm scales, the leaves of another
+    model's tree (no ``blocks``).  Idempotent: a tree with no float32
+    matmul leaf comes back itself."""
+
+    def rounds(leaf):
+        return not isinstance(leaf, QTensor) and leaf.dtype == jnp.float32
+
+    blocks = params.get("blocks", {})
+    stacks = {
+        name: blocks[name] for name in BLOCK_MATMUL_LEAVES
+        if name in blocks and rounds(blocks[name])
+    }
+    head = (
+        {"head": params["head"]}
+        if "head" in params and rounds(params["head"]) else {}
+    )
+    if not stacks and not head:
+        return params
+    stacks, head = _round_to_bf16((stacks, head))
+    out = {**params, **head}
+    if stacks:
+        out["blocks"] = {**blocks, **stacks}
+    return out
+
+
 def abstract_quantized_params(params_abs: PyTree) -> PyTree:
     """ShapeDtypeStruct skeleton of :func:`quantize_params`' output with
     no quantization math run — ``jax.eval_shape`` over the PTQ transform.

@@ -190,12 +190,28 @@ def qdot(x: jax.Array, qt: QTensor) -> jax.Array:
 
 
 def qmatmul(x: jax.Array, w) -> jax.Array:
-    """The matmul dispatch the model forwards use: int8 path for QTensor
-    weights, plain ``@`` for everything else — ONE call site per matmul,
-    so an f32 and a quantized params pytree run the identical program
-    structure."""
+    """The matmul dispatch the model forwards use — ONE call site per
+    matmul, told apart by what it is handed, so an f32, a bf16-weight and
+    a quantized params pytree run the identical program structure:
+
+    - QTensor ``w``: the int8 path (:func:`qdot`);
+    - bfloat16 ``w`` under float32 ``x`` (a serving engine's weights
+      rounded once, ``quant.calibrate.bf16_matmul_params``): ``x`` rounded
+      to bf16, one bf16 product, float32 accumulation and result — the
+      product a TPU computes for ``x @ w`` on float32 operands at default
+      precision, without rounding ``w`` again on every call;
+    - everything else (f32 @ f32; bf16 @ bf16, the bf16 train step):
+      plain ``@``.
+    """
     if isinstance(w, QTensor):
         return qdot(x, w)
+    if w.dtype == jnp.bfloat16 and x.dtype == jnp.float32:
+        return jax.lax.dot_general(
+            x.astype(jnp.bfloat16),
+            w,
+            dimension_numbers=(((x.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
     return x @ w
 
 

@@ -35,6 +35,7 @@ an axis of size 1, i.e. replication); no spec is hand-wired here.
 from __future__ import annotations
 
 import logging
+import time
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
@@ -56,7 +57,11 @@ from distributeddeeplearning_tpu.ops.flash_decode import (
 )
 from distributeddeeplearning_tpu.parallel import sharding as layout
 from distributeddeeplearning_tpu.parallel.mesh import data_parallel_size
-from distributeddeeplearning_tpu.quant.calibrate import params_dtype
+from distributeddeeplearning_tpu.quant.calibrate import (
+    bf16_matmul_params,
+    params_dtype,
+)
+from distributeddeeplearning_tpu.quant.qtensor import QTensor
 from distributeddeeplearning_tpu.serve.kv_cache import (
     OutOfPages,
     PageAllocator,
@@ -205,6 +210,46 @@ def prompt_bucket(n: int, max_seq: int, floor: int = 8) -> int:
     return min(b, max_seq)
 
 
+def _f32_product_is_one_bf16_pass() -> bool:
+    """Whether a default-precision float32 matmul on this backend rounds
+    both operands to bfloat16 and accumulates in float32 (the TPU's one
+    MXU pass): where it does, weights rounded once serve the very product
+    the float32 ones would, and nowhere else."""
+    return (
+        jax.default_backend() == "tpu"
+        and jax.config.jax_default_matmul_precision in (None, "default")
+    )
+
+
+def _matmul_operands(params):
+    """The tree an engine holds and its programs read, for the tree its
+    caller hands in (at construction and at every reload): where
+    :func:`_f32_product_is_one_bf16_pass`, float32 matmul weights become
+    the bfloat16 operands the device's matmul reads
+    (``quant.calibrate.bf16_matmul_params``: one copy, made here, not one
+    a call inside every program); anywhere else, and for int8 or bf16
+    weights, the caller's own tree."""
+    if not _f32_product_is_one_bf16_pass():
+        return params
+    t0 = time.perf_counter()
+    held = jax.block_until_ready(bf16_matmul_params(params))
+    if held is not params:
+        logger.info(
+            "engine: float32 matmul weights rounded to bfloat16 once "
+            "(%.3f GB handed in, %.3f GB held, %.3f s)",
+            cache_bytes(params) / 1e9, cache_bytes(held) / 1e9,
+            time.perf_counter() - t0,
+        )
+    return held
+
+
+def _matmul_dtype(params) -> str:
+    """The dtype of the weights the matmuls read (``head`` stands for all
+    of them: both weight transforms treat the matmul leaves alike)."""
+    head = params["head"]
+    return "int8" if isinstance(head, QTensor) else str(head.dtype)
+
+
 def _check_reload_tree(old, new) -> None:
     """Reload admissibility: the new weight set must be drop-in for the
     compiled programs — same pytree structure, and every leaf aval
@@ -331,6 +376,10 @@ class InferenceEngine:
     bucket is ``max_seq`` itself, so ``max_seq`` must be a length the
     kernel's auto-selected blocks tile (up to 1024, or a multiple of
     128) — refused at construction otherwise, never rerouted to dense.
+
+    Which weights it holds (the caller's own, or on a TPU a copy of the
+    float32 ones with the matmul leaves rounded to bfloat16 once) is
+    :class:`PagedInferenceEngine`'s rule: see there.
     """
 
     def __init__(
@@ -376,6 +425,14 @@ class InferenceEngine:
         _, num_layers, head_dim = _validate_model_dims(
             params, num_heads=num_heads, max_seq=max_seq, top_k=top_k
         )
+        # provenance the ServeReport carries: an int8 artifact must be
+        # distinguishable from an f32 one without diffing configs.
+        # weights_dtype is what was handed in; matmul_dtype and
+        # weights_bytes are what the engine holds and its matmuls read
+        self.weights_dtype = params_dtype(params)
+        params = _matmul_operands(params)
+        self.matmul_dtype = _matmul_dtype(params)
+        self.weights_bytes = cache_bytes(params)  # any pytree's leaves
         self.params = params
         self.num_heads = num_heads
         self.batch_slots = batch_slots
@@ -387,10 +444,7 @@ class InferenceEngine:
         self.temperature = float(temperature)
         if cache_dtype is None:
             cache_dtype = params["embed"].dtype
-        # provenance the ServeReport carries: an int8 artifact must be
-        # distinguishable from an f32 one without diffing configs
         self.kv_dtype = np.dtype(cache_dtype).name
-        self.weights_dtype = params_dtype(params)
         self._base_rng = jax.random.key(0) if rng is None else rng
         self._sample_step = 0
         # per-slot logit-finiteness verdict of the LAST decode step,
@@ -696,9 +750,13 @@ class InferenceEngine:
         every compiled program (params travel as jit ARGUMENTS, keyed on
         avals) and the KV cache buffers stay untouched — the swap is one
         ``device_put`` onto the engine's existing param layout.  The
+        caller hands in the kind of tree it built the engine from: it
+        goes through :func:`_matmul_operands` first, as at construction,
+        and what that gives is held against what the engine holds.  The
         scheduler applies reloads only at an idle barrier between decode
         steps (``request_reload``), so no request ever sees two weight
         sets."""
+        params = _matmul_operands(params)
         _check_reload_tree(self.params, params)
         if self._params_sharding is not None:
             params = jax.device_put(params, self._params_sharding)
@@ -753,6 +811,19 @@ class PagedInferenceEngine:
     axis never shards (the block-table gather must stay chip-local), so
     TP splits weights and the cache's HEAD dim through the partition-rule
     layout table while page addressing stays on-chip.
+
+    The weights the engine holds are the ones its matmuls read.  Where a
+    default-precision float32 product is one bf16 pass (a TPU:
+    :func:`_f32_product_is_one_bf16_pass`) float32 parameters are served
+    from a copy whose matmul leaves were rounded to bfloat16 once, at
+    construction and again at every ``reload_params`` — the product the
+    device computed anyway, without rounding every weight stack on every
+    call — and the engine keeps no reference to the float32 stacks (the
+    caller's tree is untouched; ``embed``, ``pos`` and the LayerNorm
+    scales are shared with it).  Anywhere else, and for int8 or bf16
+    weights, ``engine.params`` is the very tree that was handed in.
+    ``weights_dtype`` names what was handed in; ``matmul_dtype`` and
+    ``weights_bytes`` what is held (the ``ServeReport`` carries all three).
 
     The model comes through one description, ``model`` (a
     :class:`~.served_model.ServedModel`: its two forwards, its cache
@@ -832,6 +903,11 @@ class PagedInferenceEngine:
             )
         self.kv_layout = "paged"
         self.chunked_prefill = True
+        # see InferenceEngine: what was handed in, then what is held
+        self.weights_dtype = params_dtype(params)
+        params = _matmul_operands(params)
+        self.matmul_dtype = _matmul_dtype(params)
+        self.weights_bytes = cache_bytes(params)  # any pytree's leaves
         self.params = params
         self.num_heads = num_heads
         self.batch_slots = batch_slots
@@ -865,7 +941,6 @@ class PagedInferenceEngine:
         if cache_dtype is None:
             cache_dtype = params["embed"].dtype
         self.kv_dtype = np.dtype(cache_dtype).name
-        self.weights_dtype = params_dtype(params)
         # fidelity-probe hook (bench.py --quant): keep the last decode
         # step's / final prefill chunk's logits host-side for comparison
         # against a reference engine — off in production (one extra
@@ -1543,7 +1618,8 @@ class PagedInferenceEngine:
     def reload_params(self, params) -> None:
         """Swap the engine's weight set IN PLACE (see the dense engine's
         docstring for the same-avals contract — compiled programs and the
-        page pool stay untouched).
+        page pool stay untouched — and for what becomes of a float32 tree
+        where the engine serves a bf16 copy of its matmul weights).
 
         Paged extras: refuses while any slot holds pages (a live slot
         spanning the swap would decode new-weight queries against
@@ -1559,6 +1635,7 @@ class PagedInferenceEngine:
                 "between requests; drain the slots first (the scheduler's "
                 "request_reload does)"
             )
+        params = _matmul_operands(params)
         _check_reload_tree(self.params, params)
         if self._params_sharding is not None:
             params = jax.device_put(params, self._params_sharding)

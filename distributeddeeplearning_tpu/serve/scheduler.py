@@ -240,6 +240,11 @@ class ServeReport:
     # artifact is distinguishable from an f32 one without diffing configs
     kv_dtype: str = "float32"
     weights_dtype: str = "float32"
+    # what the engine holds of those weights: the dtype its matmuls read
+    # (bfloat16 under float32 weights_dtype = the engine rounded them
+    # once at build, serve/engine._matmul_operands) and all their bytes
+    matmul_dtype: str = "float32"
+    weights_bytes: int = 0
     # layout provenance: tensor-parallel degree the engine served at and
     # the partition-rule table that placed every array (count + digest,
     # ``parallel.sharding.layout_rules_provenance``) — a TP_* artifact is
@@ -1955,6 +1960,8 @@ class ContinuousBatchingScheduler:
             kv_layout=getattr(engine, "kv_layout", "dense"),
             kv_dtype=getattr(engine, "kv_dtype", "float32"),
             weights_dtype=getattr(engine, "weights_dtype", "float32"),
+            matmul_dtype=getattr(engine, "matmul_dtype", "float32"),
+            weights_bytes=getattr(engine, "weights_bytes", 0),
             tp=getattr(engine, "tp", 1),
             layout_rules=getattr(engine, "layout_rules", ""),
             decode_kernel=getattr(engine, "decode_kernel", "gather"),

@@ -383,3 +383,67 @@ def test_every_forward_runs_the_one_layer(name, setup, monkeypatch):
     monkeypatch.setattr(pt, "_layer_norm", counted)
     jax.eval_shape(lambda p: _call_at_tiny_geometry(name, p), setup[0])
     assert callers == ["_block"] * 2
+
+
+@pytest.mark.parametrize("name", SEAM_FORWARDS)
+def test_every_forward_reads_bf16_operand_weights(name, setup, monkeypatch):
+    """What a serving engine holds on a TPU (``quant.bf16_matmul_params``:
+    the float32 matmul weights rounded to bf16 once) runs under every
+    forward as the float32 program it was, with each matmul the product of
+    BOTH operands rounded to bf16, accumulated in float32: equal (up to
+    the order of that accumulation) to the float32 tree under a matmul
+    that rounds both operands itself, and clearly not the unrounded
+    product.  Logits, the residual stream and every cache leaf a forward
+    returns stay float32."""
+    from distributeddeeplearning_tpu.quant import bf16_matmul_params
+
+    params = setup[0]
+    held = bf16_matmul_params(params)
+    got = _call_at_tiny_geometry(name, held)
+    unrounded = _call_at_tiny_geometry(name, params)
+
+    def both_rounded(x, w):
+        assert x.dtype == jnp.float32 and w.dtype == jnp.float32
+        r = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+        return jnp.matmul(r(x), r(w), precision="highest")
+
+    monkeypatch.setattr(pt, "_mm", both_rounded)
+    want = _call_at_tiny_geometry(name, params)
+
+    got, want, unrounded = (
+        jax.tree_util.tree_leaves(t) for t in (got, want, unrounded)
+    )
+    assert [a.dtype for a in got] == [jnp.float32] * len(got)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6)
+    # logits first: the rounding shows at ten times that bound and more
+    # (a hundredth of a logit of this tiny model), so an unrounded
+    # product would not pass it
+    assert float(jnp.abs(got[0] - unrounded[0]).max()) > 2e-5
+
+
+def test_bf16_train_step_matmuls_stay_the_line_they_were():
+    """The train cell casts parameters and activations alike to bf16:
+    no matmul of its forward takes the bf16-weight-under-f32 branch (no
+    ``dot_general`` asks for a float32 result of bf16 operands)."""
+    params = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16), init_params(jax.random.key(0), **CFG)
+    )
+    tokens = jnp.zeros((2, 8), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda p: forward(p, tokens, num_heads=HEADS)
+    )(params)
+
+    def dots(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from dots(sub)
+
+    found = list(dots(jaxpr.jaxpr))
+    assert found
+    for eqn in found:
+        operands = {str(v.aval.dtype) for v in eqn.invars}
+        if operands == {"bfloat16"}:
+            assert str(eqn.outvars[0].aval.dtype) == "bfloat16"

@@ -35,11 +35,13 @@ from distributeddeeplearning_tpu.models.pipelined_transformer import (
 )
 from distributeddeeplearning_tpu.quant import (
     QTensor,
+    bf16_matmul_params,
     calibrate_params,
     dequantize,
     dequantize_kv,
     params_dtype,
     qdot,
+    qmatmul,
     quantize,
     quantize_kv,
     quantize_params,
@@ -115,6 +117,78 @@ def test_qdot_lowers_to_int8_dot_general():
     assert str(dot.outvars[0].aval.dtype) == "int32"
 
 
+def _exact_operands():
+    """``x`` [5, 64] float32 with more mantissa than bf16 keeps, ``w``
+    [64, 48] of small integers: every product of the ROUNDED operands is
+    an integer and every partial sum stays under 2**24, so a float32
+    accumulation gives one answer in any order, and it is neither the
+    unrounded product nor one a bf16 result could hold."""
+    rng = np.random.default_rng(11)
+    x = jnp.asarray(rng.integers(-1000, 1001, (5, 64)), jnp.float32)
+    w = jnp.asarray(rng.integers(-8, 9, (64, 48)), jnp.float32)
+    return x, w
+
+
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
+def test_qmatmul_bf16_weight_is_the_product_of_rounded_operands(jitted):
+    """f32 ``x`` on a bf16 ``w``: to every bit the product of ``x`` and
+    ``w`` both rounded to bf16, accumulated and returned in float32."""
+    x, w = _exact_operands()
+    mm = jax.jit(qmatmul) if jitted else qmatmul
+    got = mm(x, w.astype(jnp.bfloat16))
+    want = np.asarray(x.astype(jnp.bfloat16), np.float64) @ np.asarray(
+        w, np.float64
+    )
+    assert got.dtype == jnp.float32 and got.shape == (5, 48)
+    assert (np.asarray(got, np.float64) == want).all()
+    # the test can tell: x really lost mantissa, and the sums need more
+    # of it than a bf16 result has
+    assert (np.asarray(x @ w) != np.asarray(got)).any()
+    assert (np.asarray(got.astype(jnp.bfloat16), np.float64) != want).any()
+
+
+def test_qmatmul_bf16_weight_lowers_to_one_bf16_dot():
+    """One dot_general, bf16 x bf16 -> f32, whose weight operand is the
+    argument itself: nothing rounds (or widens) ``w`` inside the call."""
+    x, w = _exact_operands()
+    jaxpr = jax.make_jaxpr(qmatmul)(x, w.astype(jnp.bfloat16)).jaxpr
+    assert [e.primitive.name for e in jaxpr.eqns] == [
+        "convert_element_type", "dot_general",
+    ]
+    convert, dot = jaxpr.eqns
+    assert convert.invars[0] is jaxpr.invars[0]  # x, and only x
+    assert dot.invars[1] is jaxpr.invars[1]
+    assert [str(v.aval.dtype) for v in dot.invars] == ["bfloat16"] * 2
+    assert str(dot.outvars[0].aval.dtype) == "float32"
+    # a stacked activation contracts its last axis with w's first
+    out = qmatmul(jnp.stack([x, x])[:, None], w.astype(jnp.bfloat16))
+    assert out.shape == (2, 1, 5, 48)
+
+
+@pytest.mark.parametrize("case", ["f32_f32", "bf16_bf16", "bf16_f32", "int8"])
+def test_qmatmul_other_operands_run_the_line_they_ran(case):
+    """The three cases that were there are the expressions they were,
+    bit for bit: ``x @ w`` (f32 both; bf16 both, the bf16 train step;
+    also bf16 ``x`` on f32 ``w``) and ``qdot`` for a QTensor."""
+    x, w = _exact_operands()
+    x = x / 1000 + 0.013
+    w = w * 0.02
+    if case == "int8":
+        qt = quantize(w)
+        got, want = qmatmul(x, qt), qdot(x, qt)
+    else:
+        xd, wd = (
+            jnp.bfloat16 if d == "bf16" else jnp.float32
+            for d in case.split("_")
+        )
+        x, w = x.astype(xd), w.astype(wd)
+        got, want = qmatmul(x, w), x @ w
+        jaxpr = jax.make_jaxpr(qmatmul)(x, w).jaxpr
+        assert str(jaxpr) == str(jax.make_jaxpr(jnp.matmul)(x, w).jaxpr)
+    assert got.dtype == want.dtype
+    assert (np.asarray(got) == np.asarray(want)).all()
+
+
 def test_qtensor_is_a_pytree_and_scan_slices_it():
     """A stacked [L, K, N] QTensor scanned by lax.scan yields per-layer
     [K, N] QTensors whose negative-axis metadata is still valid."""
@@ -158,6 +232,79 @@ def test_quantize_params_leaves_and_passthrough(params):
     assert params_dtype(qp) == "int8"
     with pytest.raises(ValueError, match="already quantized"):
         quantize_params(qp)
+
+
+def test_bf16_matmul_params_rounds_the_f32_matmul_leaves(params):
+    """The leaves ``quantize_params`` quantizes become ``astype(bf16)``
+    of themselves; every other leaf is the very array it was."""
+    held = bf16_matmul_params(params)
+    changed = [("blocks", n) for n in ("qkv", "proj", "w_in", "w_out")]
+    changed.append(("head",))
+    for path in changed:
+        old = params[path[0]] if len(path) == 1 else params["blocks"][path[1]]
+        new = held[path[0]] if len(path) == 1 else held["blocks"][path[1]]
+        assert new.dtype == jnp.bfloat16 and new.shape == old.shape
+        assert (np.asarray(new) == np.asarray(old.astype(jnp.bfloat16))).all()
+    for name in ("embed", "pos"):
+        assert held[name] is params[name]
+    for name in ("ln1", "ln2"):
+        assert held["blocks"][name] is params["blocks"][name]
+    assert set(held) == set(params)
+    assert set(held["blocks"]) == set(params["blocks"])
+    # the caller's tree is not written to
+    assert params["head"].dtype == jnp.float32
+    assert params["blocks"]["qkv"].dtype == jnp.float32
+    # provenance: the first leaf is a LayerNorm scale, so the dtype the
+    # caller's weights are stored in still reads float32
+    assert params_dtype(held) == "float32"
+
+
+def _other_model_tree():
+    # the shape of models/hybrid_moe_transformer's tree: no ``blocks``
+    bf = jnp.bfloat16
+    return {"embed": jnp.ones((7, 4), bf), "final_norm": jnp.ones((4,), bf),
+            "layers": [{"wq": jnp.ones((4, 4), bf)}],
+            "head": jnp.ones((4, 7), bf)}
+
+
+@pytest.mark.parametrize("tree", ["int8", "bf16", "copied", "other_model"])
+def test_bf16_matmul_params_passes_everything_else_through(tree, params):
+    """QTensor leaves, bf16 leaves, its own output (idempotent) and the
+    tree of a model with no ``blocks`` come back as the same object."""
+    given = {
+        "int8": lambda: quantize_params(params),
+        "bf16": lambda: jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.bfloat16), params),
+        "copied": lambda: bf16_matmul_params(params),
+        "other_model": _other_model_tree,
+    }[tree]()
+    assert bf16_matmul_params(given) is given
+
+
+def test_bf16_matmul_params_mixed_tree_rounds_only_what_is_f32(params):
+    """Told apart a leaf, not a tree: an int8 head beside f32 stacks."""
+    mixed = {**params, "head": quantize_params(params)["head"]}
+    held = bf16_matmul_params(mixed)
+    assert held["head"] is mixed["head"]
+    assert held["blocks"]["w_in"].dtype == jnp.bfloat16
+    assert held["blocks"]["ln2"] is params["blocks"]["ln2"]
+
+
+def test_bf16_matmul_params_eval_shape_skeleton(params):
+    """``jax.eval_shape`` over the transform gives the avals of what it
+    makes (how a program is sized for the engine's tree with no weights
+    in hand), and one traced call covers every leaf it rounds."""
+    skeleton = jax.eval_shape(bf16_matmul_params, params)
+    held = bf16_matmul_params(params)
+    assert jax.tree_util.tree_structure(skeleton) == (
+        jax.tree_util.tree_structure(held)
+    )
+    for a, b in zip(jax.tree_util.tree_leaves(skeleton),
+                    jax.tree_util.tree_leaves(held)):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype)
+    eqns = jax.make_jaxpr(bf16_matmul_params)(params).jaxpr.eqns
+    assert [e.primitive.name for e in eqns] == ["jit"]
+    assert len(eqns[0].outvars) == 5
 
 
 def test_quantized_forward_tracks_f32(params):

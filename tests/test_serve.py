@@ -27,9 +27,11 @@ from distributeddeeplearning_tpu.models.pipelined_transformer import (
     init_params,
 )
 from distributeddeeplearning_tpu.parallel import MeshSpec, create_mesh
+from distributeddeeplearning_tpu.quant import bf16_matmul_params
 from distributeddeeplearning_tpu.serve import (
     ContinuousBatchingScheduler,
     InferenceEngine,
+    PagedInferenceEngine,
     Request,
     cache_bytes,
     init_cache,
@@ -144,6 +146,117 @@ def test_engine_greedy_matches_oracle(params):
         toks[0] = out[0]
         pos[0] += 1
     assert got == _naive_greedy(params, prompt, 5)
+
+
+# --------------------------------------------------------------------------
+# the weights an engine holds: the caller's own, or (where a float32
+# product is one bf16 pass: a TPU) their matmul leaves rounded to bf16 once
+# --------------------------------------------------------------------------
+
+ENGINES = {
+    "paged-pallas": lambda p: _paged(p, decode_kernel="pallas"),
+    "paged-gather": lambda p: _paged(p, decode_kernel="gather"),
+    "dense": lambda p: InferenceEngine(
+        p, num_heads=HEADS, batch_slots=2, max_seq=24),
+}
+
+# one full page of 4 shared by all three prompts: the prefix cache hits
+SHARED = [7, 7, 9, 2]
+PROMPTS = [SHARED + [5, 17, 3], SHARED + [42], SHARED + [8, 8, 30, 1, 6]]
+
+
+def _paged(params, **kw):
+    return PagedInferenceEngine(
+        params, num_heads=HEADS, batch_slots=2, max_seq=24, page_size=4,
+        prefill_chunk=4, prefix_cache=True, **kw)
+
+
+def _serve(engine, n=5):
+    results, report = ContinuousBatchingScheduler(
+        engine, max_new_tokens=n
+    ).run([Request(uid=str(i), prompt=p) for i, p in enumerate(PROMPTS)])
+    return {int(r.uid): list(r.tokens) for r in results}, report
+
+
+@pytest.fixture
+def one_bf16_pass(monkeypatch):
+    """The engine's predicate as a TPU answers it."""
+    from distributeddeeplearning_tpu.serve import engine as engine_mod
+
+    monkeypatch.setattr(
+        engine_mod, "_f32_product_is_one_bf16_pass", lambda: True)
+
+
+def test_bf16_pass_predicate_reads_backend_and_precision(monkeypatch):
+    from distributeddeeplearning_tpu.serve import engine as engine_mod
+
+    assert engine_mod._f32_product_is_one_bf16_pass() is False  # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert engine_mod._f32_product_is_one_bf16_pass() is True
+    with jax.default_matmul_precision("highest"):
+        assert engine_mod._f32_product_is_one_bf16_pass() is False
+
+
+@pytest.mark.parametrize("build", ENGINES)
+def test_engine_holds_the_callers_arrays_off_tpu(build, params):
+    """On the CPU a float32 product is a float32 product: the engine's
+    parameters are the very tree it was handed, and it says so."""
+    engine = ENGINES[build](params)
+    assert engine.params is params
+    assert engine.weights_dtype == engine.matmul_dtype == "float32"
+    assert engine.weights_bytes == cache_bytes(params)
+
+
+@pytest.mark.parametrize("build", ENGINES)
+def test_engine_serves_the_bf16_operand_copy(build, params, one_bf16_pass):
+    """Built from float32 parameters where a float32 product is one bf16
+    pass, an engine holds ``bf16_matmul_params`` of them and serves, token
+    for token, what ``forward`` gives on that tree (prefix cache on); the
+    report says what was handed in and what the matmuls read."""
+    held = bf16_matmul_params(params)
+    engine = ENGINES[build](params)
+    for a, b in zip(jax.tree_util.tree_leaves(engine.params),
+                    jax.tree_util.tree_leaves(held)):
+        assert a.dtype == b.dtype and (np.asarray(a) == np.asarray(b)).all()
+    assert engine.params["embed"] is params["embed"]
+    assert params["head"].dtype == jnp.float32  # the caller's tree stands
+
+    tokens, report = _serve(engine)
+    for i, prompt in enumerate(PROMPTS):
+        assert tokens[i] == _naive_greedy(held, prompt, 5)
+    if build != "dense":
+        assert report.prefix_hit_rate > 0
+    assert report.weights_dtype == "float32"
+    assert report.matmul_dtype == "bfloat16"
+    assert report.weights_bytes == cache_bytes(held) < cache_bytes(params)
+
+
+@pytest.mark.parametrize("build", ENGINES)
+def test_reload_of_a_float32_tree_keeps_the_bf16_copy(
+    build, params, one_bf16_pass
+):
+    """The caller reloads the float32 tree it always had: the engine
+    rounds it again, serves what a fresh engine on the new weights serves,
+    and refuses a tree of another shape or storage as before."""
+    new = init_params(jax.random.key(5), **CFG)
+    fresh, _ = _serve(ENGINES[build](new))
+    engine = ENGINES[build](params)
+    before, _ = _serve(engine)
+    engine.reload_params(new)
+    assert engine.params["head"].dtype == jnp.bfloat16
+    assert (np.asarray(engine.params["blocks"]["w_in"]) == np.asarray(
+        new["blocks"]["w_in"].astype(jnp.bfloat16))).all()
+    after, report = _serve(engine)
+    assert after == fresh != before
+    assert report.matmul_dtype == "bfloat16"
+
+    wider = init_params(jax.random.key(5), **{**CFG, "d_ff": 128})
+    with pytest.raises(ValueError, match="reload_params"):
+        engine.reload_params(wider)
+    all_bf16 = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16), new)
+    with pytest.raises(ValueError, match="reload_params"):
+        engine.reload_params(all_bf16)
 
 
 def test_engine_validates_inputs(params):
