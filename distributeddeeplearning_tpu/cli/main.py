@@ -372,11 +372,13 @@ def build_parser() -> argparse.ArgumentParser:
                          "restores the latest step's params")
     serve_p.add_argument("--model-config", default=None, metavar="JSON",
                          help="serve a decoder that mixes window and full "
-                         "attention layers with sparse experts "
+                         "attention layers, or gated short-convolution and "
+                         "attention layers, with sparse experts "
                          "(models/hybrid_moe_transformer.py), at the sizes "
                          "of this configuration file under the model's "
                          "published keys (benchmarks/configs/"
-                         "mimo-v2-flash.json is one), seeded weights; needs "
+                         "mimo-v2-flash.json and lfm2-8b-a1b.json are "
+                         "two), seeded weights; needs "
                          "--kv-layout paged --no-prefix-cache, and refuses "
                          "the int8 pool, the host tier, --speculative and "
                          "--replicas > 1")

@@ -1,4 +1,4 @@
-"""A decoder whose layers are of two attention kinds, with sparse experts.
+"""A decoder whose layers are of several kinds, with sparse experts.
 
 The pure-function model behind the paged serving engine for architectures
 that mix **window** and **full** attention layers in one stack (each kind
@@ -6,15 +6,20 @@ with its own KV head count, rotary base and optional learned sink), use
 grouped-query attention whose keys and values differ in width, rotate only
 a leading part of every head, normalise with RMS norm, and run a gated FFN
 that in most layers is a **mixture of experts** of which this chip holds a
-stated subset.
+stated subset.  A third kind of layer has no attention at all: a **gated
+short convolution** (:func:`short_conv`), whose only memory of a sequence
+is the last ``conv_taps - 1`` inputs of its depthwise convolution.
 
 Everything about the architecture is in one hashable :class:`HybridSpec`;
 parameters are a plain pytree with one dict per layer (no stacking: every
 weight is a buffer of its own, so no step slices a stack of them).  There
 is **one block function**, :func:`block`, which takes the layer's index and
-an *attention callback*; the two forwards the paged engine needs,
-:func:`forward_prefill_chunk` and :func:`forward_decode`, differ only in
-the callbacks they hand it (how a layer's cache is written and read).
+the layer's *callback*: an attention layer's says how its cache is written
+and read (``attention=``), a convolution layer's hands it the inputs just
+before the rows it was given and keeps the newest for the next call
+(``state=``).  The two forwards the paged engine needs,
+:func:`forward_prefill_chunk` and :func:`forward_decode`, and the
+cache-free :func:`forward` differ only in the callbacks they hand it.
 
 Numerics: weights and cache in the parameters' dtype (bfloat16 in
 serving), every matmul accumulating in float32, the residual stream, the
@@ -26,6 +31,10 @@ kv_heads * width]`` (heads folded into the minor axis, so a bfloat16 page
 tiles without padding) addressed through block tables; window layers own a
 ring ``[slots, window, kv_heads * width]`` written at ``pos mod window`` and
 masked by absolute position, so their bytes do not grow with the sequence.
+A convolution layer owns ``[slots, (conv_taps - 1) * d]``: each slot's last
+inputs, oldest first, folded into the minor axis like the KV heads.  That
+state has no positions to mask by, so a chunk at offset 0 starts from zeros
+whatever the slot holds.
 """
 
 from __future__ import annotations
@@ -40,13 +49,15 @@ import numpy as np
 from distributeddeeplearning_tpu.ops import flash_decode as _fd
 
 PyTree = Any
-FULL, WINDOW = 0, 1
+FULL, WINDOW, CONV = 0, 1, 2
 DENSE, EXPERTS = 0, 1
 #: what the expert layers count in a decode step (the order of the step's
 #: small integer vector): pairs (token, expert) the router made over live
 #: lanes, pairs that landed on held experts, the fullest held expert's
 #: tokens and the held experts touched, each summed over the expert layers
 EXPERT_COUNTS = ("pairs_total", "pairs_here", "tokens_max", "experts_touched")
+#: a layer's norm scales (made 1, where every other weight is drawn)
+NORM_SCALES = ("ln1", "ln2", "q_norm", "k_norm")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,7 +79,7 @@ class HybridSpec:
     sink_window: bool
     value_scale: float
     eps: float
-    attn_kinds: Tuple[int, ...]     # per layer: FULL or WINDOW
+    attn_kinds: Tuple[int, ...]     # per layer: FULL, WINDOW or CONV
     ffn_kinds: Tuple[int, ...]      # per layer: DENSE or EXPERTS
     d_ff: int                       # dense FFN width
     d_expert: int                   # one expert's width
@@ -77,10 +88,22 @@ class HybridSpec:
     experts_held: Tuple[int, ...]   # ids of the experts this chip holds
     norm_topk: bool = True
     routed_scale: float = 1.0
+    #: added to the chosen scores' sum before it divides them
+    topk_eps: float = 0.0
+    #: taps of a CONV layer's causal depthwise convolution
+    conv_taps: int = 0
+    #: a learned RMS norm over every query and key head, before the rotation
+    qk_norm: bool = False
+    #: the head is the embedding, read transposed (no ``head`` leaf)
+    tied_head: bool = False
 
     def __post_init__(self):
         if len(self.attn_kinds) != len(self.ffn_kinds):
             raise ValueError("attn_kinds and ffn_kinds differ in length")
+        if WINDOW in self.attn_kinds and self.window < 1:
+            raise ValueError("window layers need a window of 1 or more")
+        if CONV in self.attn_kinds and self.conv_taps < 2:
+            raise ValueError("convolution layers need conv_taps of 2 or more")
         for name, kv in (("full", self.kv_heads_full),
                          ("window", self.kv_heads_window)):
             if self.num_q_heads % kv:
@@ -125,7 +148,10 @@ def spec_from_config(cfg: dict) -> HybridSpec:
     ``layers_kept`` the layers run are those of the published patterns.
     ``n_routed_experts`` counts the experts held here; ``experts_held``
     their ids and ``n_routed_experts_published`` the router's width (both
-    default to "all of them")."""
+    default to "all of them").  A configuration under the ``lfm2_moe`` keys
+    (``layer_types``) is read by :func:`_spec_from_lfm2_moe`."""
+    if "layer_types" in cfg:
+        return _spec_from_lfm2_moe(cfg)
     held = cfg.get("experts_held")
     n_router = cfg.get("n_routed_experts_published", cfg["n_routed_experts"])
     if held is None:
@@ -162,16 +188,78 @@ def spec_from_config(cfg: dict) -> HybridSpec:
     )
 
 
+_LFM2_KINDS = {"conv": CONV, "full_attention": FULL}
+
+
+def _spec_from_lfm2_moe(cfg: dict) -> HybridSpec:
+    """The ``lfm2_moe`` keys: ``layer_types`` names every published layer
+    ``conv`` (a gated short convolution of ``conv_L_cache`` taps) or
+    ``full_attention`` (grouped-query, a learned RMS norm over every query
+    and key head, rotary over the whole head); the first
+    ``num_dense_layers`` published layers have a dense FFN and the others
+    ``num_experts`` experts; the head is the embedding.  ``layers_kept``
+    says which of the published layers are run (default: the first
+    ``num_hidden_layers``); ``experts_held`` the experts held here
+    (default: all of them)."""
+    if cfg.get("conv_bias") or not cfg.get("use_expert_bias", True):
+        raise ValueError("lfm2_moe: conv_bias true and use_expert_bias "
+                         "false are not written")
+    kept = cfg.get("layers_kept", range(cfg["num_hidden_layers"]))
+    if len(kept) != cfg["num_hidden_layers"]:
+        raise ValueError("layers_kept and num_hidden_layers disagree")
+    heads = cfg["num_attention_heads"]
+    head_dim = cfg.get("head_dim") or cfg["hidden_size"] // heads
+    return HybridSpec(
+        vocab_size=cfg["vocab_size"],
+        d_model=cfg["hidden_size"],
+        num_q_heads=heads,
+        k_dim=head_dim,
+        v_dim=head_dim,
+        rotary_dim=head_dim,
+        kv_heads_full=cfg["num_key_value_heads"],
+        kv_heads_window=cfg["num_key_value_heads"],
+        window=0,
+        theta_full=float(cfg["rope_theta"]),
+        theta_window=float(cfg["rope_theta"]),
+        sink_full=False,
+        sink_window=False,
+        value_scale=1.0,
+        eps=float(cfg["norm_eps"]),
+        attn_kinds=tuple(_LFM2_KINDS[cfg["layer_types"][i]] for i in kept),
+        ffn_kinds=tuple(DENSE if i < cfg["num_dense_layers"] else EXPERTS
+                        for i in kept),
+        d_ff=cfg["intermediate_size"],
+        d_expert=cfg["moe_intermediate_size"],
+        num_experts=cfg["num_experts"],
+        experts_per_token=cfg["num_experts_per_tok"],
+        experts_held=tuple(cfg.get("experts_held",
+                                   range(cfg["num_experts"]))),
+        norm_topk=bool(cfg["norm_topk_prob"]),
+        routed_scale=float(cfg.get("routed_scaling_factor") or 1.0),
+        topk_eps=1e-6,
+        conv_taps=cfg["conv_L_cache"],
+        qk_norm=True,
+        tied_head=True,
+    )
+
+
 def layer_shapes(spec: HybridSpec, layer: int) -> Dict[str, tuple]:
     """name -> shape of one layer's weights."""
     kind, d = spec.attn_kinds[layer], spec.d_model
-    hq, hkv = spec.num_q_heads, spec.kv_heads(kind)
-    out = {
-        "ln1": (d,), "wq": (d, hq * spec.k_dim), "wk": (d, hkv * spec.k_dim),
-        "wv": (d, hkv * spec.v_dim), "wo": (hq * spec.v_dim, d), "ln2": (d,),
-    }
-    if spec.has_sink(kind):
-        out["sink"] = (hq,)
+    if kind == CONV:
+        out = {"ln1": (d,), "w_in": (d, 3 * d), "conv_w": (spec.conv_taps, d),
+               "w_out": (d, d), "ln2": (d,)}
+    else:
+        hq, hkv = spec.num_q_heads, spec.kv_heads(kind)
+        out = {
+            "ln1": (d,), "wq": (d, hq * spec.k_dim),
+            "wk": (d, hkv * spec.k_dim), "wv": (d, hkv * spec.v_dim),
+            "wo": (hq * spec.v_dim, d), "ln2": (d,),
+        }
+        if spec.qk_norm:
+            out.update(q_norm=(spec.k_dim,), k_norm=(spec.k_dim,))
+        if spec.has_sink(kind):
+            out["sink"] = (hq,)
     if spec.ffn_kinds[layer] == DENSE:
         out.update(wg=(d, spec.d_ff), wu=(d, spec.d_ff), wd=(spec.d_ff, d))
     else:
@@ -197,15 +285,17 @@ def init_params(rng: jax.Array, spec: HybridSpec, *, dtype=jnp.float32,
     for layer in range(spec.num_layers):
         p = {}
         for name, shape in layer_shapes(spec, layer).items():
-            p[name] = (jnp.ones(shape, dtype) if name in ("ln1", "ln2")
+            p[name] = (jnp.ones(shape, dtype) if name in NORM_SCALES
                        else nrm(shape))
         layers.append(p)
-    return {
+    params = {
         "embed": nrm((spec.vocab_size, spec.d_model)),
         "layers": layers,
         "final_norm": jnp.ones((spec.d_model,), dtype),
-        "head": nrm((spec.d_model, spec.vocab_size)),
     }
+    if not spec.tied_head:
+        params["head"] = nrm((spec.d_model, spec.vocab_size))
+    return params
 
 
 # -- the block's parts ---------------------------------------------------------
@@ -251,7 +341,8 @@ def route(p, h32, *, spec: HybridSpec):
     """The router over ALL experts, in float32: ``s = sigmoid(h Wr)``; the
     ``experts_per_token`` experts with the largest ``s + b`` are chosen
     (the correction bias selects and does not weigh); the weights are
-    ``s[chosen]`` over their sum.  Returns (ids [T, k], weights [T, k])."""
+    ``s[chosen]`` over their sum (plus ``topk_eps``).  Returns (ids [T, k],
+    weights [T, k])."""
     s = jax.nn.sigmoid(jnp.dot(
         h32, p["router"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
@@ -259,7 +350,8 @@ def route(p, h32, *, spec: HybridSpec):
         s + p["router_bias"].astype(jnp.float32), spec.experts_per_token)
     w = jnp.take_along_axis(s, chosen, axis=-1)
     if spec.norm_topk:
-        w = w / w.sum(-1, keepdims=True)
+        total = w.sum(-1, keepdims=True)
+        w = w / (total + spec.topk_eps if spec.topk_eps else total)
     return chosen, w * spec.routed_scale
 
 
@@ -327,26 +419,64 @@ def expert_layer(p, h32, *, spec: HybridSpec, live=None):
     return y, counts
 
 
-def block(p, x, positions, *, spec: HybridSpec, layer: int, attention,
-          live=None):
-    """One layer on ``x`` [T, d] (float32 residual) at ``positions`` [T].
-
-    ``attention(q [T, Hq, dk], k [T, Hkv, dk], v [T, Hkv, dv], sink)`` ->
-    ``ctx [T, Hq, dv]`` is the caller's: it writes the layer's cache and
-    reads what the queries may see.  Returns ``(x, counts)`` with
-    ``counts`` the expert layer's (None in a dense layer)."""
-    kind = spec.attn_kinds[layer]
-    T = x.shape[0]
+def attention_op(p, h, positions, *, spec: HybridSpec, kind: int, attention):
+    """An attention layer's operator on the normed rows ``h`` [T, d]:
+    projections, the per-head norm where the spec has one, the rotation,
+    the caller's ``attention`` (see :func:`block`), the output projection."""
+    T = h.shape[0]
     hq, hkv = spec.num_q_heads, spec.kv_heads(kind)
     cdt = p["wq"].dtype
-    h = rms_norm(x, p["ln1"], spec.eps)
-    rot = dict(rotary_dim=spec.rotary_dim, theta=spec.theta(kind))
-    q = rotary(_mm(h, p["wq"]).reshape(T, hq, spec.k_dim), positions, **rot)
-    k = rotary(_mm(h, p["wk"]).reshape(T, hkv, spec.k_dim), positions, **rot)
+
+    def rotated(w, heads, norm):
+        y = _mm(h, p[w]).reshape(T, heads, spec.k_dim)
+        if spec.qk_norm:
+            y = rms_norm(y, p[norm], spec.eps)
+        return rotary(y, positions, rotary_dim=spec.rotary_dim,
+                      theta=spec.theta(kind))
+
+    q, k = rotated("wq", hq, "q_norm"), rotated("wk", hkv, "k_norm")
     v = spec.value_scale * _mm(h, p["wv"]).reshape(T, hkv, spec.v_dim)
     ctx = attention(q.astype(cdt), k.astype(cdt), v.astype(cdt),
                     p["sink"] if spec.has_sink(kind) else None)
-    x = x + _mm(ctx.reshape(T, hq * spec.v_dim), p["wo"])
+    return _mm(ctx.reshape(T, hq * spec.v_dim), p["wo"])
+
+
+def short_conv(p, h, *, spec: HybridSpec, state):
+    """A convolution layer's operator on the normed rows ``h`` [T, d]:
+    ``[B | C | X] = h W_in``; ``u = B * X``; ``c_t = sum_j w_j u_{t-taps+1+j}``
+    (depthwise, causal, inputs before the sequence's start are 0); ``(C *
+    c) W_out``.  No nonlinearity but the two gates.
+
+    ``state(u [T, d]) -> (u_{t-taps+1}, ..., u_{t-1}, u_t)``, each [T, d],
+    is the caller's: row ``t``'s earlier inputs come from wherever the
+    caller keeps them (the rows above it, a slot's state), and the newest
+    are left there for the next call.  ``u`` is rounded to the weights'
+    dtype before it is summed or stored, so a sequence reads the same
+    inputs whether they come from this call or an earlier one."""
+    d = spec.d_model
+    bcx = _mm(h, p["w_in"])
+    u = (bcx[:, :d] * bcx[:, 2 * d:]).astype(p["w_in"].dtype)
+    w = p["conv_w"].astype(jnp.float32)
+    c = sum(w[j] * tap.astype(jnp.float32) for j, tap in enumerate(state(u)))
+    return _mm(bcx[:, d:2 * d] * c, p["w_out"])
+
+
+def block(p, x, positions, *, spec: HybridSpec, layer: int, attention=None,
+          state=None, live=None):
+    """One layer on ``x`` [T, d] (float32 residual) at ``positions`` [T].
+
+    An attention layer takes ``attention(q [T, Hq, dk], k [T, Hkv, dk], v
+    [T, Hkv, dv], sink)`` -> ``ctx [T, Hq, dv]``, the caller's: it writes
+    the layer's cache and reads what the queries may see.  A convolution
+    layer takes ``state`` (:func:`short_conv`).  Returns ``(x, counts)``
+    with ``counts`` the expert layer's (None in a dense layer)."""
+    kind = spec.attn_kinds[layer]
+    h = rms_norm(x, p["ln1"], spec.eps)
+    if kind == CONV:
+        x = x + short_conv(p, h, spec=spec, state=state)
+    else:
+        x = x + attention_op(p, h, positions, spec=spec, kind=kind,
+                             attention=attention)
     h = rms_norm(x, p["ln2"], spec.eps)
     if spec.ffn_kinds[layer] == DENSE:
         return x + gated_ffn(p, h), None
@@ -355,16 +485,25 @@ def block(p, x, positions, *, spec: HybridSpec, layer: int, attention,
 
 
 def _logits(params, x, spec):
-    return _mm(rms_norm(x, params["final_norm"], spec.eps), params["head"])
+    h = rms_norm(x, params["final_norm"], spec.eps)
+    if not spec.tied_head:
+        return _mm(h, params["head"])
+    # the embedding's own buffer, contracted over its minor axis
+    embed = params["embed"]
+    return jax.lax.dot_general(
+        h.astype(embed.dtype), embed, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
-def _stack(spec, params, x, positions, attention_of, live=None):
-    """The layers in their published order; ``attention_of(layer)`` gives
-    each its callback.  Returns (x, the expert layers' counts summed)."""
+def _stack(spec, params, x, positions, callback_of, live=None):
+    """The layers in their published order; ``callback_of(layer)`` gives
+    each its callback (an attention layer's ``attention``, a convolution
+    layer's ``state``).  Returns (x, the expert layers' counts summed)."""
     total = jnp.zeros(len(EXPERT_COUNTS), jnp.int32)
     for layer, p in enumerate(params["layers"]):
-        x, counts = block(p, x, positions, spec=spec, layer=layer,
-                          attention=attention_of(layer), live=live)
+        name = "state" if spec.attn_kinds[layer] == CONV else "attention"
+        x, counts = block(p, x, positions, spec=spec, layer=layer, live=live,
+                          **{name: callback_of(layer)})
         if counts is not None:
             total = total + counts
     return x, total
@@ -375,19 +514,26 @@ def _stack(spec, params, x, positions, attention_of, live=None):
 
 def forward(params, tokens, *, spec: HybridSpec):
     """Next-token logits [s, vocab] of one sequence ``tokens`` [s], with
-    no cache: every layer attends over the sequence itself."""
+    no cache: every attention layer attends over the sequence itself, and a
+    convolution layer's earlier inputs are the rows above (zeros before
+    the sequence's start)."""
     s = tokens.shape[0]
     pos = jnp.arange(s)
     causal = pos[None, :] <= pos[:, None]
-    in_window = pos[None, :] > pos[:, None] - spec.window
+    if WINDOW in spec.attn_kinds:
+        in_window = pos[None, :] > pos[:, None] - spec.window
 
-    def attention_of(layer):
+    def callback_of(layer):
         kind = spec.attn_kinds[layer]
+        if kind == CONV:
+            return lambda u: tuple(
+                jnp.pad(u, ((back, 0), (0, 0)))[:s]
+                for back in range(spec.conv_taps - 1, -1, -1))
         visible = causal & in_window if kind == WINDOW else causal
         return lambda q, k, v, sink: attend(q, k, v, visible, sink)
 
     x = params["embed"][tokens].astype(jnp.float32)
-    x, _ = _stack(spec, params, x, pos, attention_of)
+    x, _ = _stack(spec, params, x, pos, callback_of)
     return _logits(params, x, spec)
 
 
@@ -407,8 +553,9 @@ def forward_decode(params, token, cache, pos, block_tables, live, *,
                    spec: HybridSpec, page_size: int, kernel: str = "gather"):
     """One token for every slot: ``token``/``pos`` [B], ``block_tables``
     [B, nb] (full layers' pages), ``live`` [B] bool (a lane that is not
-    live writes to the scratch page and leaves its ring as it was, and its
-    token is routed to no expert).  Returns ``(logits [B, vocab] float32,
+    live writes to the scratch page and leaves its ring and its
+    convolution state as they were, and its token is routed to no expert).
+    Returns ``(logits [B, vocab] float32,
     cache, counts)`` with ``counts`` the :data:`EXPERT_COUNTS` over the
     step's expert layers (int32 [4])."""
     B = token.shape[0]
@@ -416,11 +563,26 @@ def forward_decode(params, token, cache, pos, block_tables, live, *,
     rows = jnp.arange(B)
     page = block_tables[rows, pos // page_size]
     off = pos % page_size
-    slot_pos = ring_positions(pos, W)  # [B, W]
+    if WINDOW in spec.attn_kinds:
+        slot_pos = ring_positions(pos, W)  # [B, W]
     cache = {name: list(leaves) for name, leaves in cache.items()}
 
-    def attention_of(layer):
+    def callback_of(layer):
         kind, i = spec.attn_kinds[layer], spec.index_in_kind(layer)
+
+        def conv(u):
+            # each lane's earlier inputs are its slot's state, which then
+            # moves on by one (a lane that is not live keeps its own)
+            held = cache["conv_state"][i]
+            d = u.shape[1]
+            u = u.astype(held.dtype)
+            moved = jnp.concatenate([held[:, d:], u], axis=1)
+            cache["conv_state"][i] = jnp.where(live[:, None], moved, held)
+            return tuple(held[:, j * d:(j + 1) * d]
+                         for j in range(spec.conv_taps - 1)) + (u,)
+
+        if kind == CONV:
+            return conv
 
         def full(q, k, v, sink):
             k_pool = cache["k_full"][i].at[page, off].set(k.reshape(B, -1))
@@ -447,7 +609,7 @@ def forward_decode(params, token, cache, pos, block_tables, live, *,
         return window if kind == WINDOW else full
 
     x = params["embed"][token].astype(jnp.float32)
-    x, counts = _stack(spec, params, x, pos, attention_of, live=live)
+    x, counts = _stack(spec, params, x, pos, callback_of, live=live)
     cache = {name: tuple(leaves) for name, leaves in cache.items()}
     return _logits(params, x, spec), cache, counts
 
@@ -463,9 +625,12 @@ def forward_prefill_chunk(params, tokens, cache, block_table, offset, slot,
     [nb]) and attends over the pages a few at a time, up to the chunk's
     end, so its cost follows the live context.  A window layer
     attends to the ring's positions and to the chunk itself, then leaves the
-    chunk's last ``window`` real positions in the ring.  Returns ``(logits
-    [1, 1, vocab] of the last real position, cache)``: the one row a serving
-    engine samples from."""
+    chunk's last ``window`` real positions in the ring.  A convolution
+    layer starts from the slot's state (from zeros where ``offset`` is 0:
+    a sequence's first chunk, whatever the slot's last occupant left) and
+    leaves the inputs of the chunk's last ``conv_taps - 1`` real positions
+    there.  Returns ``(logits [1, 1, vocab] of the last real position,
+    cache)``: the one row a serving engine samples from."""
     b, C = tokens.shape
     if b != 1:
         raise ValueError(f"chunked prefill is per-sequence, got batch {b}")
@@ -476,19 +641,36 @@ def forward_prefill_chunk(params, tokens, cache, block_table, offset, slot,
     pages = jnp.where(page_idx < nb,
                       block_table[jnp.minimum(page_idx, nb - 1)], 0)
     offs = posns % page_size
-    held_pos = ring_positions(offset - 1, W)  # [W] what the ring holds now
-    # the chunk's last W real positions go into the ring
-    tail = real - W + jnp.arange(W)
-    tail_ok = tail >= 0
-    tail_src = jnp.maximum(tail, 0)
-    tail_dst = jnp.mod(offset + tail, W)
-    in_chunk = (posns[None, :] <= posns[:, None]) & (
-        posns[None, :] > posns[:, None] - W)
-    on_ring = (held_pos[None, :] >= 0) & (held_pos[None, :] > posns[:, None] - W)
+    if WINDOW in spec.attn_kinds:
+        held_pos = ring_positions(offset - 1, W)  # [W] what the ring holds now
+        # the chunk's last W real positions go into the ring
+        tail = real - W + jnp.arange(W)
+        tail_ok = tail >= 0
+        tail_src = jnp.maximum(tail, 0)
+        tail_dst = jnp.mod(offset + tail, W)
+        in_chunk = (posns[None, :] <= posns[:, None]) & (
+            posns[None, :] > posns[:, None] - W)
+        on_ring = (held_pos[None, :] >= 0) & (
+            held_pos[None, :] > posns[:, None] - W)
     cache = {name: list(leaves) for name, leaves in cache.items()}
 
-    def attention_of(layer):
+    def callback_of(layer):
         kind, i = spec.attn_kinds[layer], spec.index_in_kind(layer)
+
+        def conv(u):
+            leaf = cache["conv_state"][i]
+            before = spec.conv_taps - 1
+            u = u.astype(leaf.dtype)
+            held = jnp.where(offset > 0, leaf[slot], 0).reshape(before, -1)
+            rows = jnp.concatenate([held, u], axis=0)  # row r: chunk row r - before
+            # the last real rows (with fewer than `before` of them, what
+            # was held moves up) stay for the next chunk or decode step
+            cache["conv_state"][i] = leaf.at[slot].set(
+                jax.lax.dynamic_slice_in_dim(rows, real, before).reshape(-1))
+            return tuple(rows[j:j + C] for j in range(spec.conv_taps))
+
+        if kind == CONV:
+            return conv
 
         def full(q, k, v, sink):
             k_pool = cache["k_full"][i].at[pages, offs].set(k.reshape(C, -1))
@@ -518,7 +700,7 @@ def forward_prefill_chunk(params, tokens, cache, block_table, offset, slot,
 
     x = params["embed"][tokens[0]].astype(jnp.float32)
     # the rows that pad the chunk reach no expert
-    x, _ = _stack(spec, params, x, posns, attention_of,
+    x, _ = _stack(spec, params, x, posns, callback_of,
                   live=jnp.arange(C) < real)
     x = jax.lax.dynamic_slice_in_dim(x, real - 1, 1, axis=0)
     cache = {name: tuple(leaves) for name, leaves in cache.items()}

@@ -245,8 +245,9 @@ def _matmul_operands(params):
 
 def _matmul_dtype(params) -> str:
     """The dtype of the weights the matmuls read (``head`` stands for all
-    of them: both weight transforms treat the matmul leaves alike)."""
-    head = params["head"]
+    of them: both weight transforms treat the matmul leaves alike; a
+    model whose head is its embedding has no ``head`` leaf)."""
+    head = params["head"] if "head" in params else params["embed"]
     return "int8" if isinstance(head, QTensor) else str(head.dtype)
 
 
@@ -877,11 +878,7 @@ class PagedInferenceEngine:
                 )
             # refused by name, before any device work
             if prefix_cache:
-                model.refuse(
-                    "prefix_cache",
-                    "a shared prefix would need the window layers' last "
-                    "positions at the prefix's end; pass prefix_cache=False",
-                )
+                model.refuse("prefix_cache", "pass prefix_cache=False")
             if cache_dtype is not None and np.dtype(cache_dtype) == np.int8:
                 model.refuse("int8_pool")
             if host_pages:

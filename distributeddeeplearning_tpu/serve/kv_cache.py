@@ -201,9 +201,10 @@ def page_bytes(cache: Cache) -> int:
     """Bytes of ONE page across k+v and all layers — the HBM granule the
     allocator hands out (``cache_bytes == (num_pages+1) * page_bytes``).
     Sums EVERY pool leaf, so the int8 layout's per-page scale bytes are
-    charged to the page they belong to.  In a cache of two kinds
+    charged to the page they belong to.  In a cache of several kinds
     (:func:`init_hybrid_cache`) only the full layers' leaves are paged;
-    the window layers' rings are :func:`slot_state_bytes`."""
+    the window layers' rings and the convolution layers' states are
+    :func:`slot_state_bytes`."""
     return sum(
         leaf.size // leaf.shape[0] * leaf.dtype.itemsize
         for leaf in paged_leaves(cache)
@@ -212,7 +213,8 @@ def page_bytes(cache: Cache) -> int:
 
 def paged_leaves(cache: Cache) -> List[jax.Array]:
     """The leaves laid out ``[pages, ...]``, which the allocator's pages
-    index (every leaf, but for a two-kind cache's rings)."""
+    index (every leaf, but for the per-slot leaves of
+    :data:`RING_LEAVES`)."""
     return jax.tree_util.tree_leaves(
         {k: v for k, v in cache.items() if k not in RING_LEAVES}
     )
@@ -220,8 +222,9 @@ def paged_leaves(cache: Cache) -> List[jax.Array]:
 
 def slot_state_bytes(cache: Cache) -> int:
     """Bytes held per SLOT rather than per page, summed over the slots:
-    the window layers' rings of a two-kind cache (0 for every other
-    layout).  They are committed whole from the start and never grow."""
+    the window layers' rings and the convolution layers' states of a
+    cache of several kinds (0 for every other layout).  They are committed
+    whole from the start and never grow."""
     return cache_bytes({k: v for k, v in cache.items() if k in RING_LEAVES})
 
 
@@ -237,11 +240,14 @@ def slot_state_bytes(cache: Cache) -> int:
 # (16, 128) without padding whatever the head count, where a trailing
 # ``(4, 192)`` would pad to ``(16, 256)``.  K and V differ in width, so each
 # has a leaf of its own per layer (a tuple of per-layer arrays: every layer's
-# buffer is updated in place on its own).
+# buffer is updated in place on its own).  A gated short-convolution layer
+# has no K/V at all: what it carries of a sequence is the last few inputs of
+# its convolution, ``conv_positions`` rows of the model's width a slot,
+# folded into the minor axis like the heads (oldest first).
 # --------------------------------------------------------------------------
 
-#: the per-slot leaves of a two-kind cache
-RING_LEAVES = ("k_win", "v_win")
+#: the per-slot leaves of such a cache (every other leaf is paged)
+RING_LEAVES = ("k_win", "v_win", "conv_state")
 
 
 def init_hybrid_cache(
@@ -256,12 +262,17 @@ def init_hybrid_cache(
     kv_heads_window: int,
     k_dim: int,
     v_dim: int,
+    conv_layers: int = 0,
+    conv_positions: int = 0,
+    d_model: int = 0,
     dtype: Any = jnp.bfloat16,
 ) -> Cache:
     """``{"k_full", "v_full"}``: per full layer ``[pages + 1, page_size,
     kv_heads_full * width]`` (page 0 the scratch page); ``{"k_win",
     "v_win"}``: per window layer ``[batch_slots, window, kv_heads_window *
-    width]``."""
+    width]``; ``{"conv_state"}``: per convolution layer ``[batch_slots,
+    conv_positions * d_model]``.  A kind with no layers has an empty
+    tuple."""
     if num_pages < 1:
         raise ValueError(f"num_pages must be >= 1, got {num_pages}")
     if _is_int8(dtype):
@@ -280,6 +291,10 @@ def init_hybrid_cache(
         "v_full": leaves(full_layers, pool, kv_heads_full, v_dim),
         "k_win": leaves(window_layers, ring, kv_heads_window, k_dim),
         "v_win": leaves(window_layers, ring, kv_heads_window, v_dim),
+        "conv_state": tuple(
+            jnp.zeros((batch_slots, conv_positions * d_model), dtype)
+            for _ in range(conv_layers)
+        ),
     }
 
 

@@ -39,9 +39,10 @@ What it records is the whole point of serving benchmarks:
   why the head of the queue stayed queued (slot, pages, HBM forecast),
   counted where the decision is taken, and how much of the worst-case
   page reservation is ever written,
-- ``expert_*`` / ``*_positions_held_sum``: what an engine whose model has
-  expert layers and a cache of two kinds counted over the run's decode
-  steps (``engine.step_counters``; all 0 for any other model).
+- ``expert_*`` / ``*_positions_held_sum`` / ``*_bytes_held_sum``: what an
+  engine whose model has expert layers and a cache of several kinds
+  counted over the run's decode steps (``engine.step_counters``; all 0
+  for any other model).
 
 Every percentile block routes through the obs histogram
 (:func:`..obs.registry.summarize`), and aggregate counters/histograms
@@ -351,6 +352,10 @@ class ServeReport:
     # ``*_positions_held_sum``: summed a decode step over live slots, the
     # positions ONE layer of the kind holds for the slot: a full layer
     # every position, a window layer at most the window.
+    # ``slot_state_bytes_held_sum`` / ``kv_bytes_held_sum``: summed a
+    # decode step over live slots, the bytes the slot holds beside the
+    # pages (window rings, convolution states: whole, whatever its
+    # length) and in them (its positions' K/V, every paged layer).
     expert_pairs_total: int = 0
     expert_pairs_here: int = 0
     expert_tokens_max_sum: float = 0.0
@@ -358,6 +363,8 @@ class ServeReport:
     experts_touched_sum: int = 0
     window_positions_held_sum: int = 0
     full_positions_held_sum: int = 0
+    slot_state_bytes_held_sum: int = 0
+    kv_bytes_held_sum: int = 0
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)

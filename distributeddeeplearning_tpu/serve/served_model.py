@@ -11,11 +11,13 @@ has to refuse for it.  Two bindings exist:
   engine's programs, shapes and numbers for it are what they were.
 - :func:`hybrid_model` binds ``models.hybrid_moe_transformer`` (window and
   full attention layers with their own KV head counts and cache lifetimes,
-  sparse experts of which a stated subset is held): its cache has per-slot
-  state (the window layers' rings) beside the pages, so its chunk program
-  is also told the slot and how many of the chunk's tokens are real, its
-  decode program which lanes are live, and its decode step returns a small
-  vector of expert counts that rides with the step's one fetch.
+  sparse experts of which a stated subset is held; gated short-convolution
+  layers with no K/V at all): its cache has per-slot state (the window
+  layers' rings, the convolution layers' last inputs) beside the pages, so
+  its chunk program is also told the slot and how many of the chunk's
+  tokens are real, its decode program which lanes are live, and its decode
+  step returns a small vector of expert counts that rides with the step's
+  one fetch.
 """
 
 from __future__ import annotations
@@ -83,14 +85,17 @@ class ServedModel:
     #: counts nothing.  The engine adds what comes back and reads none of it
     count_step: Optional[Callable[..., Dict[str, float]]] = None
     refuses: FrozenSet[str] = frozenset()
+    #: feature -> the model's own reason, said before the caller's
+    reasons: Dict[str, str] = dataclasses.field(default_factory=dict)
 
     def refuse(self, feature: str, why: str = "") -> None:
         """Raise :class:`Refused`, by name, if this model cannot run with
         ``feature`` (a key of :data:`FEATURES`)."""
         if feature in self.refuses:
+            said = "; ".join(x for x in (self.reasons.get(feature), why) if x)
             raise Refused(
                 f"the {self.family!r} model refuses {feature} "
-                f"({FEATURES[feature]})" + (f": {why}" if why else "")
+                f"({FEATURES[feature]})" + (f": {said}" if said else "")
             )
 
 
@@ -170,16 +175,26 @@ def hybrid_model(spec) -> ServedModel:
 
     n_full = len(spec.layers_of(hm.FULL))
     n_window = len(spec.layers_of(hm.WINDOW))
+    n_conv = len(spec.layers_of(hm.CONV))
+
+    # bytes of the cache this description laid out (set by init_cache, read
+    # by count_step): the per-slot state of ONE lane, the K/V of ONE position
+    laid_out = {"lane_state": 0, "position": 0}
 
     def init_cache(*, num_pages, page_size, batch_slots, dtype):
-        return kv_cache.init_hybrid_cache(
+        cache = kv_cache.init_hybrid_cache(
             num_pages=num_pages, page_size=page_size,
             batch_slots=batch_slots, window=spec.window,
             full_layers=n_full, window_layers=n_window,
             kv_heads_full=spec.kv_heads_full,
             kv_heads_window=spec.kv_heads_window,
-            k_dim=spec.k_dim, v_dim=spec.v_dim, dtype=dtype,
+            k_dim=spec.k_dim, v_dim=spec.v_dim,
+            conv_layers=n_conv, conv_positions=max(spec.conv_taps - 1, 0),
+            d_model=spec.d_model, dtype=dtype,
         )
+        laid_out["lane_state"] = kv_cache.slot_state_bytes(cache) // batch_slots
+        laid_out["position"] = kv_cache.page_bytes(cache) // page_size
+        return cache
 
     def prefill_chunk(params, tokens, cache, table, offset, slot, real, *,
                       page_size, kernel, mesh=None):
@@ -195,9 +210,9 @@ def hybrid_model(spec) -> ServedModel:
 
     def scrub(cache, page_ids, from_offs, slot, *, page_size):
         # the listed pages from their offsets on, in every full layer, and
-        # the slot's whole ring in every window layer: what a ring holds
-        # is the sequence's newest positions, which a scrub from any
-        # position reaches
+        # the slot's whole ring in every window layer and whole state in
+        # every convolution layer: what they hold is the sequence's newest
+        # positions, which a scrub from any position reaches
         zero = (jnp.arange(page_size)[None, :] >= from_offs[:, None])[..., None]
         out = {}
         for name, leaves in cache.items():
@@ -224,7 +239,8 @@ def hybrid_model(spec) -> ServedModel:
     def count_step(counts, live_pos):
         # the ``*_sum`` entries are sums over decode steps of the step's
         # mean over its expert layers; a full layer holds every position
-        # of a live slot, a window layer at most the window
+        # of a live slot, a window layer at most the window; a live lane
+        # holds its per-slot state whole and its positions' K/V in pages
         step = dict(zip(hm.EXPERT_COUNTS, (int(x) for x in counts)))
         held = live_pos.astype(np.int64) + 1
         return {
@@ -237,7 +253,22 @@ def hybrid_model(spec) -> ServedModel:
             "window_positions_held_sum":
                 int(np.minimum(held, spec.window).sum()),
             "full_positions_held_sum": int(held.sum()),
+            "slot_state_bytes_held_sum": len(held) * laid_out["lane_state"],
+            "kv_bytes_held_sum": int(held.sum()) * laid_out["position"],
         }
+
+    state = ("the convolution layers' state" if n_conv
+             else "the window layers' last positions")
+    reasons = {
+        "prefix_cache": f"a hit would need {state} at the prefix's end, and "
+                        "a slot keeps them at its newest position only",
+        "int8_pool": "quant/qtensor knows no per-slot leaf",
+        "host_tier": "serve/kv_tier.py spills pages, and a sequence here "
+                     "is its pages and its slot's state",
+        "verify": f"a rejected tail would have to rewind {state}",
+        "tensor_mesh": "no layout rule for folded KV heads, an expert axis "
+                       "or a per-slot leaf",
+    }
 
     return ServedModel(
         family="hybrid_moe",
@@ -258,4 +289,5 @@ def hybrid_model(spec) -> ServedModel:
         chunk_floor=128,
         count_step=count_step,
         refuses=frozenset(FEATURES),
+        reasons=reasons,
     )
