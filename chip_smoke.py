@@ -258,11 +258,12 @@ def phase_kernels(size: Size, *, on_chip: bool) -> Dict[str, Any]:
         1 + np.arange(b)[:, None] * nb + np.arange(nb)[None], jnp.int32
     )
     pos = jnp.asarray(rng.integers(ps, history, size=b), jnp.int32)
+    # pool pages as the engines hold them: heads folded into the minor axis
     for pool in ("float32", "int8"):
         if pool == "int8":
             k_l, v_l = (
                 jnp.asarray(rng.integers(
-                    -127, 128, size=(pages, ps, h, hd), dtype=np.int8
+                    -127, 128, size=(pages, ps, h * hd), dtype=np.int8
                 ))
                 for _ in range(2)
             )
@@ -273,7 +274,7 @@ def phase_kernels(size: Size, *, on_chip: bool) -> Dict[str, Any]:
                 for _ in range(2)
             )
         else:
-            k_l, v_l = normal(pages, ps, h, hd), normal(pages, ps, h, hd)
+            k_l, v_l = normal(pages, ps, h * hd), normal(pages, ps, h * hd)
             k_s = v_s = None
         q3, k_t, v_t = normal(b, h, hd), normal(b, h, hd), normal(b, h, hd)
         compare(

@@ -662,8 +662,8 @@ def check_sharding_coverage() -> List[Finding]:
     attn_abs = {
         "q": _sds((_SLOTS, 1, _H, _D // _H), jnp.float32),
         "out": _sds((_SLOTS, 1, _H, _D // _H), jnp.float32),
-        "k_pages": _sds((5, _PAGE, _H, _D // _H), jnp.float32),
-        "v_pages": _sds((5, _PAGE, _H, _D // _H), jnp.float32),
+        "k_pages": _sds((5, _PAGE, _D), jnp.float32),
+        "v_pages": _sds((5, _PAGE, _D), jnp.float32),
         "k_scale": _sds((5, _PAGE, _H), jnp.float32),
         "v_scale": _sds((5, _PAGE, _H), jnp.float32),
         "tables": _sds((_SLOTS, 4), jnp.int32),

@@ -276,23 +276,30 @@ def _embed_at(params, tokens, positions):
 def _write_kv(leaves, at, k, v):
     """The K/V write of the cached forwards: the new tokens' ``k``/``v``
     (``[..., h, hd]``) into this layer's cache ``leaves = (k_l, v_l, k_s,
-    v_s)`` at index ``at``; returns the written leaves.
+    v_s)`` at index ``at`` (row and position); returns the written leaves.
 
-    ``k_s``/``v_s`` (``k_l``'s shape minus ``hd``, f32) exist under the int8
+    The values take the trailing shape of the leaf they land in: ``(h,
+    hd)`` in the dense cache, the heads folded into one minor axis ``(h *
+    hd,)`` in the paged pool (``serve.kv_cache.init_paged_cache``).
+
+    ``k_s``/``v_s`` (``[..., h]`` a position, f32) exist under the int8
     layout only and are None otherwise.  There the new tokens quantize on
-    write (values and their own per-position-per-head scales) and only
-    stored history pays the 8-bit grid: a decode step still attends its
-    EXACT current token, which the caller hands to attention beside the
-    cache (storage is quantized, the in-flight value costs nothing to keep
-    f32)."""
+    write, per head and BEFORE the fold (values and their own
+    per-position-per-head scales), and only stored history pays the 8-bit
+    grid: a decode step still attends its EXACT current token, which the
+    caller hands to attention beside the cache (storage is quantized, the
+    in-flight value costs nothing to keep f32)."""
     k_l, v_l, k_s, v_s = leaves
-    if k_s is None:
-        return (k_l.at[at].set(k.astype(k_l.dtype)),
-                v_l.at[at].set(v.astype(v_l.dtype)), None, None)
-    kq, ks = _q_kv(k)
-    vq, vs = _q_kv(v)
-    return (k_l.at[at].set(kq), v_l.at[at].set(vq),
-            k_s.at[at].set(ks), v_s.at[at].set(vs))
+    if k_s is not None:
+        k, ks = _q_kv(k)
+        v, vs = _q_kv(v)
+        k_s, v_s = k_s.at[at].set(ks), v_s.at[at].set(vs)
+    new = k.shape[:-2]  # one entry of ``at`` per new token
+    return (
+        k_l.at[at].set(k.reshape(new + k_l.shape[2:]).astype(k_l.dtype)),
+        v_l.at[at].set(v.reshape(new + v_l.shape[2:]).astype(v_l.dtype)),
+        k_s, v_s,
+    )
 
 
 def forward_decode(params, token, cache, pos, *, num_heads: int,
@@ -366,10 +373,12 @@ def _scan_pool(blocks, x, cache, attend_of, *, num_heads: int):
     """Walk the layers over a PAGED pool, in place: the one way the paged
     forwards (decode, chunk, verify) touch it.
 
-    Every leaf ``[pages, L, page_size, ...]`` is viewed as rows ``[pages *
-    L, page_size, ...]``, layer ``l`` of physical page ``p`` at row ``p * L
-    + l`` (no data moves where the leaf lies row-major on the device; see
-    ``serve.kv_cache.init_paged_cache``).  The rows ride ``lax.scan`` as its
+    Every leaf ``[pages, L, page_size, h * hd]`` (scales ``[..., h]``) is
+    viewed as rows ``[pages * L, page_size, h * hd]``, layer ``l`` of
+    physical page ``p`` at row ``p * L + l``.  With the heads folded into
+    the minor axis the device keeps the leaf row-major, so the view is a
+    bitcast and no data moves (``serve.kv_cache.init_paged_cache`` says
+    why).  The rows ride ``lax.scan`` as its
     CARRY, never as scanned input or stacked output and never transposed,
     so a layer's ``.at[rows, offs].set`` writes the donated pool where it
     lies and a call moves the positions it writes, not the pool.
@@ -407,7 +416,9 @@ def forward_decode_paged(
 
     Same contract as :func:`forward_decode` — ``token``/``pos``: [B] int32,
     returns ``(logits [B, vocab], new_cache)`` — but ``cache`` is the
-    global page pool ``{"k", "v"}`` each ``[pages, L, page_size, h, hd]``
+    global page pool ``{"k", "v"}`` each ``[pages, L, page_size, h * hd]``
+    (heads folded into the minor axis; the layer still splits its
+    projections to heads, :func:`_write_kv` folds what it stores)
     and ``block_tables`` ([B, nb] int32) maps each slot's logical pages to
     physical ones: logical position ``j`` lives at ``(table[j //
     page_size], j % page_size)``.  Same write-then-attend order and the

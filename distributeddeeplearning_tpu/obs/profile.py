@@ -368,9 +368,12 @@ def decode_phase_breakdown(
 
     if quantized:
         def _gather_dequant(k, v, ks, vs, tbl):
+            # the pool folds its heads into the minor axis; a scale names
+            # one head of one position, so split the heads under it
+            ks, vs = ks[tbl], vs[tbl]
             return (
-                dequantize_kv(k[tbl], ks[tbl]),
-                dequantize_kv(v[tbl], vs[tbl]),
+                dequantize_kv(k[tbl].reshape(ks.shape + (-1,)), ks),
+                dequantize_kv(v[tbl].reshape(vs.shape + (-1,)), vs),
             )
 
         t_dequant_inc = _time_jitted(
@@ -388,7 +391,7 @@ def decode_phase_breakdown(
     # kernel path (fixed pseudo-random queries — the traffic, masking
     # and kernel dispatch are the step's own; only the q values differ)
     num_heads = engine.num_heads
-    hd = cache["k"].shape[-1]
+    hd = cache["k"].shape[-1] // num_heads  # minor axis: h * hd
     L = cache["k"].shape[1]
     b = engine.batch_slots
     kernel = getattr(engine, "decode_kernel", "gather")

@@ -143,9 +143,11 @@ def _kernel(tables_ref, maxpos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     ``h=12`` need no padding:
 
     ``q_ref``/``o_ref`` [1, h, nq, hd] (head-major, so a head is a
-    leading-dim index); ``k_ref``/``v_ref`` [1, block, h, hd] — the
+    leading-dim index); ``k_ref``/``v_ref`` [1, block, h * hd] — the
     physical page the index_map resolved through the prefetched block
-    table, one head read per loop step as a second-minor strided load;
+    table, its heads folded into the minor axis as the pool holds them
+    (``serve.kv_cache.init_paged_cache``), one head read per loop step as
+    the static lane slice ``[hh * hd, (hh + 1) * hd)``;
     ``ks_ref``/``vs_ref`` [1, block, h] per-(position, head) scales (int8
     pools); ``ko_ref``/``vo_ref`` [1, h, hd] the slot's exact in-flight
     token (decode overlay); ``pos_ref`` [1, nq, 1] the per-query
@@ -185,8 +187,9 @@ def _kernel(tables_ref, maxpos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
             ) == maxpos  # [block, 1]
         for hh in range(num_heads):
             q = q_ref[0, hh]  # [nq, hd]
-            kf = k_ref[0, :, hh, :].astype(jnp.float32)  # [block, hd]
-            vf = v_ref[0, :, hh, :].astype(jnp.float32)
+            lanes = slice(hh * hd, (hh + 1) * hd)  # head hh of the page
+            kf = k_ref[0, :, lanes].astype(jnp.float32)  # [block, hd]
+            vf = v_ref[0, :, lanes].astype(jnp.float32)
             if quantized:
                 # in-tile dequant: one multiply per stored vector at
                 # [block, hd] granularity — f32 history never leaves VMEM
@@ -231,8 +234,10 @@ def _pallas_attention(
     v_own: Optional[jax.Array] = None,
 ) -> jax.Array:
     """The kernel call: ``q4`` [b, nq, h, hd] against pool pages ``k_l``/
-    ``v_l`` [P, block, h, hd] addressed through ``tables`` [b, nb];
-    ``posmat`` [b, nq] per-query visibility.  Returns [b, nq, h, hd] f32.
+    ``v_l`` [P, block, h * hd] (heads folded, ``h`` and ``hd`` taken from
+    ``q4``; scales ``k_s``/``v_s`` [P, block, h]) addressed through
+    ``tables`` [b, nb]; ``posmat`` [b, nq] per-query visibility.  Returns
+    [b, nq, h, hd] f32.
     """
     b, nq, h, hd = q4.shape
     nb = tables.shape[1]
@@ -258,7 +263,7 @@ def _pallas_attention(
         (1, h, nq, hd), lambda bb, j, tbl, mp: (bb, 0, 0, 0)
     )
     page_spec = pl.BlockSpec(
-        (1, block, h, hd), lambda bb, j, tbl, mp: (tbl[bb, j], 0, 0, 0)
+        (1, block, h * hd), lambda bb, j, tbl, mp: (tbl[bb, j], 0, 0)
     )
     if quantized:
         scale_spec = pl.BlockSpec(
@@ -429,17 +434,19 @@ def dense_block(s: int, cap: int = 128) -> int:
 
 
 def _dense_as_pages(k_l, v_l, k_s, v_s, block: int):
-    """View a dense [B, S, ...] cache layer as pool pages [B·S/block,
-    block, ...] plus the identity block tables — the reshape is
-    layout-preserving, so the kernel's paged addressing covers the dense
-    layout with zero data movement."""
+    """View a dense cache layer (values [B, S, h, hd], scales [B, S, h]) as
+    pool pages [B·S/block, block, h * hd] and [B·S/block, block, h] plus the
+    identity block tables: a reshape of its rows, so the kernel's paged
+    addressing covers the dense layout.  (Row-major the reshape moves
+    nothing; where the device keeps ``(h, hd)`` in padded tiles it is a copy
+    of the layer: the dense cache is not folded yet.)"""
     b, s = k_l.shape[0], k_l.shape[1]
     nb = s // block
 
     def pages(leaf):
         if leaf is None:
             return None
-        return leaf.reshape((b * nb, block) + leaf.shape[2:])
+        return leaf.reshape(b * nb, block, -1)
 
     tables = (
         jnp.arange(b, dtype=jnp.int32)[:, None] * nb
@@ -495,9 +502,11 @@ def decode_attention_paged(
     """Single-token decode attention over the paged pool.
 
     ``q3``/``k_t``/``v_t``: [b, h, hd] (query + the exact in-flight
-    token); ``k_l``/``v_l``: [P, ps, h, hd] (this layer's pool slice,
-    already holding the current token's quantized write); ``k_s``/``v_s``:
-    [P, ps, h] f32 or None; ``pos``: [b]; returns ctx [b, h, hd].
+    token); ``k_l``/``v_l``: [P, ps, h * hd] (this layer's pool rows, heads
+    folded into the minor axis, already holding the current token's
+    quantized write); ``k_s``/``v_s``: [P, ps, h] f32 or None; ``pos``:
+    [b]; returns ctx [b, h, hd].  The kernel reads a head as a lane slice
+    of the page; the other paths split the heads after the table gather.
     """
     b, num_heads, hd = q3.shape
     nb = block_tables.shape[1]
@@ -554,15 +563,17 @@ def _gather_decode_paged(
         k_seq = jnp.where(
             own,
             k_t[:, None],
-            dequantize_kv(k_l[block_tables], k_s[block_tables]).reshape(
-                b, s, num_heads, hd
+            dequantize_kv(
+                k_l[block_tables].reshape(b, s, num_heads, hd),
+                k_s[block_tables].reshape(b, s, num_heads),
             ),
         )
         v_seq = jnp.where(
             own,
             v_t[:, None],
-            dequantize_kv(v_l[block_tables], v_s[block_tables]).reshape(
-                b, s, num_heads, hd
+            dequantize_kv(
+                v_l[block_tables].reshape(b, s, num_heads, hd),
+                v_s[block_tables].reshape(b, s, num_heads),
             ),
         )
     else:
@@ -676,11 +687,13 @@ def _gather_chunk(
     nb = block_table.shape[0]
     s = nb * page_size
     if k_s is not None:
-        k_seq = dequantize_kv(k_l[block_table], k_s[block_table]).reshape(
-            s, num_heads, hd
+        k_seq = dequantize_kv(
+            k_l[block_table].reshape(s, num_heads, hd),
+            k_s[block_table].reshape(s, num_heads),
         )
-        v_seq = dequantize_kv(v_l[block_table], v_s[block_table]).reshape(
-            s, num_heads, hd
+        v_seq = dequantize_kv(
+            v_l[block_table].reshape(s, num_heads, hd),
+            v_s[block_table].reshape(s, num_heads),
         )
     else:
         k_seq = k_l[block_table].reshape(s, num_heads, hd)

@@ -202,10 +202,11 @@ LAYOUT_RULES: LayoutRules = (
     # tensor; scale leaves ([slots, L, S, h] f32) drop the hd dim.
     (r"^kv_dense/(k|v)$", (DATA_AXES, None, None, "tensor", None)),
     (r"^kv_dense/(k|v)_scale$", (DATA_AXES, None, None, "tensor")),
-    # paged [pages+1, L, page_size, h, hd]: the page axis NEVER shards
-    # (the block-table gather must stay chip-local), heads over tensor.
-    (r"^kv_paged/(k|v)$", (None, None, None, "tensor", None)),
-    (r"^kv_paged/(k|v)_scale$", (None, None, None, "tensor")),
+    # paged [pages+1, L, page_size, h * hd], scales [..., h]: the page axis
+    # NEVER shards (the block-table gather must stay chip-local), heads over
+    # tensor: a shard of the folded minor axis is a contiguous group of
+    # whole heads, the same heads its scales' shard names.
+    (r"^kv_paged/(k|v)(_scale)?$", (None, None, None, "tensor")),
     # -- engine operands (``io/`` namespace; before the param rules so
     # ``io/pos`` can never fall through to the [max_len, d] ``pos`` rule).
     # Per-slot vectors ride the data axes (a pure-TP mesh has data size 1,
@@ -214,11 +215,12 @@ LAYOUT_RULES: LayoutRules = (
     (r"^io/(block_tables?|page_ids|k|v|from_(pos|offs)|offsets?|draft_len)$", ()),
     # -- flash-decode kernel operands (``attn/`` namespace): the Pallas
     # path shard_maps over the mesh so each chip's kernel instance runs
-    # its LOCAL heads — q/pages/out head dim over tensor, scale leaves
-    # likewise, block tables and position matrices replicated (page
-    # addressing is chip-local by construction).
-    (r"^attn/(q|out|(k|v)_pages)$", (None, None, "tensor", None)),
-    (r"^attn/(k|v)_scale$", (None, None, "tensor")),
+    # its LOCAL heads — q/out head dim over tensor, the pages' folded minor
+    # axis ([rows, page_size, h * hd]) and the scale leaves likewise, block
+    # tables and position matrices replicated (page addressing is chip-local
+    # by construction).
+    (r"^attn/(q|out)$", (None, None, "tensor", None)),
+    (r"^attn/(k|v)_(pages|scale)$", (None, None, "tensor")),
     (r"^attn/(k|v)_own$", (None, "tensor", None)),
     (r"^attn/(tables|posmat)$", ()),
     # the same kernel over the DENSE layout ([slots, S, h, hd] rows viewed

@@ -148,9 +148,10 @@ def cache_bytes(cache: Cache) -> int:
 #
 # The dense layout above reserves ``max_seq`` positions per slot whether or
 # not the sequence ever grows that long; the paged layout allocates HBM by
-# ACTUAL tokens: ``k, v: [num_pages, L, page_size, h, hd]`` and each slot
-# owns a host-side list of page ids (its block table).  Logical position
-# ``j`` of a slot lives at ``(table[j // page_size], j % page_size)``.
+# ACTUAL tokens: ``k, v: [num_pages, L, page_size, h * hd]`` (the heads
+# folded into the minor axis, head ``i`` at lanes ``[i * hd, (i + 1) * hd)``)
+# and each slot owns a host-side list of page ids (its block table).  Logical
+# position ``j`` of a slot lives at ``(table[j // page_size], j % page_size)``.
 # Admissible concurrency is then bounded by free pages, not by ``slots ×
 # max_seq`` reservations, and identical prompt prefixes can SHARE physical
 # pages (refcounted — a full page whose token ids match an already-cached
@@ -167,33 +168,38 @@ def init_paged_cache(
     head_dim: int,
     dtype: Any = jnp.float32,
 ) -> Cache:
-    """Zero-filled page pool ``{"k", "v"}``, each [pages, L, page_size, h, hd].
+    """Zero-filled page pool ``{"k", "v"}``, each [pages + 1, L, page_size,
+    h * hd]: a position's heads lie side by side in the minor axis, head
+    ``i`` at lanes ``[i * hd, (i + 1) * hd)``.
 
     ``num_pages`` counts USABLE pages; one extra scratch page (id 0,
     :data:`SCRATCH_PAGE`) is prepended so inactive decode lanes have a safe
     write target.  Page-major so one page is a contiguous leading-dim slice:
     the paged forwards (``models.pipelined_transformer._scan_pool``) view a
-    leaf as rows ``[(pages+1) * L, page_size, ...]``, layer ``l`` of page
+    leaf as rows ``[(pages+1) * L, page_size, h * hd]``, layer ``l`` of page
     ``p`` at row ``p * L + l``, write new positions into the donated pool
     in place and gather a block table's rows with one leading-axis take.
-    (The view moves nothing where the leaf lies row-major on the device.
-    A TPU lays an array out by its shape alone: at ``head_dim`` 64 it
-    makes the PAGE axis the minor one, and transposes on the way in and
-    out of every program that addresses pages; ``PERF.md`` section 7.)
+    The heads are folded because a TPU lays an array out by its shape
+    alone: a trailing ``(h, 64)`` pads its 64 lanes to 128 and makes the
+    PAGE axis the minor one, so every program that addressed pages
+    transposed the pool in and out; a minor axis of ``h * hd`` (128 or
+    more) lies row-major by default, the row view is a bitcast, and a
+    position holds the bytes it counts (``PERF.md`` section 6, PR 35).
 
     ``dtype=jnp.int8`` adds f32 scale pools ``{"k_scale", "v_scale"}``,
-    each [pages, L, page_size, h] — one scale per stored K/V vector, so
+    each [pages + 1, L, page_size, h] — one scale per stored K/V vector, so
     incremental token writes never force a page-wide requantize.
     """
     if num_pages < 1:
         raise ValueError(f"num_pages must be >= 1, got {num_pages}")
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
-    shape = (num_pages + 1, num_layers, page_size, num_heads, head_dim)
+    rows = (num_pages + 1, num_layers, page_size)
+    shape = rows + (num_heads * head_dim,)
     cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if _is_int8(dtype):
-        cache["k_scale"] = jnp.zeros(shape[:-1], jnp.float32)
-        cache["v_scale"] = jnp.zeros(shape[:-1], jnp.float32)
+        cache["k_scale"] = jnp.zeros(rows + (num_heads,), jnp.float32)
+        cache["v_scale"] = jnp.zeros(rows + (num_heads,), jnp.float32)
     return cache
 
 
@@ -621,7 +627,8 @@ def insert_pages(
     ``page_size``) into the pool pages listed in ``page_ids`` — the paged
     analogue of :func:`insert_sequence`, used by tests and one-shot
     (non-chunked) inserts; the engine's chunked prefill writes pages inside
-    the compiled chunk program instead.  Int8 pools quantize on the way in
+    the compiled chunk program instead.  The heads fold into the pool's
+    minor axis on the way in; int8 pools quantize per head first
     (per-position-per-head scales scattered alongside the values)."""
     if k.ndim == 5:
         k, v = k[0], v[0]
@@ -629,16 +636,18 @@ def insert_pages(
     n = P // page_size
     paged_k = k.reshape(L, n, page_size, h, hd).swapaxes(0, 1)
     paged_v = v.reshape(L, n, page_size, h, hd).swapaxes(0, 1)
+    fold = (n, L, page_size, h * hd)
     if quantized_cache(cache):
         kq, ks = quantize_kv(paged_k)
         vq, vs = quantize_kv(paged_v)
         return {
-            "k": cache["k"].at[page_ids].set(kq),
-            "v": cache["v"].at[page_ids].set(vq),
+            "k": cache["k"].at[page_ids].set(kq.reshape(fold)),
+            "v": cache["v"].at[page_ids].set(vq.reshape(fold)),
             "k_scale": cache["k_scale"].at[page_ids].set(ks),
             "v_scale": cache["v_scale"].at[page_ids].set(vs),
         }
+    dtype = cache["k"].dtype
     return {
-        "k": cache["k"].at[page_ids].set(paged_k.astype(cache["k"].dtype)),
-        "v": cache["v"].at[page_ids].set(paged_v.astype(cache["v"].dtype)),
+        "k": cache["k"].at[page_ids].set(paged_k.reshape(fold).astype(dtype)),
+        "v": cache["v"].at[page_ids].set(paged_v.reshape(fold).astype(dtype)),
     }

@@ -6,9 +6,12 @@ block-shape rule (last two block dims divisible by the dtype tile, or equal
 to the array dims) and the "only scalars from SMEM" rule are enforced, so a
 kernel the TPU compiler would refuse fails here, in tier-1, instead of as an
 ``error`` finish reason on the chip.  Geometries: the full LM (12 heads of
-64, 64-token pages), ``hd=128`` with 128-token pages, and a single head
-(the one shape at which the block rule happens to hold whatever the
-kernel does, which exposes what else the lowering objects to).
+64, 64-token pages), the served cell's (``galactica-1.3b``: 32 heads of 64,
+64-token pages, 64-row chunks), ``hd=128`` with 128-token pages, and a
+single head (the one shape at which the block rule happens to hold whatever
+the kernel does, which exposes what else the lowering objects to).  Pool
+pages come as the pool holds them: heads folded into the minor axis,
+``[P, page, h * hd]``, a head read as a lane slice of the page.
 """
 
 from __future__ import annotations
@@ -49,14 +52,15 @@ def _sds(shape, dtype=jnp.float32):
 
 @pytest.mark.parametrize("pool", ["float32", "int8"])
 @pytest.mark.parametrize(
-    "heads,hd,page", [(12, 64, 64), (8, 128, 128), (1, 128, 128)]
+    "heads,hd,page",
+    [(12, 64, 64), (8, 128, 128), (1, 128, 128), (32, 64, 64)],
 )
 def test_flash_decode_forms_lower_for_tpu(heads, hd, page, pool):
     """decode, chunk-prefill (full chunk and the smallest tail bucket) and
     K+1-verify over the paged pool, and decode over the dense layout."""
     quantized = pool == "int8"
     pages = SLOTS * BLOCKS + 1
-    kv = _sds((pages, page, heads, hd), jnp.int8 if quantized else jnp.float32)
+    kv = _sds((pages, page, heads * hd), jnp.int8 if quantized else jnp.float32)
     scale = _sds((pages, page, heads)) if quantized else None
     q3 = _sds((SLOTS, heads, hd))
     pos = _sds((SLOTS,), jnp.int32)

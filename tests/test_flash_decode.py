@@ -178,7 +178,7 @@ def test_int8_flash_scale_exact_vs_gather(params, layout):
     if layout == "paged":
         _, tables = _paged_setup()
         args = (
-            q3, pool(P, PS, HEADS, HD), pool(P, PS, HEADS, HD),
+            q3, pool(P, PS, HEADS * HD), pool(P, PS, HEADS * HD),  # folded
             scales(P, PS, HEADS), scales(P, PS, HEADS), k_t, v_t, pos,
             tables,
         )

@@ -196,16 +196,18 @@ def test_chunked_prefill_matches_forward(params):
         )
         off += real
     # page contents == the monolithic prefill's K/V, page by page
-    k_pages = np.asarray(cache["k"])  # [pages, L, ps, h, hd]
+    k_pages = np.asarray(cache["k"])  # [pages, L, ps, h * hd]
+    k_want = np.asarray(k_ref).reshape(k_ref.shape[:3] + (-1,))  # heads folded
     for j in range(len(prompt)):
         np.testing.assert_allclose(
             k_pages[1 + j // page_size, :, j % page_size],
-            np.asarray(k_ref)[0, :, j], atol=1e-6,
+            k_want[0, :, j], atol=1e-6,
         )
 
 
 def test_insert_pages_roundtrip(params):
-    """insert_pages scatters [L, P, h, hd] K/V into listed pages."""
+    """insert_pages scatters [L, P, h, hd] K/V into listed pages, the heads
+    folded into the pool's minor axis (head i at lanes [i*hd, (i+1)*hd))."""
     tokens = jnp.asarray([[5, 17, 3, 42, 8, 9, 11, 2]], jnp.int32)
     _, k, v = forward_prefill(params, tokens, num_heads=HEADS)
     cache = init_paged_cache(
@@ -215,13 +217,17 @@ def test_insert_pages_roundtrip(params):
     cache = insert_pages(
         cache, k[0], v[0], jnp.asarray([2, 3], jnp.int32), page_size=4
     )
+    assert cache["k"].shape == (5, CFG["num_layers"], 4, HEADS * HEAD_DIM)
+    k_want = np.asarray(k).reshape(k.shape[:3] + (-1,))
     np.testing.assert_allclose(
-        np.asarray(cache["k"])[2, :, :, :, :],
-        np.asarray(k)[0, :, 0:4], atol=1e-6,
+        np.asarray(cache["k"])[2], k_want[0, :, 0:4], atol=1e-6,
     )
     np.testing.assert_allclose(
-        np.asarray(cache["k"])[3, :, 2],
-        np.asarray(k)[0, :, 6], atol=1e-6,
+        np.asarray(cache["k"])[3, :, 2], k_want[0, :, 6], atol=1e-6,
+    )
+    np.testing.assert_allclose(  # head 1 of a position is its second lane group
+        np.asarray(cache["v"])[3, :, 2, HEAD_DIM:2 * HEAD_DIM],
+        np.asarray(v)[0, :, 6, 1], atol=1e-6,
     )
     assert page_bytes(cache) == cache_bytes(cache) // 5  # 4 pages + scratch
 

@@ -1,16 +1,20 @@
 """The paged forwards walk the page pool in place.
 
 ``models.pipelined_transformer._scan_pool`` carries every pool leaf through
-the layer scan as rows ``[(pages+1) * L, page_size, ...]`` (layer ``l`` of
-page ``p`` at row ``p * L + l``) and writes new positions into that carry.
-Two things are pinned here, on the CPU:
+the layer scan as rows ``[(pages+1) * L, page_size, h * hd]`` (layer ``l``
+of page ``p`` at row ``p * L + l``; the pool folds its heads into the minor
+axis) and writes new positions into that carry.  Pinned here, on the CPU:
 
 - logits and EVERY pool leaf, the scratch page included, are equal to every
   bit to a plain reference written in this file: a Python loop over the
   layers, each on its own slice ``cache[leaf][:, l]`` under the block tables
   as they stand, with no scan and no row view;
 - the compiled program's temporaries do not grow with the pool (a pool that
-  rides the scan as input and stacked output makes them 1.7 x the pool).
+  rides the scan as input and stacked output makes them 1.7 x the pool);
+- compiled for a described TPU v5e (no chip), at the served cell's widths,
+  the pool's default layout is row-major and the decode and chunk programs
+  set aside next to nothing beside it: the row view is a bitcast (a trailing
+  ``(32, 64)`` made the page axis minor and cost four whole-pool copies).
 """
 
 from __future__ import annotations
@@ -40,14 +44,15 @@ def params():
 def _pool(dtype, seed):
     """A pool with something in every row, the scratch page included."""
     rng = np.random.default_rng(seed)
-    shape = (PAGES + 1, LAYERS, PAGE, HEADS, HEAD_DIM)
+    rows = (PAGES + 1, LAYERS, PAGE)
+    shape = rows + (HEADS * HEAD_DIM,)  # heads folded, as the engines hold it
     if dtype == "int8":
         return {
             "k": jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
             "v": jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
-            "k_scale": jnp.asarray(rng.uniform(1e-3, 2e-2, shape[:-1]),
+            "k_scale": jnp.asarray(rng.uniform(1e-3, 2e-2, rows + (HEADS,)),
                                    jnp.float32),
-            "v_scale": jnp.asarray(rng.uniform(1e-3, 2e-2, shape[:-1]),
+            "v_scale": jnp.asarray(rng.uniform(1e-3, 2e-2, rows + (HEADS,)),
                                    jnp.float32),
         }
     return {"k": jnp.asarray(rng.normal(size=shape), jnp.float32),
@@ -78,12 +83,14 @@ def _reference(params, x, cache, pages, offs, attend):
             t.reshape(lead + (HEADS, HEAD_DIM))
             for t in jnp.split(pt._mm(h, p["qkv"]), 3, axis=-1)
         )
-        if "k_scale" in leaf:
+        if "k_scale" in leaf:  # quantized per head, then folded
             kq, ks = pt._q_kv(k_c)
             vq, vs = pt._q_kv(v_c)
-            new = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+            new = {"k": kq.reshape(lead + (-1,)), "v": vq.reshape(lead + (-1,)),
+                   "k_scale": ks, "v_scale": vs}
         else:
-            new = {"k": k_c, "v": v_c}
+            new = {"k": k_c.reshape(lead + (-1,)),
+                   "v": v_c.reshape(lead + (-1,))}
         leaf = {n: a.at[pages, offs].set(new[n].astype(a.dtype))
                 for n, a in leaf.items()}
         ctx = attend(q, k_c, v_c, leaf["k"], leaf["v"],
@@ -262,3 +269,71 @@ def test_temporaries_do_not_grow_with_the_pool(params, form):
     large, pool_bytes = temporaries(256)
     assert large == small, (small, large)
     assert large < pool_bytes / 4
+
+
+# -- the same two programs compiled for a described TPU v5e: no chip, shapes
+# only.  The topology is described inside a fixture, never at import (one
+# process at a time may load the TPU's library; see the module docstring of
+# tests/test_chip_lowering.py for what lowering alone can show).
+
+CELL = dict(num_layers=2, d_model=2048, num_heads=32, d_ff=8192,
+            vocab_size=50000, max_len=2048)  # galactica-1.3b's widths
+CELL_PAGE, CELL_PAGES, CELL_SLOTS, CELL_NB, CELL_CHUNK = 64, 64, 16, 10, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to compile for
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("form", ["decode", "chunk"])
+def test_pool_lies_row_major_on_a_v5e_and_no_program_copies_it(
+        one_chip, monkeypatch, form):
+    """The guard that no later shape change brings the transposes back."""
+    from distributeddeeplearning_tpu.quant import bf16_matmul_params
+
+    monkeypatch.setattr(fd, "_use_interpret", lambda: False)  # Mosaic compiles
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree)
+
+    # what the engine serves from on a TPU: matmul leaves rounded to bf16
+    weights = on_chip(jax.eval_shape(
+        lambda: bf16_matmul_params(pt.init_params(jax.random.key(0), **CELL))))
+    cache = on_chip(jax.eval_shape(functools.partial(
+        init_paged_cache, num_pages=CELL_PAGES, num_layers=CELL["num_layers"],
+        page_size=CELL_PAGE, num_heads=CELL["num_heads"],
+        head_dim=CELL["d_model"] // CELL["num_heads"])))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    kw = dict(num_heads=CELL["num_heads"], page_size=CELL_PAGE, kernel="pallas")
+    if form == "decode":
+        fn = lambda p, c, tok, pos, tables: pt.forward_decode_paged(  # noqa: E731
+            p, tok, c, pos, tables, **kw)
+        args = (i32(CELL_SLOTS), i32(CELL_SLOTS), i32(CELL_SLOTS, CELL_NB))
+    else:
+        fn = lambda p, c, toks, table, off: pt.forward_prefill_chunk(  # noqa: E731
+            p, toks, c, table, off, **kw)
+        args = (i32(1, CELL_CHUNK), i32(CELL_NB), i32())
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        weights, cache, *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the kernel, via Mosaic
+    pool_formats = compiled.input_formats[0][1]
+    for name, leaf in cache.items():
+        assert leaf.shape == (CELL_PAGES + 1, CELL["num_layers"], CELL_PAGE,
+                              CELL["d_model"])
+        assert tuple(pool_formats[name].layout.major_to_minor) == (0, 1, 2, 3), (
+            name, pool_formats[name])
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in cache.values())
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries < pool_bytes / 10, (temporaries, pool_bytes)

@@ -15,6 +15,8 @@ all-reduce count the comm-path lint audits.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -208,3 +210,87 @@ def test_collective_stats_splits_tp_from_data_all_reduce():
     stats = comms.collective_stats(hlo, mesh=mesh)
     assert stats.get(comms.TP_ALL_REDUCE, {}).get("count", 0) >= 1, stats
     assert stats.get("all-reduce", {}).get("count", 0) >= 1, stats
+
+
+@pytest.mark.parametrize("cache_dtype", [None, jnp.int8], ids=["f32", "int8"])
+def test_folded_pool_shards_whole_heads_and_same_logits(params, cache_dtype):
+    """The paged pool folds its heads into the minor axis: the ``kv_paged``
+    rule's tensor shard of that axis is a contiguous group of WHOLE heads
+    (the heads its scales' shard names), the ``attn/*_pages`` rule hands
+    each chip's kernel instance those local heads, and a decode step
+    through the per-shard Pallas kernel gives the logits of the one-device
+    program."""
+    from distributeddeeplearning_tpu.models.pipelined_transformer import (
+        forward_decode_paged,
+        forward_prefill,
+    )
+    from distributeddeeplearning_tpu.ops import flash_decode as fd
+    from distributeddeeplearning_tpu.parallel import sharding as layout
+    from distributeddeeplearning_tpu.serve import (
+        init_paged_cache,
+        insert_pages,
+    )
+    from distributeddeeplearning_tpu.serve.kv_cache import cache_sharding
+
+    hd, page, tp = CFG["d_model"] // HEADS, 4, 2
+    mesh = create_mesh(MeshSpec(data=1, tensor=tp), devices=jax.devices()[:tp])
+    tokens = jnp.asarray([[5, 17, 3, 42, 8, 9, 11, 2]], jnp.int32)
+    _, k, v = forward_prefill(params, tokens, num_heads=HEADS)
+    kw = {} if cache_dtype is None else {"dtype": cache_dtype}
+    cache = insert_pages(
+        init_paged_cache(num_pages=6, num_layers=CFG["num_layers"],
+                         page_size=page, num_heads=HEADS, head_dim=hd, **kw),
+        k[0], v[0], jnp.asarray([2, 5], jnp.int32), page_size=page,
+    )
+    shardings = cache_sharding(
+        mesh, quantized=cache_dtype is not None, layout="paged")
+    placed = jax.device_put(cache, shardings)
+    local = HEADS // tp
+    for name, leaf in placed.items():
+        assert shardings[name].spec == P(None, None, None, "tensor"), name
+        width = local * (1 if name.endswith("_scale") else hd)
+        for i, shard in enumerate(
+                sorted(leaf.addressable_shards, key=lambda s: s.device.id)):
+            assert shard.data.shape == leaf.shape[:-1] + (width,), name
+            np.testing.assert_array_equal(  # heads [i*local, (i+1)*local)
+                np.asarray(shard.data),
+                np.asarray(cache[name])[..., i * width:(i + 1) * width])
+
+    # the kernel's operands under the mesh: pages and scales split their
+    # minor axis, q and out their head axis, all to the same local heads
+    q4 = jnp.zeros((2, 1, HEADS, hd), jnp.float32)
+    rows = placed["k"].reshape((-1,) + placed["k"].shape[2:])
+    names, in_specs, out_spec = fd.attention_partition_specs(
+        {"q": q4, "k_pages": rows, "v_pages": rows,
+         "k_scale": None, "v_scale": None}, mesh=mesh)
+    assert dict(zip(names, in_specs)) == {
+        "q": P(None, None, "tensor"),  # [b, nq, h, hd]: the head axis
+        "k_pages": P(None, None, "tensor"),  # [rows, page, h * hd]: the minor
+        "v_pages": P(None, None, "tensor"),
+    }
+    assert out_spec == P(None, None, "tensor")
+
+    token = jnp.asarray([7, 9], jnp.int32)
+    pos = jnp.asarray([8, 3], jnp.int32)  # slot 0 appends, slot 1 mid-page
+    tables = jnp.asarray([[2, 5, 1], [3, 0, 0]], jnp.int32)
+
+    def step(p, c, *, mesh):
+        return forward_decode_paged(
+            p, token, c, pos, tables, num_heads=HEADS, page_size=page,
+            kernel="pallas", mesh=mesh)
+
+    want, want_cache = jax.jit(functools.partial(step, mesh=None))(
+        params, cache)
+    sharded_params = jax.device_put(
+        params, layout.resolve_shardings(mesh, params, prefix="params"))
+    per_shard = functools.partial(step, mesh=mesh)
+    assert "shard_map" in str(jax.make_jaxpr(per_shard)(sharded_params, placed))
+    got, got_cache = jax.jit(per_shard)(sharded_params, placed)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-5)
+    for name in cache:
+        assert got_cache[name].shape == cache[name].shape
+        np.testing.assert_allclose(
+            np.asarray(got_cache[name]).astype(np.float32),
+            np.asarray(want_cache[name]).astype(np.float32),
+            atol=1e-6, err_msg=name)
