@@ -377,8 +377,8 @@ def build_parser() -> argparse.ArgumentParser:
                          "(models/hybrid_moe_transformer.py), at the sizes "
                          "of this configuration file under the model's "
                          "published keys (benchmarks/configs/"
-                         "mimo-v2-flash.json and lfm2-8b-a1b.json are "
-                         "two), seeded weights; needs "
+                         "mimo-v2-flash.json, lfm2-8b-a1b.json and "
+                         "trinity-mini.json are three), seeded weights; needs "
                          "--kv-layout paged --no-prefix-cache, and refuses "
                          "the int8 pool, the host tier, --speculative and "
                          "--replicas > 1")

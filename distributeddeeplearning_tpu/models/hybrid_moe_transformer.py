@@ -8,7 +8,11 @@ a leading part of every head, normalise with RMS norm, and run a gated FFN
 that in most layers is a **mixture of experts** of which this chip holds a
 stated subset.  A third kind of layer has no attention at all: a **gated
 short convolution** (:func:`short_conv`), whose only memory of a sequence
-is the last ``conv_taps - 1`` inputs of its depthwise convolution.
+is the last ``conv_taps - 1`` inputs of its depthwise convolution.  Values of
+the spec also give an always-on **shared expert** beside the routed sum, a
+sigmoid **gate on the attention's output**, a norm **after** each operator
+as well as before it, layer kinds that **do not rotate** at all, and a
+scaled embedding.
 
 Everything about the architecture is in one hashable :class:`HybridSpec`;
 parameters are a plain pytree with one dict per layer (no stacking: every
@@ -57,7 +61,7 @@ DENSE, EXPERTS = 0, 1
 #: tokens and the held experts touched, each summed over the expert layers
 EXPERT_COUNTS = ("pairs_total", "pairs_here", "tokens_max", "experts_touched")
 #: a layer's norm scales (made 1, where every other weight is drawn)
-NORM_SCALES = ("ln1", "ln2", "q_norm", "k_norm")
+NORM_SCALES = ("ln1", "ln2", "q_norm", "k_norm", "ln1_post", "ln2_post")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,6 +100,19 @@ class HybridSpec:
     qk_norm: bool = False
     #: the head is the embedding, read transposed (no ``head`` leaf)
     tied_head: bool = False
+    #: width of a gated FFN that every token takes beside its routed
+    #: experts in an expert layer (0: none)
+    shared_width: int = 0
+    #: ``sigmoid(h Wgate)`` multiplies the attention's output, element by
+    #: element, before the output projection
+    output_gate: bool = False
+    #: an RMS norm after each operator too, before it joins the residual
+    post_norms: bool = False
+    #: whether a full layer's queries and keys rotate at all (False: it has
+    #: no position signal but the causal mask; a window layer always rotates)
+    rotate_full: bool = True
+    #: multiplies the embedding's rows as they enter the residual stream
+    embed_scale: float = 1.0
 
     def __post_init__(self):
         if len(self.attn_kinds) != len(self.ffn_kinds):
@@ -132,6 +149,9 @@ class HybridSpec:
     def has_sink(self, kind: int) -> bool:
         return self.sink_window if kind == WINDOW else self.sink_full
 
+    def rotates(self, kind: int) -> bool:
+        return kind == WINDOW or self.rotate_full
+
     def layers_of(self, kind: int) -> Tuple[int, ...]:
         return tuple(i for i, k in enumerate(self.attn_kinds) if k == kind)
 
@@ -149,7 +169,10 @@ def spec_from_config(cfg: dict) -> HybridSpec:
     ``n_routed_experts`` counts the experts held here; ``experts_held``
     their ids and ``n_routed_experts_published`` the router's width (both
     default to "all of them").  A configuration under the ``lfm2_moe`` keys
-    (``layer_types``) is read by :func:`_spec_from_lfm2_moe`."""
+    (``layer_types``) is read by :func:`_spec_from_lfm2_moe`, one whose
+    ``model_type`` is ``afmoe`` by :func:`_spec_from_afmoe`."""
+    if cfg.get("model_type") == "afmoe":
+        return _spec_from_afmoe(cfg)
     if "layer_types" in cfg:
         return _spec_from_lfm2_moe(cfg)
     held = cfg.get("experts_held")
@@ -243,6 +266,66 @@ def _spec_from_lfm2_moe(cfg: dict) -> HybridSpec:
     )
 
 
+_AFMOE_KINDS = {"sliding_attention": WINDOW, "full_attention": FULL}
+
+
+def _spec_from_afmoe(cfg: dict) -> HybridSpec:
+    """The ``afmoe`` keys: ``layer_types`` names every published layer
+    ``sliding_attention`` (a window of ``sliding_window``, rotary over the
+    whole head) or ``full_attention`` (no position signal at all); both
+    kinds have one KV head count, a learned RMS norm over every query and
+    key head, a sigmoid gate on the attention's output and an RMS norm after
+    each operator as well as before it; the first ``num_dense_layers``
+    published layers have a dense FFN, the others ``num_shared_experts``
+    always-on experts beside the routed ones (``route_norm``,
+    ``route_scale``); the embedding is scaled by ``sqrt(hidden_size)``
+    where ``mup_enabled``.  ``num_experts`` counts the experts held here,
+    ``experts_held`` their ids and ``num_experts_published`` the router's
+    width (both default to "all of them"); ``layers_kept`` says which of
+    the published layers are run."""
+    if cfg.get("score_func", "sigmoid") != "sigmoid":
+        raise ValueError("afmoe: only the sigmoid router is written")
+    kept = cfg.get("layers_kept", range(cfg["num_hidden_layers"]))
+    if len(kept) != cfg["num_hidden_layers"]:
+        raise ValueError("layers_kept and num_hidden_layers disagree")
+    d, head_dim = cfg["hidden_size"], cfg["head_dim"]
+    return HybridSpec(
+        vocab_size=cfg["vocab_size"],
+        d_model=d,
+        num_q_heads=cfg["num_attention_heads"],
+        k_dim=head_dim,
+        v_dim=head_dim,
+        rotary_dim=head_dim,
+        kv_heads_full=cfg["num_key_value_heads"],
+        kv_heads_window=cfg["num_key_value_heads"],
+        window=cfg["sliding_window"],
+        theta_full=float(cfg["rope_theta"]),
+        theta_window=float(cfg["rope_theta"]),
+        sink_full=False,
+        sink_window=False,
+        value_scale=1.0,
+        eps=float(cfg["rms_norm_eps"]),
+        attn_kinds=tuple(_AFMOE_KINDS[cfg["layer_types"][i]] for i in kept),
+        ffn_kinds=tuple(DENSE if i < cfg["num_dense_layers"] else EXPERTS
+                        for i in kept),
+        d_ff=cfg["intermediate_size"],
+        d_expert=cfg["moe_intermediate_size"],
+        num_experts=cfg.get("num_experts_published", cfg["num_experts"]),
+        experts_per_token=cfg["num_experts_per_tok"],
+        experts_held=tuple(cfg.get("experts_held",
+                                   range(cfg["num_experts"]))),
+        norm_topk=bool(cfg["route_norm"]),
+        routed_scale=float(cfg.get("route_scale") or 1.0),
+        topk_eps=1e-20,
+        qk_norm=True,
+        shared_width=cfg["num_shared_experts"] * cfg["moe_intermediate_size"],
+        output_gate=True,
+        post_norms=True,
+        rotate_full=False,
+        embed_scale=float(d) ** 0.5 if cfg.get("mup_enabled") else 1.0,
+    )
+
+
 def layer_shapes(spec: HybridSpec, layer: int) -> Dict[str, tuple]:
     """name -> shape of one layer's weights."""
     kind, d = spec.attn_kinds[layer], spec.d_model
@@ -260,6 +343,10 @@ def layer_shapes(spec: HybridSpec, layer: int) -> Dict[str, tuple]:
             out.update(q_norm=(spec.k_dim,), k_norm=(spec.k_dim,))
         if spec.has_sink(kind):
             out["sink"] = (hq,)
+        if spec.output_gate:
+            out["w_gate"] = (d, hq * spec.v_dim)
+    if spec.post_norms:
+        out.update(ln1_post=(d,), ln2_post=(d,))
     if spec.ffn_kinds[layer] == DENSE:
         out.update(wg=(d, spec.d_ff), wu=(d, spec.d_ff), wd=(spec.d_ff, d))
     else:
@@ -267,6 +354,10 @@ def layer_shapes(spec: HybridSpec, layer: int) -> Dict[str, tuple]:
         out.update(router=(d, spec.num_experts),
                    router_bias=(spec.num_experts,),
                    wg=(held, d, fe), wu=(held, d, fe), wd=(held, fe, d))
+        if spec.shared_width:
+            fs = spec.shared_width
+            out.update(shared_wg=(d, fs), shared_wu=(d, fs),
+                       shared_wd=(fs, d))
     return out
 
 
@@ -331,10 +422,11 @@ def rotary(x, positions, *, rotary_dim: int, theta: float):
 attend = _fd.gqa_attend
 
 
-def gated_ffn(p, h):
-    """``(silu(h Wg) * h Wu) Wd`` at the dense width."""
-    a = jax.nn.silu(_mm(h, p["wg"])) * _mm(h, p["wu"])
-    return _mm(a, p["wd"])
+def gated_ffn(p, h, prefix: str = ""):
+    """``(silu(h Wg) * h Wu) Wd`` at the dense width (``prefix`` "shared_":
+    the always-on expert's three matrices)."""
+    a = jax.nn.silu(_mm(h, p[prefix + "wg"])) * _mm(h, p[prefix + "wu"])
+    return _mm(a, p[prefix + "wd"])
 
 
 def route(p, h32, *, spec: HybridSpec):
@@ -371,8 +463,12 @@ def expert_layer(p, h32, *, spec: HybridSpec, live=None):
     here.  So the products run over the first ``rows`` sorted pairs, four
     times that expectation, when the pairs here fit in them, and over all
     ``T * k`` when they do not: nothing is ever dropped, and the cost
-    follows the load.  Returns ``(y [T, d] float32, counts)`` with
-    ``counts`` the :data:`EXPERT_COUNTS` of this layer (int32 [4])."""
+    follows the load.  Where the spec has a ``shared_width``, the always-on
+    gated FFN of every row given (padding rows and dead lanes too: masking
+    them would cost more than their rows of one product) is added to the
+    routed sum; it takes part in no count.  Returns ``(y [T, d] float32,
+    counts)`` with ``counts`` the :data:`EXPERT_COUNTS` of this layer (int32
+    [4])."""
     T, d = h32.shape
     k, held = spec.experts_per_token, len(spec.experts_held)
     chosen, w = route(p, h32, spec=spec)
@@ -413,6 +509,8 @@ def expert_layer(p, h32, *, spec: HybridSpec, live=None):
                          lambda: products(T * k))
     else:
         y = products(T * k)
+    if spec.shared_width:
+        y = y + gated_ffn(p, h32, "shared_")
     made = jnp.int32(T * k) if live is None else live.sum().astype(jnp.int32) * k
     counts = jnp.stack(
         [made, here, sizes.max(), (sizes > 0).sum().astype(jnp.int32)])
@@ -421,8 +519,9 @@ def expert_layer(p, h32, *, spec: HybridSpec, live=None):
 
 def attention_op(p, h, positions, *, spec: HybridSpec, kind: int, attention):
     """An attention layer's operator on the normed rows ``h`` [T, d]:
-    projections, the per-head norm where the spec has one, the rotation,
-    the caller's ``attention`` (see :func:`block`), the output projection."""
+    projections, the per-head norm where the spec has one, the rotation
+    where the kind rotates, the caller's ``attention`` (see :func:`block`),
+    the output gate where the spec has one, the output projection."""
     T = h.shape[0]
     hq, hkv = spec.num_q_heads, spec.kv_heads(kind)
     cdt = p["wq"].dtype
@@ -431,6 +530,8 @@ def attention_op(p, h, positions, *, spec: HybridSpec, kind: int, attention):
         y = _mm(h, p[w]).reshape(T, heads, spec.k_dim)
         if spec.qk_norm:
             y = rms_norm(y, p[norm], spec.eps)
+        if not spec.rotates(kind):
+            return y
         return rotary(y, positions, rotary_dim=spec.rotary_dim,
                       theta=spec.theta(kind))
 
@@ -438,7 +539,10 @@ def attention_op(p, h, positions, *, spec: HybridSpec, kind: int, attention):
     v = spec.value_scale * _mm(h, p["wv"]).reshape(T, hkv, spec.v_dim)
     ctx = attention(q.astype(cdt), k.astype(cdt), v.astype(cdt),
                     p["sink"] if spec.has_sink(kind) else None)
-    return _mm(ctx.reshape(T, hq * spec.v_dim), p["wo"])
+    ctx = ctx.reshape(T, hq * spec.v_dim)
+    if spec.output_gate:
+        ctx = ctx.astype(jnp.float32) * jax.nn.sigmoid(_mm(h, p["w_gate"]))
+    return _mm(ctx, p["wo"])
 
 
 def short_conv(p, h, *, spec: HybridSpec, state):
@@ -471,17 +575,30 @@ def block(p, x, positions, *, spec: HybridSpec, layer: int, attention=None,
     layer takes ``state`` (:func:`short_conv`).  Returns ``(x, counts)``
     with ``counts`` the expert layer's (None in a dense layer)."""
     kind = spec.attn_kinds[layer]
+
+    def joined(x, y, norm):
+        """The operator's output into the residual, through the norm after
+        it where the spec has one."""
+        return x + (rms_norm(y, p[norm], spec.eps) if spec.post_norms else y)
+
     h = rms_norm(x, p["ln1"], spec.eps)
     if kind == CONV:
-        x = x + short_conv(p, h, spec=spec, state=state)
+        y = short_conv(p, h, spec=spec, state=state)
     else:
-        x = x + attention_op(p, h, positions, spec=spec, kind=kind,
-                             attention=attention)
+        y = attention_op(p, h, positions, spec=spec, kind=kind,
+                         attention=attention)
+    x = joined(x, y, "ln1_post")
     h = rms_norm(x, p["ln2"], spec.eps)
     if spec.ffn_kinds[layer] == DENSE:
-        return x + gated_ffn(p, h), None
+        return joined(x, gated_ffn(p, h), "ln2_post"), None
     y, counts = expert_layer(p, h, spec=spec, live=live)
-    return x + y, counts
+    return joined(x, y, "ln2_post"), counts
+
+
+def _embed(params, tokens, spec):
+    """The tokens' rows of the embedding as the float32 residual stream."""
+    x = params["embed"][tokens].astype(jnp.float32)
+    return x * spec.embed_scale if spec.embed_scale != 1.0 else x
 
 
 def _logits(params, x, spec):
@@ -532,7 +649,7 @@ def forward(params, tokens, *, spec: HybridSpec):
         visible = causal & in_window if kind == WINDOW else causal
         return lambda q, k, v, sink: attend(q, k, v, visible, sink)
 
-    x = params["embed"][tokens].astype(jnp.float32)
+    x = _embed(params, tokens, spec)
     x, _ = _stack(spec, params, x, pos, callback_of)
     return _logits(params, x, spec)
 
@@ -608,7 +725,7 @@ def forward_decode(params, token, cache, pos, block_tables, live, *,
 
         return window if kind == WINDOW else full
 
-    x = params["embed"][token].astype(jnp.float32)
+    x = _embed(params, token, spec)
     x, counts = _stack(spec, params, x, pos, callback_of, live=live)
     cache = {name: tuple(leaves) for name, leaves in cache.items()}
     return _logits(params, x, spec), cache, counts
@@ -698,7 +815,7 @@ def forward_prefill_chunk(params, tokens, cache, block_table, offset, slot,
 
         return window if kind == WINDOW else full
 
-    x = params["embed"][tokens[0]].astype(jnp.float32)
+    x = _embed(params, tokens[0], spec)
     # the rows that pad the chunk reach no expert
     x, _ = _stack(spec, params, x, posns, callback_of,
                   live=jnp.arange(C) < real)

@@ -351,7 +351,10 @@ class ServeReport:
     # expert layers: how many experts' weights the step had to read).
     # ``*_positions_held_sum``: summed a decode step over live slots, the
     # positions ONE layer of the kind holds for the slot: a full layer
-    # every position, a window layer at most the window.
+    # every position, a window layer at most the window;
+    # ``ring_positions_capacity_sum``: likewise, the positions ONE window
+    # layer's ring has room for (the window, whatever the slot holds): what
+    # a decode step's read of the ring streams.
     # ``slot_state_bytes_held_sum`` / ``kv_bytes_held_sum``: summed a
     # decode step over live slots, the bytes the slot holds beside the
     # pages (window rings, convolution states: whole, whatever its
@@ -363,6 +366,7 @@ class ServeReport:
     experts_touched_sum: int = 0
     window_positions_held_sum: int = 0
     full_positions_held_sum: int = 0
+    ring_positions_capacity_sum: int = 0
     slot_state_bytes_held_sum: int = 0
     kv_bytes_held_sum: int = 0
 

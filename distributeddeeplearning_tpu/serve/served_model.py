@@ -11,8 +11,9 @@ has to refuse for it.  Two bindings exist:
   engine's programs, shapes and numbers for it are what they were.
 - :func:`hybrid_model` binds ``models.hybrid_moe_transformer`` (window and
   full attention layers with their own KV head counts and cache lifetimes,
-  sparse experts of which a stated subset is held; gated short-convolution
-  layers with no K/V at all): its cache has per-slot state (the window
+  sparse experts of which a stated subset is held, with or without an
+  always-on one beside them; gated short-convolution layers with no K/V at
+  all): its cache has per-slot state (the window
   layers' rings, the convolution layers' last inputs) beside the pages, so
   its chunk program is also told the slot and how many of the chunk's
   tokens are real, its decode program which lanes are live, and its decode
@@ -252,6 +253,11 @@ def hybrid_model(spec) -> ServedModel:
             "experts_touched_sum": step["experts_touched"],
             "window_positions_held_sum":
                 int(np.minimum(held, spec.window).sum()),
+            # what one window layer's read streams for the live lanes:
+            # each one's whole ring, whatever it holds (the read is over
+            # every lane, so the dead lanes' rings are streamed besides)
+            "ring_positions_capacity_sum":
+                len(held) * spec.window if n_window else 0,
             "full_positions_held_sum": int(held.sum()),
             "slot_state_bytes_held_sum": len(held) * laid_out["lane_state"],
             "kv_bytes_held_sum": int(held.sum()) * laid_out["position"],
