@@ -905,7 +905,8 @@ def build_program_records() -> List[ProgramRecord]:
             ),
             ProgramRecord(
                 f"{tag}.decode", engine._decode_jit,
-                (p_abs, c_abs, slot_vec, slot_vec, tables, scalar, False),
+                (p_abs, c_abs, slot_vec, slot_vec, tables, scalar,
+                 slot_vec, _sds((_SLOTS,), jnp.bool_), False),
                 donate_min=nleaves,
                 int8_history_len=_SEQ if int8_cache else None,
                 int8_head_dim=_HD if (int8_cache and flash) else None,

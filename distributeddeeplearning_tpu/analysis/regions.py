@@ -63,11 +63,15 @@ HOT_REGIONS: Tuple[HotRegion, ...] = (
         module="distributeddeeplearning_tpu.serve.scheduler",
         qualname="ContinuousBatchingScheduler.run",
         locator="while pending or active",
-        # the ONE designed sync is the token readback inside engine.decode
-        # (not in this region's source), so the loop body itself budgets 0
+        # the ONE designed sync is the token readback inside the engine's
+        # reading half (engine.decode_fetch, which engine.decode ends in:
+        # not in this region's source), so the loop body itself budgets 0:
+        # with a step in flight it calls the two halves a turn apart, with
+        # none it calls engine.decode
         # the spans that cover the turn outside the step are load-bearing
         # too: the benchmark's turn and idle-attribution metrics read them
-        landmarks=("engine.decode(", "trace.span(", '"serve/poll"',
+        landmarks=("engine.decode_dispatch(", "engine.decode_fetch(",
+                   "engine.decode(", "trace.span(", '"serve/poll"',
                    '"serve/admission"', '"serve/emit"'),
         sync_budget=0,
     ),

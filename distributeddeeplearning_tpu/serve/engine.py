@@ -36,7 +36,7 @@ from __future__ import annotations
 
 import logging
 import time
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -785,6 +785,20 @@ class PrefillTask:
         return self.offset >= len(self.prompt)
 
 
+class DispatchedStep(NamedTuple):
+    """A decode step that was launched and not read yet
+    (``PagedInferenceEngine.decode_dispatch`` -> ``decode_fetch``): its
+    outputs as they lie on the device (``logits`` and ``counts`` None where
+    the step returns none), and the live lanes' positions for what the
+    model's step counted."""
+
+    tokens: Any
+    finite: Any
+    logits: Any
+    counts: Any
+    live_pos: Optional[np.ndarray]
+
+
 class PagedInferenceEngine:
     """Paged-KV-cache generation: HBM by actual tokens, not ``max_seq``.
 
@@ -801,7 +815,11 @@ class PagedInferenceEngine:
       through the compiled chunk program (``forward_prefill_chunk``);
       returns the first sampled token once the last chunk lands;
     - ``decode(tokens, pos)`` — one step for all slots via block-table
-      gather (``forward_decode_paged``);
+      gather (``forward_decode_paged``); it is ``decode_dispatch`` (upload
+      and launch, nothing read) and ``decode_fetch`` (the step's one read)
+      back to back, and a loop that calls the halves itself can launch a
+      step before it reads the one before: the sampled tokens stay on the
+      device and feed the next step from there;
     - ``release(slot)`` — decref the slot's pages; full prompt pages
       stay in the prefix table (reclaimable) for future hits.
 
@@ -1001,7 +1019,8 @@ class PagedInferenceEngine:
             )
             decode_kw = dict(
                 in_shardings=(
-                    p_shard, c_shard, slot_vec, slot_vec, rep, scalar
+                    p_shard, c_shard, slot_vec, slot_vec, rep, scalar,
+                    slot_vec, slot_vec,
                 ),
             )
         else:
@@ -1016,6 +1035,10 @@ class PagedInferenceEngine:
         # lanes whose block-table row is installed (the prompt is fully
         # written): what a model with per-slot state is told each step
         self._live = np.zeros(batch_slots, bool)
+        # the tokens the last dispatched decode step sampled, still on the
+        # device: the next step's input for every lane the host does not
+        # set itself (see _decode_fn)
+        self._last_toks = jnp.zeros(batch_slots, jnp.int32)
         # what decode steps counted since reset_stats(), under the names
         # ServeReport carries them by (empty for a model that counts none)
         self.step_counters: Dict[str, float] = {}
@@ -1051,7 +1074,12 @@ class PagedInferenceEngine:
             )
 
         def _decode_fn(params, cache, tokens, pos, block_tables, step,
-                       with_logits, *slot_args):
+                       prev_tokens, fresh, with_logits, *slot_args):
+            # a lane's input token is the host's where the host set one
+            # (``fresh``: its first token came from its prefill, or the
+            # step before was read), else the one the step before sampled,
+            # which never left the device
+            tokens = jnp.where(fresh, tokens, prev_tokens)
             logits, cache, *counts = model.decode(
                 params, tokens, cache, pos, block_tables, *slot_args,
                 page_size=page_size, kernel=dec_kernel, mesh=mesh,
@@ -1086,7 +1114,7 @@ class PagedInferenceEngine:
             _chunk_fn, donate_argnums=(1,), **chunk_kw
         ))
         self._decode_jit = tracked_jit(f"{tag}.decode", jax.jit(
-            _decode_fn, donate_argnums=(1,), static_argnums=(6,), **decode_kw
+            _decode_fn, donate_argnums=(1,), static_argnums=(8,), **decode_kw
         ))
         self._sample_jit = jax.jit(_sample)
         self._scrub_jit = tracked_jit(f"{tag}.scrub", jax.jit(
@@ -1401,19 +1429,59 @@ class PagedInferenceEngine:
     def decode(self, tokens: np.ndarray, pos: np.ndarray) -> np.ndarray:
         """One decode step for every slot via block-table gather.  Same
         contract as the dense engine; released slots' rows point at the
-        scratch page so their (ignored) lane writes are harmless."""
+        scratch page so their (ignored) lane writes are harmless.  The
+        step's two halves back to back."""
+        return self.decode_fetch(self.decode_dispatch(tokens, pos))
+
+    # what a loop asks before it calls the halves itself: an engine whose
+    # ``decode`` was wrapped or overridden (a subclass, a planted fault) is
+    # driven through that ``decode``
+    decode.is_its_two_halves = True
+
+    def decode_dispatch(
+        self,
+        tokens: np.ndarray,
+        pos: np.ndarray,
+        fresh: Optional[np.ndarray] = None,
+        rows: Optional[np.ndarray] = None,
+    ) -> "DispatchedStep":
+        """Upload one decode step's arguments and launch it; read nothing.
+
+        ``fresh[i]`` false makes lane ``i``'s input the token the step
+        dispatched before this one sampled for it, taken where it lies on
+        the device (``tokens[i]`` is then ignored), so a caller can launch
+        step n+1 before it has read step n.  ``rows[i]`` false gives lane
+        ``i`` what a released slot has: the scratch row, not live; for a
+        lane that the caller knows to have ended but has not released yet.
+        Both default to every lane.  What comes back goes to
+        :meth:`decode_fetch`, once, in dispatch order.  Nothing here keeps
+        a reference to the caller's buffers: they may change as soon as
+        this returns."""
         trace = get_tracer()
         with trace.span("serve/engine.decode_upload"):
+            tables, live = self._block_tables, self._live
+            if rows is not None:
+                tables = np.where(rows[:, None], tables, SCRATCH_PAGE)
+                live = live & rows
+            # host copies that nothing else holds: an upload may alias
+            # its source (it does on the CPU), and the step may still run
+            # when the caller, or release / prefill_step here, next writes
+            # these buffers
             args = (
                 self.params,
                 self._cache,
-                jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(pos, jnp.int32),
-                jnp.asarray(self._block_tables),
+                jnp.asarray(np.array(tokens, np.int32)),
+                jnp.asarray(np.array(pos, np.int32)),
+                jnp.asarray(np.array(tables)),
                 jnp.int32(self._next_step()),
+                self._last_toks,
+                jnp.asarray(
+                    np.ones(self.batch_slots, bool) if fresh is None
+                    else np.array(fresh)),
             )
             slot_args = (
-                (jnp.asarray(self._live),) if self.model.slot_state else ()
+                (jnp.asarray(np.array(live)),)
+                if self.model.slot_state else ()
             )
         logits = None
         with trace.span("serve/engine.decode_dispatch"):
@@ -1425,27 +1493,38 @@ class PagedInferenceEngine:
                 toks, finite, *counts, self._cache = self._decode_jit(
                     *args, False, *slot_args
                 )
+        self._last_toks = toks
+        return DispatchedStep(
+            toks, finite, logits, counts[0] if counts else None,
+            np.asarray(pos)[live] if counts else None,
+        )
+
+    def decode_fetch(self, step: "DispatchedStep") -> np.ndarray:
+        """Read a dispatched step: its sampled tokens come back,
+        ``last_finite`` (and ``last_logits``) are then that step's, and
+        what it counted is added to ``step_counters``."""
+        trace = get_tracer()
         # every device->host read in ONE span of its own (same contract
         # as the dense engine): the logits probe must not be billed to
         # dispatch, or the dispatch-vs-readback split on the timeline
         # reads as ~0 exactly when capture_logits is on
         with trace.span("serve/engine.decode_fetch"):
-            if logits is not None:
-                self.last_logits = np.asarray(logits)
-            self.last_finite = np.asarray(finite)
-            toks = np.asarray(toks)
-            if counts:
+            if step.logits is not None:
+                self.last_logits = np.asarray(step.logits)
+            self.last_finite = np.asarray(step.finite)
+            toks = np.asarray(step.tokens)
+            if step.counts is not None:
                 # what the model's step counted: its own reducer names the
                 # report's fields, the engine only adds
-                step = self.model.count_step(
-                    np.asarray(counts[0]), np.asarray(pos)[self._live])
-                for name, value in step.items():
+                counted = self.model.count_step(
+                    np.asarray(step.counts), step.live_pos)
+                for name, value in counted.items():
                     self.step_counters[name] = (
                         self.step_counters.get(name, 0) + value)
                 # the step's own addends at the step's time, so that a
                 # traced window can be counted by itself (recorded only
                 # while the tracer is on or a capture is live)
-                trace.event("serve/engine.step_counts", **step)
+                trace.event("serve/engine.step_counts", **counted)
             return toks
 
     # -- fault injection / quarantine hooks --------------------------------

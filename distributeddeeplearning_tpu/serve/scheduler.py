@@ -52,7 +52,26 @@ a ``jax.profiler`` capture is live): ``serve/poll``, ``serve/admission``
 (around ``serve/admit``), ``serve/prefill_chunk``, ``serve/decode_step``
 (the engine splits it into ``serve/engine.decode_upload`` /
 ``decode_dispatch`` / ``decode_fetch``) and ``serve/emit``; request-scoped
-spans and the lifecycle events carry ``uid`` and ``trace``.
+spans and the lifecycle events carry ``uid`` and ``trace``.  With a step
+in flight (below) a turn's ``decode_upload`` and ``decode_dispatch`` launch
+step n+1 and its ``decode_fetch`` and ``serve/emit`` then read and stream
+step n, the one dispatched a turn earlier: ``decode_fetch`` is how long the
+host still waits for the device once its own turn is done.
+
+One decode step in flight: where the engine offers a step's two halves
+(``decode_dispatch`` / ``decode_fetch``: the paged engine) and the run has
+neither a speculative decoder nor a fault plan that acts on decode steps,
+a turn is poll, admission, one prefill chunk, the dispatch of step n+1,
+and only THEN the read and emit of step n, so the host's turn runs beside
+the device's step and not after it.  Step n+1 takes each lane's token from
+where step n left it on the device; what else it needs the host knows by
+count: a lane whose budget ends with a step in flight takes no row in the
+next one, a lane that ends on EOS (or is cancelled, or expires) is found a
+read late and its one extra row is computed and dropped
+(``ServeReport.decode_rows_wasted``).  Every dispatched step is read first
+(a turn that dispatches nothing) before a preemption, a host-tier spill or
+a quarantine's scrub.  The dense engine and engines without the halves run
+the serial turn: dispatch and read back to back, as before.
 
 Resilience (PR 7) — the scheduler is also the serving stack's blast-radius
 boundary; every failure mode is scoped to ONE request, never the batch:
@@ -188,6 +207,19 @@ class _SlotState:
     ttft_s: float
     queue_wait_s: float = 0.0
     deadline_at: Optional[float] = None  # absolute perf_counter deadline
+    # rows of this lane in steps that were dispatched and not read yet:
+    # its newest token is then on the device, its count known without it
+    unread: int = 0
+
+
+@dataclasses.dataclass
+class _Step:
+    """A decode step the loop dispatched and has not read yet."""
+
+    number: int  # 1-based, the fault clock
+    lanes: Dict[int, _SlotState]  # the lanes that took a row in it
+    # what engine.decode_fetch reads (with no step in flight: the result)
+    handle: Any
 
 
 @dataclasses.dataclass
@@ -369,6 +401,13 @@ class ServeReport:
     ring_positions_capacity_sum: int = 0
     slot_state_bytes_held_sum: int = 0
     kv_bytes_held_sum: int = 0
+    # one decode step in flight: the steps dispatched while the step
+    # before was still unread (of ``decode_steps``; 0 on the serial turn),
+    # and the rows computed for a lane that had ended by the time its step
+    # was read (EOS, cancellation, deadline or quarantine found a read
+    # late): computed, never streamed
+    decode_steps_overlapped: int = 0
+    decode_rows_wasted: int = 0
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -673,6 +712,16 @@ class ContinuousBatchingScheduler:
         of its own.
         """
         engine.tier_inflight()  # retire landed prefetches
+        want = self._tier_pressure(engine, hbm_ledger)
+        if not want:
+            return 0
+        return engine.spill_cold_pages(want)
+
+    @staticmethod
+    def _tier_pressure(engine, hbm_ledger) -> int:
+        """Pages the pump would spill right now (0: no pressure): host
+        counters and the ledger forecast only.  The loop asks before the
+        pump does, to read a step in flight ahead of the spill."""
         target = max(1, engine.num_pages // 8)  # free-page cushion
         pressure = engine.allocator.free_pages < target
         if (
@@ -687,8 +736,7 @@ class ContinuousBatchingScheduler:
             )
         if not pressure:
             return 0
-        want = min(8, max(1, target - engine.allocator.free_pages))
-        return engine.spill_cold_pages(want)
+        return min(8, max(1, target - engine.allocator.free_pages))
 
     def run(
         self,
@@ -770,6 +818,27 @@ class ContinuousBatchingScheduler:
         spec = self.spec_decoder
         dlen_buf = np.zeros(slots, np.int32)
         keep_buf = np.zeros(slots, np.int32)
+        # decode steps in flight: what this run can hold, not a knob.  One
+        # where the engine's ``decode`` is its two halves back to back
+        # (``decode_dispatch``, ``decode_fetch``) and nothing acts between
+        # a step's dispatch and its read (a speculative decoder's verify
+        # and rollback, a fault plan's poison or stall); otherwise none:
+        # ``engine.decode``, the serial turn
+        depth = int(
+            getattr(engine.decode, "is_its_two_halves", False)
+            and spec is None
+            and not (plan and plan.acts_on_decode_steps())
+        )
+        unread: deque = deque()  # _Step, oldest first; depth + 1 at most
+        # per lane of the step being built: the host sets its token / it
+        # takes a row (see PagedInferenceEngine.decode_dispatch)
+        fresh_buf = np.ones(slots, bool)
+        rows_buf = np.zeros(slots, bool)
+        # quarantined lanes waiting for the steps in flight to be read
+        # before their scrub: (slot, state, error)
+        condemned: List = []
+        steps_overlapped = 0
+        rows_wasted = 0
         # bounded when result_window is set (live mode) — see __init__.
         # Per-step timing/occupancy feed ONLY end-of-run aggregates, so
         # they stream into the obs histogram / running sums (O(1) memory
@@ -951,7 +1020,7 @@ class ContinuousBatchingScheduler:
                 tokens=len(m.preserved) + len(st.generated), ttft_s=st.ttft_s,
                 trace=st.req.trace_id,
             )
-            del active[slot]
+            active.pop(slot, None)  # a condemned lane has left it already
             release(slot)  # paged: pages back to the pool
             free.append(slot)
 
@@ -1341,7 +1410,7 @@ class ContinuousBatchingScheduler:
         # deadline/cancel sweeps cost one wall-clock read per loop only
         # when something can actually expire
         try:
-            while pending or active or prefilling or more:
+            while pending or active or prefilling or more or unread:
                 # loop liveness for the watchdog: a tick here means the host
                 # loop is advancing — a hung decode dispatch stops ticking.
                 # NOT armed until the first decode step has completed: the
@@ -1411,6 +1480,7 @@ class ContinuousBatchingScheduler:
                     self._pending_reload is not None
                     and not active
                     and not prefilling
+                    and not unread
                 ):
                     apply_reload = self._pending_reload
                     self._pending_reload = None
@@ -1442,13 +1512,36 @@ class ContinuousBatchingScheduler:
                             # release a finished request takes)
                             complete(slot, st, "deadline")
 
+                # With a step in flight, whatever takes a lane away by a
+                # road other than the step's own read, or wants the cache
+                # settled, waits for a turn that only reads: a cut (the
+                # victim's stream has to be whole before it is requeued), a
+                # host-tier spill, a quarantined lane's scrub.  Such a turn
+                # admits, prefills and dispatches nothing; the next one
+                # finds nothing unread and goes about it as the serial turn
+                # would.  Never taken where nothing is in flight.
+                settling = unread and (
+                    condemned
+                    or (
+                        pending and not draining
+                        and self._pending_reload is None
+                        and self._preemption_victim(
+                            active, self._class_rank[pending[0].priority]
+                        ) is not None
+                    )
+                    or (
+                        tier is not None
+                        and self._tier_pressure(engine, hbm_ledger) > 0
+                    )
+                )
+
                 # One span over the turn's whole admission block (entered only
                 # with a non-empty queue): slot-pressure preemption, the tier
                 # pump's page cushion, the ladder below, down to serve/admit.
                 # ``blocked`` names why the head stayed queued this turn — the
                 # first reason that held — for ServeReport.admission_turns.
                 blocked = None
-                if pending:
+                if pending and not settling:
                     admission_turns["queued"] += 1
                     admission = trace.span(
                         "serve/admission", pending=len(pending)
@@ -1458,7 +1551,9 @@ class ContinuousBatchingScheduler:
                 with admission:
                     # Admit prompts into free slots — mid-flight: slots
                     # released in the previous iteration take new work while
-                    # the rest decode on.  Paged engines additionally gate on
+                    # the rest decode on (none of it in a turn that only
+                    # reads: every condition below then asks for not
+                    # settling).  Paged engines additionally gate on
                     # free PAGES: a request that could strand mid-decode is
                     # left queued (backpressure) until completions free its
                     # reservation.
@@ -1471,7 +1566,7 @@ class ContinuousBatchingScheduler:
                     # below, where the blocked resource is known.
                     if (
                         pending and not free and not draining
-                        and self._pending_reload is None
+                        and self._pending_reload is None and not settling
                     ):
                         head_rank = self._class_rank.get(pending[0].priority)
                         if head_rank is not None:
@@ -1484,12 +1579,12 @@ class ContinuousBatchingScheduler:
                     # demoting the coldest reclaimable prefix pages — the
                     # designed D2H copy runs HERE, ahead of the ladder,
                     # instead of synchronously inside alloc's evict hook
-                    if tier is not None:
+                    if tier is not None and not settling:
                         self._tier_pump(engine, hbm_ledger)
 
                     hbm_committed = None  # ledger walk amortized per iteration
                     while (
-                        pending and not draining and free
+                        pending and not draining and free and not settling
                         # reload pending: hold admission so the active set
                         # drains to the idle barrier (queued requests are
                         # served by the NEW weights after the swap)
@@ -1664,7 +1759,10 @@ class ContinuousBatchingScheduler:
                             free.append(slot)
                             continue
                         activate(slot, req, budget, first, queue_wait)
-                    if blocked is None and pending and not free:
+                    if (
+                        blocked is None and pending and not free
+                        and not settling
+                    ):
                         blocked = "blocked_slots"
                     if blocked is not None:
                         admission_turns[blocked] += 1
@@ -1673,7 +1771,7 @@ class ContinuousBatchingScheduler:
                 # through to decode — the chunked-prefill interleave: running
                 # requests stall at most one chunk's compute per step, not a
                 # whole O(P²) prompt pass.
-                if prefilling:
+                if prefilling and not settling:
                     task, req, budget, queue_wait = prefilling[0]
                     m = meta[req.uid]
                     expired = (
@@ -1711,45 +1809,73 @@ class ContinuousBatchingScheduler:
                                     queue_wait,
                                 )
 
-                if not active:
+                if not active and not unread:
                     if more and not pending and not prefilling:
                         # idle live loop: nothing in flight, the source still
                         # open — back off so the poll doesn't busy-spin
                         time.sleep(0.001)
                     continue
 
-                if spec is not None:
-                    dlen_buf[:] = 0  # stale lanes must not draft
-                for slot, st in active.items():
-                    tokens_buf[slot] = st.generated[-1]
-                    pos_buf[slot] = st.next_pos
+                # Build the next step: every active lane takes a row unless
+                # it ends with a step already in flight, which is known by
+                # count (its budget, the cache's last position) without
+                # reading that step.  Such a lane is uploaded as a released
+                # slot is (rows_buf) until its release, at the read.  A lane
+                # with a row in flight takes its token from the device, any
+                # other from the host (fresh_buf).  No step is built in a
+                # turn that only reads, nor past step_cap.
+                lanes: Dict[int, _SlotState] = {}
+                if not settling and (
+                    self.step_cap is None
+                    or n_decode_steps + len(unread) < self.step_cap
+                ):
                     if spec is not None:
-                        # per-slot draft cap: emitted tokens (accepted +
-                        # bonus) never exceed the remaining budget, so
-                        # the verify write horizon stays inside the
-                        # worst-case page reservation made at admission,
-                        # and never walks off the position table.  0 =
-                        # this slot runs a plain decode step through the
-                        # verify program.
-                        dlen_buf[slot] = max(0, min(
-                            spec.draft_tokens,
-                            st.budget - len(st.generated) - 1,
-                            engine.max_seq - 1 - st.next_pos,
-                        ))
-                occ_sum += len(active) / slots
-                occ_n += 1
-                if kv_pages_held is not None:
-                    # positions written so far, per slot that holds pages
-                    written_pos = {
-                        slot: st.next_pos for slot, st in active.items()
-                    }
-                    for task, _req, _budget, _wait in prefilling:
-                        written_pos[task.slot] = task.offset
-                    reserved, written = kv_pages_held(written_pos)
-                    kv_reserved_sum += reserved
-                    kv_written_sum += written
-                decode_step = n_decode_steps + 1  # 1-based, the fault clock
-                if plan:
+                        dlen_buf[:] = 0  # stale lanes must not draft
+                    rows_buf[:] = False
+                    fresh_buf[:] = True
+                    for slot, st in active.items():
+                        if (
+                            len(st.generated) + st.unread >= st.budget
+                            or st.next_pos + st.unread >= engine.max_seq
+                        ):
+                            continue
+                        lanes[slot] = st
+                        rows_buf[slot] = True
+                        if st.unread:
+                            fresh_buf[slot] = False
+                        else:
+                            tokens_buf[slot] = st.generated[-1]
+                        pos_buf[slot] = st.next_pos + st.unread
+                        if spec is not None:
+                            # per-slot draft cap: emitted tokens (accepted +
+                            # bonus) never exceed the remaining budget, so
+                            # the verify write horizon stays inside the
+                            # worst-case page reservation made at admission,
+                            # and never walks off the position table.  0 =
+                            # this slot runs a plain decode step through the
+                            # verify program.
+                            dlen_buf[slot] = max(0, min(
+                                spec.draft_tokens,
+                                st.budget - len(st.generated) - 1,
+                                engine.max_seq - 1 - st.next_pos,
+                            ))
+                # 1-based, the fault clock; a step is numbered as dispatched
+                decode_step = n_decode_steps + len(unread) + 1
+                if lanes:
+                    occ_sum += len(lanes) / slots
+                    occ_n += 1
+                    if kv_pages_held is not None:
+                        # positions written so far, per slot that holds pages
+                        written_pos = {
+                            slot: st.next_pos + st.unread
+                            for slot, st in active.items()
+                        }
+                        for task, _req, _budget, _wait in prefilling:
+                            written_pos[task.slot] = task.offset
+                        reserved, written = kv_pages_held(written_pos)
+                        kv_reserved_sum += reserved
+                        kv_written_sum += written
+                if plan and lanes:
                     stall = plan.take_decode_stall(decode_step)
                     if stall is not None:
                         time.sleep(stall)  # injected hung-dispatch (watchdog)
@@ -1757,6 +1883,8 @@ class ContinuousBatchingScheduler:
                         # victim needs >= 1 decode-written position so the NaN
                         # lands in a private (never prefix-shared) cache
                         # region — no eligible slot leaves the fault armed
+                        # (a plan with this fault runs with no step in
+                        # flight, so the cache is settled here)
                         victim = min(
                             (
                                 s for s, st in active.items()
@@ -1775,39 +1903,67 @@ class ContinuousBatchingScheduler:
                                     "would be a silent no-op"
                                 )
                             poison(victim, active[victim].next_pos - 1)
+
+                # One span a turn: the dispatch of the step just built, then
+                # the read of the oldest unread step once more are out than
+                # the run keeps in flight (with none in flight: the step just
+                # dispatched; with one: the step before it), or whenever
+                # nothing went out this turn.
                 t0 = time.perf_counter()
-                res = None
-                try:
-                    if spec is not None:
-                        # draft K + verify K+1 in one batched call; one
-                        # readback carries tokens/acceptance/finiteness
-                        with trace.span(
-                            "serve/spec_step", active=len(active)
-                        ):
-                            res = spec.step(tokens_buf, pos_buf, dlen_buf)
-                        out = None
-                    else:
-                        with trace.span(
-                            "serve/decode_step", active=len(active)
-                        ):
-                            out = engine.decode(tokens_buf, pos_buf)
-                except Exception as exc:  # noqa: BLE001
-                    # The decode step failed batch-wide through no fault of
-                    # any single request (a hung collective, a dispatch bug):
-                    # requeue every active slot ONCE — prompt extended by the
-                    # tokens already generated, so a greedy retry continues
-                    # bit-identically — instead of failing them all.  A slot
-                    # whose retry budget is spent completes "error".
-                    for slot, st in list(active.items()):
-                        requeue_active(
-                            slot, st,
-                            f"decode failed: {type(exc).__name__}: {exc}",
-                        )
-                    continue
-                step_wall = time.perf_counter() - t0  # host math only
-                step_hist.record(step_wall)
-                decode_wall += step_wall
-                n_decode_steps += 1
+                step = out = res = failure = None
+                with trace.span(
+                    "serve/spec_step" if spec is not None
+                    else "serve/decode_step",
+                    active=len(active),
+                ):
+                    if lanes:
+                        try:
+                            if spec is not None:
+                                # draft K + verify K+1 in one batched call;
+                                # one readback carries tokens/acceptance/
+                                # finiteness
+                                handle = spec.step(
+                                    tokens_buf, pos_buf, dlen_buf
+                                )
+                            elif depth:
+                                handle = engine.decode_dispatch(
+                                    tokens_buf, pos_buf, fresh_buf, rows_buf
+                                )
+                            else:
+                                handle = engine.decode(tokens_buf, pos_buf)
+                        except Exception as exc:  # noqa: BLE001
+                            failure = exc
+                        else:
+                            if unread:
+                                steps_overlapped += 1
+                            for st in lanes.values():
+                                st.unread += 1
+                            unread.append(_Step(decode_step, lanes, handle))
+                    if unread and (
+                        len(unread) > depth or not lanes
+                        or failure is not None
+                    ):
+                        step = unread.popleft()
+                        try:
+                            if depth:
+                                out = engine.decode_fetch(step.handle)
+                            elif spec is not None:
+                                res = step.handle
+                            else:
+                                out = step.handle
+                        except Exception as exc:  # noqa: BLE001
+                            # what the steps behind it computed rests on it
+                            failure, step = exc, None
+                            unread.clear()
+                if step is None and failure is None:
+                    continue  # a step went out and none was due to be read
+
+                if step is not None:
+                    step_wall = time.perf_counter() - t0  # host math only
+                    step_hist.record(step_wall)
+                    decode_wall += step_wall
+                    n_decode_steps += 1
+                    decode_step = step.number
                 if res is not None:
                     draft_hist.record(res.draft_s)
                     verify_hist.record(res.verify_s)
@@ -1817,7 +1973,10 @@ class ContinuousBatchingScheduler:
 
                 # the rest of the turn in one span: quarantine check,
                 # on_token, budget/EOS, finish, release
-                with trace.span("serve/emit", active=len(active)):
+                with (
+                    trace.span("serve/emit", active=len(active))
+                    if step is not None else no_span
+                ):
                     # NaN quarantine: engines report per-slot logit finiteness
                     # from the SAME jitted step (no extra sync).  A poisoned slot
                     # is scrubbed and fails alone — the batch decodes on.
@@ -1831,18 +1990,17 @@ class ContinuousBatchingScheduler:
                     # after that would zero the dustbin instead of the freed
                     # pages' rejected-draft tail
                     finished: List = []
-                    for slot, st in list(active.items()):
+                    read_lanes = step.lanes if step is not None else {}
+                    for slot, st in read_lanes.items():
+                        st.unread -= 1
+                        if active.get(slot) is not st:
+                            # the lane ended while this row was in flight
+                            # (EOS found a read late, a cancel, a deadline,
+                            # a quarantine): computed, and dropped here
+                            rows_wasted += 1
+                            continue
                         if finite is not None and not finite[slot]:
                             quarantined += 1
-                            scrub = getattr(engine, "scrub_slot", None)
-                            if scrub is not None:
-                                # zero the slot's decode-written region so the
-                                # NaN cannot leak to the next occupant via the
-                                # 0-weight * NaN-value softmax path (in spec
-                                # mode this also covers the step's whole
-                                # draft/verify write horizon, so the batched
-                                # rollback can skip the slot)
-                                scrub(slot, len(st.req.prompt))
                             trace.event(
                                 "serve/request_quarantined", uid=st.req.uid,
                                 step=decode_step, trace=st.req.trace_id,
@@ -1855,8 +2013,12 @@ class ContinuousBatchingScheduler:
                                 "decode_quarantine", registry=get_registry(),
                                 uid=st.req.uid, step=decode_step,
                             )
-                            finished.append((
-                                slot, st, "error",
+                            # out of the batch now (a row of it still in
+                            # flight is dropped at its read), scrubbed and
+                            # failed below once nothing is unread
+                            del active[slot]
+                            condemned.append((
+                                slot, st,
                                 "non-finite logits (quarantined at decode "
                                 f"step {decode_step})",
                             ))
@@ -1887,6 +2049,19 @@ class ContinuousBatchingScheduler:
                         reason = self._finished(st)
                         if reason is not None:
                             finished.append((slot, st, reason, None))
+                    if condemned and not unread:
+                        scrub = getattr(engine, "scrub_slot", None)
+                        for slot, st, err in condemned:
+                            if scrub is not None:
+                                # zero the slot's decode-written region so the
+                                # NaN cannot leak to the next occupant via the
+                                # 0-weight * NaN-value softmax path (in spec
+                                # mode this also covers the step's whole
+                                # draft/verify write horizon, so the batched
+                                # rollback can skip the slot)
+                                scrub(slot, len(st.req.prompt))
+                            finished.append((slot, st, "error", err))
+                        condemned.clear()
                     if res is not None and rollback_needed:
                         # ONE batched dispatch zeroes every slot's rejected
                         # tail (positions >= pos + keep) — the jitted form of
@@ -1897,15 +2072,32 @@ class ContinuousBatchingScheduler:
                     for slot, st, reason, err in finished:
                         complete(slot, st, reason, error=err)
 
-                    if on_step is not None:
+                    if step is not None and on_step is not None:
                         on_step(decode_step)
 
-                    if (
-                        self.step_cap is not None
-                        and n_decode_steps >= self.step_cap
-                    ):
-                        capped = True
-                        break
+                if failure is not None:
+                    # A decode step failed batch-wide through no fault of
+                    # any single request (a hung collective, a dispatch bug):
+                    # requeue every active slot ONCE — prompt extended by the
+                    # tokens already generated, so a greedy retry continues
+                    # bit-identically — instead of failing them all.  A slot
+                    # whose retry budget is spent completes "error".  What an
+                    # older step in flight had computed was read and emitted
+                    # above; what the failed read left behind it is dropped.
+                    for slot, st in list(active.items()):
+                        requeue_active(
+                            slot, st,
+                            "decode failed: "
+                            f"{type(failure).__name__}: {failure}",
+                        )
+                    continue
+
+                if (
+                    self.step_cap is not None
+                    and n_decode_steps >= self.step_cap
+                ):
+                    capped = True
+                    break
 
             if capped:
                 # deadline semantics for smoke runs: everything still running
@@ -1952,6 +2144,8 @@ class ContinuousBatchingScheduler:
             admission_turns=admission_turns,
             kv_pages_reserved_sum=kv_reserved_sum,
             kv_pages_written_sum=kv_written_sum,
+            decode_steps_overlapped=steps_overlapped,
+            decode_rows_wasted=rows_wasted,
             **{
                 name: value - counted_before.get(name, 0)
                 for name, value in getattr(

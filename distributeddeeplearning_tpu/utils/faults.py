@@ -351,6 +351,15 @@ class FaultPlan:
         (first decode step >= the armed step)."""
         return self._take_at_or_after("replica_death", step) is not None
 
+    def acts_on_decode_steps(self) -> bool:
+        """True while a ``decode_nan`` or ``decode_stall`` is still armed:
+        the scheduler then keeps no decode step in flight, so the fault
+        lands between one step's read and the next step's dispatch."""
+        return any(
+            s.kind in ("decode_nan", "decode_stall") and not s.fired
+            for s in self.specs
+        )
+
     def take_decode_stall(self, step: int) -> Optional[float]:
         """``decode_stall``: seconds to sleep before this decode step's
         dispatch, or None."""

@@ -12,7 +12,8 @@ The load-bearing guarantees:
 - the event list is bounded and counts what it dropped;
 - one ``scheduler.run`` over a paged engine yields every span of the turn,
   properly nested, and the spans that idle time is attributed to (the leaves
-  of the issue's table: ``span_reduce.TURN_SPANS``) cover the run's wall;
+  of the issue's table: ``span_reduce.TURN_SPANS``) cover the run's wall
+  (85% of it since ISSUE 37 took the wait for the step out of the wall);
 - ``ServeReport.admission_turns`` names the resource that blocked the head,
   and the page sums obey written <= reserved (0 on the dense engine);
 - each of the seven per-layer readers under ``benchmarks/layer_metrics``
@@ -281,7 +282,16 @@ def test_one_run_yields_every_span_of_the_turn_nested_and_covering(tracer):
     assert {(s["args"]["uid"], s["args"]["trace"]) for s in admits} == {
         (f"r{i}", f"t{i}") for i in range(8)}
     steps = [s for s in spans if s["name"] == "serve/decode_step"]
-    assert len(steps) == report.decode_steps
+    # one of each engine span a decode step; one serve/decode_step a turn,
+    # which holds a step's dispatch and the read of the step before it (a
+    # step dispatched with nothing in flight is read a turn later, in a
+    # span that holds the read alone)
+    for name in ("serve/engine.decode_upload", "serve/engine.decode_dispatch",
+                 "serve/engine.decode_fetch"):
+        assert sum(s["name"] == name for s in spans) == report.decode_steps
+    assert report.decode_steps_overlapped >= 0.9 * report.decode_steps
+    assert len(steps) == (
+        2 * report.decode_steps - report.decode_steps_overlapped)
     assert all(s["args"]["active"] >= 1 for s in steps)
     # none of the seven lies inside another, so their lengths add up
     assert not any(
@@ -290,7 +300,11 @@ def test_one_run_yields_every_span_of_the_turn_nested_and_covering(tracer):
         if up is not None and spans[i]["name"] in span_reduce.TURN_SPANS)
     covered = 1e-6 * sum(
         s["dur"] for s in spans if s["name"] in span_reduce.TURN_SPANS)
-    assert covered >= 0.9 * wall, (covered, wall)
+    # what no span covers (the sweeps, building the step) is the same tenth
+    # of a millisecond a turn it was; the wait for the step inside
+    # decode_fetch, which was half the wall and wholly covered, now runs
+    # beside the host's turn, so the share stands on a shorter wall
+    assert covered >= 0.85 * wall, (covered, wall)
 
 
 def test_dense_engine_decode_has_the_three_engine_spans(params, tracer):
