@@ -224,6 +224,8 @@ OBS_HOT_REGIONS: Tuple[HotRegion, ...] = (
     HotRegion(
         name="obs-nullspan-exit", module=_OBS_TRACE, qualname="_NullSpan.__exit__"
     ),
+    # runs at every collection, in whatever the process was doing
+    HotRegion(name="obs-gc-hook", module=_OBS_TRACE, qualname="_on_gc"),
     HotRegion(
         name="obs-histogram-record",
         module=_OBS_REG,

@@ -100,6 +100,9 @@ class _RecorderSpan:
             (t1 - self._t0) * 1e6, self._args,
         )
 
+    def note(self, **args) -> None:
+        self._args.update(args)
+
 
 class FlightRecorder:
     """Bounded ring of recent spans / events / metric deltas.

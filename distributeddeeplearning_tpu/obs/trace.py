@@ -41,6 +41,7 @@ Usage::
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import sys
@@ -70,8 +71,9 @@ __all__ = [
 PROCESS_RECORDER: Any = object()
 
 #: how many events a tracer keeps (oldest dropped, counted in ``dropped``):
-#: about a quarter of an hour of the serve loop's ~10 spans a turn, well
-#: under 100 MB of event dicts at worst
+#: at the serve loop's ~12 spans a turn, and a ``host/gc`` a collection,
+#: a minute or two of a busy loop at 9 ms a turn; well under 100 MB of
+#: event dicts at worst
 MAX_EVENTS = 131_072
 
 
@@ -89,6 +91,9 @@ class _NullSpan:
         return self
 
     def __exit__(self, *exc) -> None:
+        return None
+
+    def note(self, **args: Any) -> None:
         return None
 
 
@@ -130,6 +135,11 @@ class _Span:
         self._t0 = time.perf_counter()
         tracer._depth_local.depth = getattr(tracer._depth_local, "depth", 0) + 1
         return self
+
+    def note(self, **args: Any) -> None:
+        """Args the span records when it closes, beside (or over) those it
+        opened with; the capture's host plane has only those."""
+        self._args.update(args)
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
@@ -251,6 +261,12 @@ class Tracer:
     @property
     def enabled(self) -> bool:
         return self._enabled
+
+    @property
+    def recording(self) -> bool:
+        """Whether a span opened now lands in ``events``: enabled, or a
+        ``jax.profiler`` capture is live."""
+        return self._enabled or self._capture_live()
 
     @property
     def epoch_unix_s(self) -> float:
@@ -390,6 +406,45 @@ class Tracer:
 # dumps freeze.
 
 _TRACER = Tracer(enabled=False, recorder=PROCESS_RECORDER)
+
+
+#: the collection in progress, as a span (collections never overlap)
+_gc_span: Optional[_Span] = None
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    """The ``gc.callbacks`` hook: while the process tracer records, each
+    collection is a ``host/gc`` span with its ``generation`` and the objects
+    it ``collected``; otherwise it returns at its first test.  It never
+    binds the capture probe (a collection can land inside the import of jax
+    itself), so a capture is followed once a span has bound it."""
+    global _gc_span
+    tracer = _TRACER
+    if phase == "start":
+        if not (
+            isinstance(tracer, Tracer)
+            and (
+                tracer._enabled
+                or (tracer._annotate and tracer._capture_live())
+            )
+        ):
+            return
+        span = _Span(tracer, "host/gc", "host",
+                     {"generation": info["generation"]})
+        span.__enter__()
+        _gc_span = span
+    elif _gc_span is not None:
+        span, _gc_span = _gc_span, None
+        span.note(collected=info["collected"])
+        span.__exit__(None, None, None)
+
+
+# one hook a process: a reload of this module replaces its predecessor's
+gc.callbacks[:] = [
+    hook for hook in gc.callbacks
+    if (getattr(hook, "__module__", None), getattr(hook, "__name__", None))
+    != (__name__, "_on_gc")
+] + [_on_gc]
 
 
 def get_tracer() -> Tracer:

@@ -681,7 +681,10 @@ class InferenceEngine:
                 self._cache, k, v, jnp.int32(slot)
             )
         tok = self._sample_jit(last, jnp.int32(self._next_step()))
-        return int(np.asarray(tok)[0])
+        # the scheduler's ``serve/prefill`` around this names the request;
+        # duck-typed engines share this signature, so no uid comes in
+        with get_tracer().span("serve/engine.first_token_fetch", slot=slot):
+            return int(np.asarray(tok)[0])
 
     def decode(self, tokens: np.ndarray, pos: np.ndarray) -> np.ndarray:
         """One decode step for every slot: ``tokens[i]`` at ``pos[i]`` →
@@ -694,17 +697,18 @@ class InferenceEngine:
         # readback — the scheduler's one designed sync (it needs the token
         # ids), where the host waits out the device's step
         trace = get_tracer()
-        with trace.span("serve/engine.decode_upload"):
+        step = self._next_step()
+        with trace.span("serve/engine.decode_upload", step=step):
             args = (
                 self.params,
                 self._cache,
                 jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(pos, jnp.int32),
-                jnp.int32(self._next_step()),
+                jnp.int32(step),
             )
-        with trace.span("serve/engine.decode_dispatch"):
+        with trace.span("serve/engine.decode_dispatch", step=step):
             toks, finite, self._cache = self._decode_jit(*args)
-        with trace.span("serve/engine.decode_fetch"):
+        with trace.span("serve/engine.decode_fetch", step=step):
             # the finite readback piggybacks on the token sync (same
             # computation, already materialized)
             self.last_finite = np.asarray(finite)
@@ -771,14 +775,15 @@ class PrefillTask:
     decode steps, so a long prompt never stalls running requests for its
     full O(P²) pass."""
 
-    __slots__ = ("slot", "prompt", "pages", "offset", "shared_tokens")
+    __slots__ = ("slot", "prompt", "pages", "offset", "shared_tokens", "uid")
 
-    def __init__(self, slot, prompt, pages, offset, shared_tokens):
+    def __init__(self, slot, prompt, pages, offset, shared_tokens, uid=None):
         self.slot = slot
         self.prompt = list(prompt)
         self.pages = pages  # this sequence's block table (physical ids)
         self.offset = offset  # tokens already in cache (shared + chunked)
         self.shared_tokens = shared_tokens  # prefix-cache hit length
+        self.uid = uid  # the request's, for the spans
 
     @property
     def done(self) -> bool:
@@ -787,11 +792,13 @@ class PrefillTask:
 
 class DispatchedStep(NamedTuple):
     """A decode step that was launched and not read yet
-    (``PagedInferenceEngine.decode_dispatch`` -> ``decode_fetch``): its
-    outputs as they lie on the device (``logits`` and ``counts`` None where
-    the step returns none), and the live lanes' positions for what the
-    model's step counted."""
+    (``PagedInferenceEngine.decode_dispatch`` -> ``decode_fetch``): the
+    engine's number for it (``step`` on its spans), its outputs as they lie
+    on the device (``logits`` and ``counts`` None where the step returns
+    none), and the live lanes' positions for what the model's step
+    counted."""
 
+    step: int
     tokens: Any
     finite: Any
     logits: Any
@@ -1265,10 +1272,12 @@ class PagedInferenceEngine:
         return tuple(prompt[: n_pages * self.page_size])
 
     def prefill_begin(
-        self, slot: int, prompt: Sequence[int], max_new_tokens: int
+        self, slot: int, prompt: Sequence[int], max_new_tokens: int,
+        uid: Optional[str] = None,
     ) -> PrefillTask:
         """Allocate the sequence's pages (prefix-cache hits first), map
-        the slot's block table, and return the chunking task."""
+        the slot's block table, and return the chunking task (``uid``, the
+        request's, names its spans)."""
         length = len(prompt)
         if not length:
             raise ValueError("empty prompt")
@@ -1333,7 +1342,7 @@ class PagedInferenceEngine:
         self.prompt_tokens_seen += length
         self.prefix_hit_tokens += offset
         self.prefix_hit_tokens_host += restored * ps
-        return PrefillTask(slot, prompt, pages, offset, offset)
+        return PrefillTask(slot, prompt, pages, offset, offset, uid)
 
     def prefill_step(self, task: PrefillTask) -> Optional[int]:
         """Run ONE chunk of ``task``'s prompt; returns the first sampled
@@ -1404,7 +1413,12 @@ class PagedInferenceEngine:
         if self.capture_logits:
             self.last_prefill_logits = np.asarray(last)[0]
         tok = self._sample_jit(last, jnp.int32(self._next_step()))
-        return int(np.asarray(tok)[0])
+        # the turn's one blocking read besides decode_fetch: the chunk
+        # program and the sampler, behind whatever step is in flight
+        with get_tracer().span(
+            "serve/engine.first_token_fetch", uid=task.uid, slot=task.slot
+        ):
+            return int(np.asarray(tok)[0])
 
     def prefill(
         self,
@@ -1458,7 +1472,8 @@ class PagedInferenceEngine:
         a reference to the caller's buffers: they may change as soon as
         this returns."""
         trace = get_tracer()
-        with trace.span("serve/engine.decode_upload"):
+        step = self._next_step()
+        with trace.span("serve/engine.decode_upload", step=step):
             tables, live = self._block_tables, self._live
             if rows is not None:
                 tables = np.where(rows[:, None], tables, SCRATCH_PAGE)
@@ -1473,7 +1488,7 @@ class PagedInferenceEngine:
                 jnp.asarray(np.array(tokens, np.int32)),
                 jnp.asarray(np.array(pos, np.int32)),
                 jnp.asarray(np.array(tables)),
-                jnp.int32(self._next_step()),
+                jnp.int32(step),
                 self._last_toks,
                 jnp.asarray(
                     np.ones(self.batch_slots, bool) if fresh is None
@@ -1484,7 +1499,7 @@ class PagedInferenceEngine:
                 if self.model.slot_state else ()
             )
         logits = None
-        with trace.span("serve/engine.decode_dispatch"):
+        with trace.span("serve/engine.decode_dispatch", step=step):
             if self.capture_logits:
                 toks, logits, finite, *counts, self._cache = (
                     self._decode_jit(*args, True, *slot_args)
@@ -1495,7 +1510,7 @@ class PagedInferenceEngine:
                 )
         self._last_toks = toks
         return DispatchedStep(
-            toks, finite, logits, counts[0] if counts else None,
+            step, toks, finite, logits, counts[0] if counts else None,
             np.asarray(pos)[live] if counts else None,
         )
 
@@ -1508,7 +1523,7 @@ class PagedInferenceEngine:
         # as the dense engine): the logits probe must not be billed to
         # dispatch, or the dispatch-vs-readback split on the timeline
         # reads as ~0 exactly when capture_logits is on
-        with trace.span("serve/engine.decode_fetch"):
+        with trace.span("serve/engine.decode_fetch", step=step.step):
             if step.logits is not None:
                 self.last_logits = np.asarray(step.logits)
             self.last_finite = np.asarray(step.finite)

@@ -46,12 +46,20 @@ What it records is the whole point of serving benchmarks:
 
 Every percentile block routes through the obs histogram
 (:func:`..obs.registry.summarize`), and aggregate counters/histograms
-feed the process metrics registry once per ``run()``.  One loop turn is
+feed the process metrics registry once per ``run()``.  The loop is
 covered by spans on the obs tracer (no-ops unless a driver enabled it or
-a ``jax.profiler`` capture is live): ``serve/poll``, ``serve/admission``
-(around ``serve/admit``), ``serve/prefill_chunk``, ``serve/decode_step``
-(the engine splits it into ``serve/engine.decode_upload`` /
-``decode_dispatch`` / ``decode_fetch``) and ``serve/emit``; request-scoped
+a ``jax.profiler`` capture is live; the flight recorder's ring gets them
+regardless).  At depth 0 ``serve/turn`` (each turn that holds a request;
+``step``, ``live``) and ``serve/idle`` (each stretch that holds none)
+tile it; inside a turn: ``serve/poll``, ``serve/admission`` (around
+``serve/admit``), ``serve/prefill_chunk`` (around the engine's
+``chunk_dispatch`` and, after a final chunk, ``first_token_fetch``; the
+dense engine's ``serve/prefill`` likewise), ``serve/decode_step`` (the
+engine splits it into ``serve/engine.decode_upload`` / ``decode_dispatch``
+/ ``decode_fetch``, each with the engine's ``step`` number) and
+``serve/emit``; what is left of the turn is the loop's own bookkeeping:
+the sweeps, the settle decision, building the step.  ``host/gc`` spans
+(:mod:`..obs.trace`) land wherever a collection does.  Request-scoped
 spans and the lifecycle events carry ``uid`` and ``trace``.  With a step
 in flight (below) a turn's ``decode_upload`` and ``decode_dispatch`` launch
 step n+1 and its ``decode_fetch`` and ``serve/emit`` then read and stream
@@ -220,6 +228,71 @@ class _Step:
     lanes: Dict[int, _SlotState]  # the lanes that took a row in it
     # what engine.decode_fetch reads (with no step in flight: the result)
     handle: Any
+
+
+class _LoopSpans:
+    """The loop's own spans, entered and left by hand so that the loop body
+    keeps its indentation.  ``serve/turn`` covers each turn that holds a
+    request, its self time the loop's own bookkeeping (``step``: the loop's
+    number for the decode step it dispatched, or -1; ``live``: that step's
+    lanes).  ``serve/idle`` covers each stretch that holds none, from its
+    first poll to the end of the poll that brings a request: one span a
+    stretch, not one a poll, so the flight recorder's ring keeps what came
+    before.  Together they tile the loop at depth 0."""
+
+    def __init__(self, trace):
+        self._trace = trace
+        self._turn = self._idle = None
+        self._idle_records = False
+
+    def top(self, holding: bool) -> None:
+        """A turn begins: the last one ends."""
+        self._end_turn()
+        if holding:
+            self._end_idle()
+            self._begin_turn()
+        elif self._idle is None:
+            self._begin_idle()
+
+    def polled(self, holding: bool, more: bool) -> None:
+        """After the turn's poll.  A stretch that began before a capture
+        did records nothing, so it begins again once the tracer records."""
+        if self._idle is None:
+            return
+        if holding or not more:
+            self._end_idle()
+            if holding:
+                self._begin_turn()
+        elif not self._idle_records and self._trace.recording:
+            self._end_idle()
+            self._begin_idle()
+
+    def dispatched(self, step: int, live: int) -> None:
+        self._turn.note(step=step, live=live)
+
+    def close(self) -> None:
+        self._end_turn()
+        self._end_idle()
+
+    def _begin_turn(self) -> None:
+        # the args are known only later: noted, they skip the capture's
+        # host plane, which gets its args at the span's start
+        self._turn = self._trace.span("serve/turn").__enter__()
+        self._turn.note(step=-1, live=0)
+
+    def _end_turn(self) -> None:
+        if self._turn is not None:
+            self._turn.__exit__(None, None, None)
+            self._turn = None
+
+    def _begin_idle(self) -> None:
+        self._idle = self._trace.span("serve/idle").__enter__()
+        self._idle_records = self._trace.recording
+
+    def _end_idle(self) -> None:
+        if self._idle is not None:
+            self._idle.__exit__(None, None, None)
+            self._idle = None
 
 
 @dataclasses.dataclass
@@ -1407,10 +1480,12 @@ class ContinuousBatchingScheduler:
         # live mode: with a poll source the loop stays alive while idle
         # until the source closes (poll() -> None) or a drain begins
         more = poll is not None
+        loop_spans = _LoopSpans(trace)
         # deadline/cancel sweeps cost one wall-clock read per loop only
         # when something can actually expire
         try:
             while pending or active or prefilling or more or unread:
+                loop_spans.top(bool(active or pending or prefilling or unread))
                 # loop liveness for the watchdog: a tick here means the host
                 # loop is advancing — a hung decode dispatch stops ticking.
                 # NOT armed until the first decode step has completed: the
@@ -1462,6 +1537,8 @@ class ContinuousBatchingScheduler:
                         release(task.slot)
                         free.append(task.slot)
                         fail_request(req, None, queue_wait, reason="preempted")
+                loop_spans.polled(
+                    bool(active or pending or prefilling or unread), more)
                 if draining and pending:
                     # NOT one-shot: a decode exception mid-drain requeues
                     # its surviving slots here, and with admission gated
@@ -1738,7 +1815,7 @@ class ContinuousBatchingScheduler:
                                     trace=req.trace_id,
                                 ):
                                     task = engine.prefill_begin(
-                                        slot, req.prompt, budget
+                                        slot, req.prompt, budget, uid=req.uid
                                     )
                             except Exception as exc:  # noqa: BLE001 — per-request
                                 release(slot)
@@ -1939,6 +2016,7 @@ class ContinuousBatchingScheduler:
                             for st in lanes.values():
                                 st.unread += 1
                             unread.append(_Step(decode_step, lanes, handle))
+                            loop_spans.dispatched(decode_step, len(lanes))
                     if unread and (
                         len(unread) > depth or not lanes
                         or failure is not None
@@ -2112,6 +2190,7 @@ class ContinuousBatchingScheduler:
                 while pending:
                     fail_request(pending.popleft(), None, reason="cancelled")
         finally:
+            loop_spans.close()
             # the watchdog must die with the loop: a lingering armed
             # watchdog would hard-exit the process long after run()
             # returned (or raised)
