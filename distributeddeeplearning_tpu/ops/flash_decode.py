@@ -10,10 +10,12 @@ into the tile:
 - **Pallas kernel** (:func:`_pallas_attention`): grid ``(slots,
   history_blocks)`` with the history dimension sequential and every head
   of the page handled per step — an online-softmax split-K over the
-  slot's pages.  Block tables ride as
-  scalar prefetch (``pltpu.PrefetchScalarGridSpec``) so each K/V tile's
+  slot's pages; the one-row float32 decode takes all heads in one
+  block-diagonal matmul a page (:func:`_kernel_decode`).  Block tables ride
+  as scalar prefetch (``pltpu.PrefetchScalarGridSpec``) so each K/V tile's
   ``BlockSpec`` index_map resolves ``logical page j -> physical page
-  tables[b, j]`` and the pages stream HBM→VMEM **directly** — the gathered
+  tables[b, j]`` (pages past the slot's newest position repeat its last
+  one and fetch nothing) and the pages stream HBM→VMEM **directly** — the gathered
   ``[b, s, h, hd]`` history never exists as an array.  Int8 pools
   dequantize *inside the tile*: ``kf = k_int8 · scale[pos, head]`` at
   ``[page_size, hd]`` granularity, so f32 history never exists in HBM at
@@ -212,6 +214,107 @@ def _kernel(tables_ref, maxpos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
             o_ref[0, hh] = (acc_ref[hh] / l).astype(o_ref.dtype)
 
 
+def _kernel_decode(tables_ref, maxpos_ref, q_ref, k_ref, v_ref, o_ref,
+                   qbd_ref, m_ref, l_ref, acc_ref, *, block: int,
+                   num_heads: int, hd: int):
+    """One (slot, history-block) grid step of the one-row float32 decode:
+    ALL heads in one matmul a page.
+
+    ``q_ref`` [1, 1, h * hd] is the slot's query as one row, heads side by
+    side as the pool folds them.  At the slot's first step it is laid out
+    block-diagonally in ``qbd_ref`` [h, h * hd] (row ``hh`` carries head
+    ``hh`` in lanes ``[hh * hd, (hh + 1) * hd)``, zeros elsewhere), so one
+    dot against the folded page ``k_ref`` [1, block, h * hd] gives every
+    head's scores [h, block], one softmax fold runs over them, and one dot
+    with ``v_ref`` [1, block, h * hd] adds into ``acc`` [h, h * hd], whose
+    diagonal blocks are the heads' contexts.  The zeros add exact zeros, so
+    each head's sums are the ones :func:`_kernel`'s per-head dots make.
+    ``o_ref`` [1, 1, h * hd], laid out as ``q_ref``; the row sits at the
+    slot's position ``maxpos_ref[b]``, which is also the block-skip bound."""
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        rows = jax.lax.broadcasted_iota(jnp.int32, qbd_ref.shape, 0) * hd
+        lanes = jax.lax.broadcasted_iota(jnp.int32, qbd_ref.shape, 1)
+        own = (lanes >= rows) & (lanes < rows + hd)
+        qbd_ref[...] = jnp.where(own, q_ref[0], 0.0)
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    maxpos = maxpos_ref[b]
+
+    @pl.when(j * block <= maxpos)
+    def _compute():
+        s = jax.lax.dot_general(
+            qbd_ref[...], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) / math.sqrt(hd)  # [h, block]
+        cols = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(cols <= maxpos, s, NEG_BIG)
+        _fold_block(s, v_ref[0], m_ref, l_ref, acc_ref)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        for hh in range(num_heads):  # once a slot: each head's own block
+            lanes = slice(hh * hd, (hh + 1) * hd)
+            l = jnp.maximum(l_ref[hh:hh + 1, :1], 1e-30)
+            o_ref[0, :, lanes] = (acc_ref[hh:hh + 1, lanes] / l).astype(
+                o_ref.dtype)
+
+
+def _page_map(block: int):
+    """The index map of a pool page (and its scales) for grid step ``(bb,
+    j)``: blocks past the slot's newest position ``mp[bb]`` repeat its last
+    page, so they fetch nothing, as they compute nothing."""
+
+    def page(bb, j, tbl, mp):
+        return (tbl[bb, jnp.minimum(j, mp[bb] // block)], 0, 0)
+
+    return page
+
+
+def _compiler_params():
+    if _use_interpret():
+        return None
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+
+
+def _pallas_decode_f32(q, k_l, v_l, tables, pos, *, block: int):
+    """The one-row float32 form: ``q`` [b, h, hd] at ``pos`` [b] against the
+    folded pages through ``tables`` [b, nb].  Returns [b, h, hd] f32."""
+    b, h, hd = q.shape
+    row = pl.BlockSpec((1, 1, h * hd), lambda bb, j, tbl, mp: (bb, 0, 0))
+    page = _page_map(block)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, tables.shape[1]),
+        in_specs=[
+            row,
+            pl.BlockSpec((1, block, h * hd), page),
+            pl.BlockSpec((1, block, h * hd), page),
+        ],
+        out_specs=row,
+        scratch_shapes=[
+            pltpu.VMEM((h, h * hd), jnp.float32),
+            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((h, h * hd), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel_decode, block=block, num_heads=h, hd=hd),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, h * hd), jnp.float32),
+        compiler_params=_compiler_params(),
+        interpret=_use_interpret(),
+        name=_kernel_name(1, False, False),
+    )(tables, pos, q.reshape(b, 1, h * hd), k_l, v_l)
+    return out.reshape(b, h, hd)
+
+
 def _kernel_name(nq: int, quantized: bool, overlay: bool) -> str:
     """Stable ``pallas_call`` name per kernel form, so a trace reduction
     finds the decode / chunk-prefill / verify kernels after a refactor."""
@@ -237,12 +340,21 @@ def _pallas_attention(
     ``v_l`` [P, block, h * hd] (heads folded, ``h`` and ``hd`` taken from
     ``q4``; scales ``k_s``/``v_s`` [P, block, h]) addressed through
     ``tables`` [b, nb]; ``posmat`` [b, nq] per-query visibility.  Returns
-    [b, nq, h, hd] f32.
+    [b, nq, h, hd] f32.  The one-row float32 form (decode) runs
+    :func:`_kernel_decode`, all heads in one matmul a page; the others
+    (chunk prefill and verify, whose 64 rows would make that 32 times the
+    multiply-adds, and the int8 pool with its overlay) run :func:`_kernel`,
+    a head at a time.
     """
     b, nq, h, hd = q4.shape
     nb = tables.shape[1]
     quantized = k_s is not None
     overlay = k_own is not None
+    posmat = posmat.astype(jnp.int32)
+    if nq == 1 and not quantized and not overlay:
+        return _pallas_decode_f32(
+            q4[:, 0], k_l, v_l, tables, posmat[:, 0], block=block
+        )[:, None]
     if overlay and nq != 1:
         # the in-kernel own-position select reads the slot's single
         # position — the single-token decode contract; a multi-query
@@ -262,13 +374,9 @@ def _pallas_attention(
     head_major = pl.BlockSpec(
         (1, h, nq, hd), lambda bb, j, tbl, mp: (bb, 0, 0, 0)
     )
-    page_spec = pl.BlockSpec(
-        (1, block, h * hd), lambda bb, j, tbl, mp: (tbl[bb, j], 0, 0)
-    )
+    page_spec = pl.BlockSpec((1, block, h * hd), _page_map(block))
     if quantized:
-        scale_spec = pl.BlockSpec(
-            (1, block, h), lambda bb, j, tbl, mp: (tbl[bb, j], 0, 0)
-        )
+        scale_spec = pl.BlockSpec((1, block, h), _page_map(block))
     else:
         scale_spec = pl.BlockSpec(
             (1, 1, 1), lambda bb, j, tbl, mp: (0, 0, 0)
@@ -301,17 +409,11 @@ def _pallas_attention(
             pltpu.VMEM((h, nq, hd), jnp.float32),
         ],
     )
-    compiler_params = None
-    if not _use_interpret():
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        )
-    posmat = posmat.astype(jnp.int32)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, nq, hd), jnp.float32),
-        compiler_params=compiler_params,
+        compiler_params=_compiler_params(),
         interpret=_use_interpret(),
         name=_kernel_name(nq, quantized, overlay),
     )(

@@ -150,6 +150,62 @@ def test_pallas_kernel_matches_gather_reference(params, layout, dtype):
     )
 
 
+@pytest.mark.parametrize("heads", [32, 12])
+def test_pallas_decode_reads_nothing_past_each_slot(heads):
+    """The one-row f32 kernel at the served widths (heads of 64, pages of
+    64): four slots at positions 0, 63, 64 and 300, the pages each reserves
+    past its position filled with NaN, and a lane whose row is all scratch
+    at a stale position.  Against the gather reference over the same pool
+    with those pages finite (it gathers them, and ``0 × NaN`` would carry
+    into its sums): every slot to f32 tolerance, no NaN in any lane."""
+    hd, page, nb = 64, 64, 8
+    pos = np.asarray([0, 63, 64, 300, 200], np.int32)
+    tables = np.zeros((len(pos), nb), np.int32)  # page 0: the scratch page
+    past = []
+    for lane, p in enumerate(pos[:4]):
+        tables[lane] = 1 + lane * nb + np.arange(nb)
+        past += list(tables[lane, p // page + 1:])
+    rng = np.random.default_rng(11)
+    k, v = (
+        rng.normal(size=(1 + 4 * nb, page, heads * hd)).astype(np.float32)
+        for _ in range(2)
+    )
+    q = jnp.asarray(rng.normal(size=(len(pos), heads, hd)).astype(np.float32))
+    ref = fd._gather_decode_paged(
+        q, jnp.asarray(k), jnp.asarray(v), None, None, q, q,
+        jnp.asarray(pos), jnp.asarray(tables), page_size=page,
+    )
+    k[past], v[past] = np.nan, np.nan
+    got = np.asarray(fd.decode_attention_paged(
+        q, jnp.asarray(k), jnp.asarray(v), None, None, q, q,
+        jnp.asarray(pos), jnp.asarray(tables), page_size=page,
+        kernel="pallas",
+    ))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[:4], np.asarray(ref)[:4], atol=5e-5,
+                               rtol=1e-5)
+
+
+def test_page_map_repeats_the_last_page_past_the_newest_position():
+    """A grid step past a slot's newest position names that slot's last
+    page, so the pipeline fetches nothing new for it."""
+    page = fd._page_map(64)
+    tables = jnp.arange(20, dtype=jnp.int32).reshape(2, 10)
+    newest = jnp.asarray([130, 639], jnp.int32)
+    assert [int(page(0, j, tables, newest)[0]) for j in range(10)] == [
+        0, 1, 2, 2, 2, 2, 2, 2, 2, 2
+    ]
+    assert [int(page(1, j, tables, newest)[0]) for j in range(10)] == list(
+        range(10, 20)
+    )
+
+
+def test_decode_kernel_name_is_what_the_roofline_reads():
+    """``flash_decode_roofline`` sums the trace events named
+    ``flash_decode_decode_*``: the one-row f32 form keeps its name."""
+    assert fd._kernel_name(1, False, False) == "flash_decode_decode_f32"
+
+
 @pytest.mark.parametrize("layout", ["dense", "paged"])
 def test_int8_flash_scale_exact_vs_gather(params, layout):
     """Int8 scale-exactness: fed the SAME quantized cache state, the
