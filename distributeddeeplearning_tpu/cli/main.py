@@ -373,12 +373,14 @@ def build_parser() -> argparse.ArgumentParser:
     serve_p.add_argument("--model-config", default=None, metavar="JSON",
                          help="serve a decoder that mixes window and full "
                          "attention layers, or gated short-convolution and "
-                         "attention layers, with sparse experts "
+                         "attention layers, with sparse experts, or "
+                         "state-space (Mamba) and attention layers "
                          "(models/hybrid_moe_transformer.py), at the sizes "
                          "of this configuration file under the model's "
                          "published keys (benchmarks/configs/"
-                         "mimo-v2-flash.json, lfm2-8b-a1b.json and "
-                         "trinity-mini.json are three), seeded weights; needs "
+                         "mimo-v2-flash.json, lfm2-8b-a1b.json, "
+                         "trinity-mini.json and jamba2-3b.json are four), "
+                         "seeded weights; needs "
                          "--kv-layout paged --no-prefix-cache, and refuses "
                          "the int8 pool, the host tier, --speculative and "
                          "--replicas > 1")

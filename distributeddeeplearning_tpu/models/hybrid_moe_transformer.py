@@ -21,7 +21,9 @@ is **one block function**, :func:`block`, which takes the layer's index and
 the layer's *callback*: an attention layer's says how its cache is written
 and read (``attention=``), a convolution layer's hands it the inputs just
 before the rows it was given and keeps the newest for the next call
-(``state=``).  The two forwards the paged engine needs,
+(``state=``), and a state-space layer's does that and also runs its scan from
+the state it keeps (``state=``, a :class:`MambaState`).  The two forwards the
+paged engine needs,
 :func:`forward_prefill_chunk` and :func:`forward_decode`, and the
 cache-free :func:`forward` differ only in the callbacks they hand it.
 
@@ -39,21 +41,30 @@ A convolution layer owns ``[slots, (conv_taps - 1) * d]``: each slot's last
 inputs, oldest first, folded into the minor axis like the KV heads.  That
 state has no positions to mask by, so a chunk at offset 0 starts from zeros
 whatever the slot holds.
+
+A fourth kind, the **state-space** (Mamba) layer (:func:`mamba_mixer`),
+carries more than a few inputs: beside its convolution's last ``d_conv - 1``
+inputs, a state of ``d_state x d_inner`` values a slot that a selective scan
+updates at every row (:mod:`ops.ssm_scan` over a chunk's rows, one step a lane
+in a decode step).  Its cache is two per-slot leaves: the convolution's last
+inputs, laid out as a convolution layer's, and the state in float32, folded
+``[slots, d_state * d_inner]``; both start from zeros at offset 0.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from distributeddeeplearning_tpu.ops import flash_decode as _fd
+from distributeddeeplearning_tpu.ops.ssm_scan import ssm_scan_chunk
 
 PyTree = Any
-FULL, WINDOW, CONV = 0, 1, 2
+FULL, WINDOW, CONV, MAMBA = 0, 1, 2, 3
 DENSE, EXPERTS = 0, 1
 #: what the expert layers count in a decode step (the order of the step's
 #: small integer vector): pairs (token, expert) the router made over live
@@ -62,6 +73,26 @@ DENSE, EXPERTS = 0, 1
 EXPERT_COUNTS = ("pairs_total", "pairs_here", "tokens_max", "experts_touched")
 #: a layer's norm scales (made 1, where every other weight is drawn)
 NORM_SCALES = ("ln1", "ln2", "q_norm", "k_norm", "ln1_post", "ln2_post")
+#: a state-space mixer's leaves that are made, not drawn (:func:`mamba_init`)
+MAMBA_MADE = ("dt_norm", "b_norm", "c_norm", "A_log", "D", "b_dt")
+
+
+@dataclasses.dataclass(frozen=True)
+class SsmSpec:
+    """A state-space (MAMBA) layer's numbers."""
+
+    d_inner: int            # channels of the scan (``expand * d_model``)
+    d_state: int            # states a channel
+    d_conv: int             # taps of the causal depthwise convolution
+    dt_rank: int            # width of the step's low-rank projection
+    conv_bias: bool = True
+    #: a learned RMS norm over each of the step, B and C before they are used
+    inner_norms: bool = True
+
+    def __post_init__(self):
+        if min(self.d_inner, self.d_state, self.dt_rank) < 1 or self.d_conv < 2:
+            raise ValueError(f"a state-space layer needs positive widths and "
+                             f"d_conv of 2 or more, got {self}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,7 +114,7 @@ class HybridSpec:
     sink_window: bool
     value_scale: float
     eps: float
-    attn_kinds: Tuple[int, ...]     # per layer: FULL, WINDOW or CONV
+    attn_kinds: Tuple[int, ...]     # per layer: FULL, WINDOW, CONV or MAMBA
     ffn_kinds: Tuple[int, ...]      # per layer: DENSE or EXPERTS
     d_ff: int                       # dense FFN width
     d_expert: int                   # one expert's width
@@ -113,6 +144,8 @@ class HybridSpec:
     rotate_full: bool = True
     #: multiplies the embedding's rows as they enter the residual stream
     embed_scale: float = 1.0
+    #: the state-space layers' numbers (where any layer is MAMBA)
+    ssm: Optional[SsmSpec] = None
 
     def __post_init__(self):
         if len(self.attn_kinds) != len(self.ffn_kinds):
@@ -121,6 +154,8 @@ class HybridSpec:
             raise ValueError("window layers need a window of 1 or more")
         if CONV in self.attn_kinds and self.conv_taps < 2:
             raise ValueError("convolution layers need conv_taps of 2 or more")
+        if MAMBA in self.attn_kinds and self.ssm is None:
+            raise ValueError("state-space layers need an SsmSpec (ssm=)")
         for name, kv in (("full", self.kv_heads_full),
                          ("window", self.kv_heads_window)):
             if self.num_q_heads % kv:
@@ -170,9 +205,12 @@ def spec_from_config(cfg: dict) -> HybridSpec:
     their ids and ``n_routed_experts_published`` the router's width (both
     default to "all of them").  A configuration under the ``lfm2_moe`` keys
     (``layer_types``) is read by :func:`_spec_from_lfm2_moe`, one whose
-    ``model_type`` is ``afmoe`` by :func:`_spec_from_afmoe`."""
+    ``model_type`` is ``afmoe`` by :func:`_spec_from_afmoe`, ``jamba`` by
+    :func:`_spec_from_jamba`."""
     if cfg.get("model_type") == "afmoe":
         return _spec_from_afmoe(cfg)
+    if cfg.get("model_type") == "jamba":
+        return _spec_from_jamba(cfg)
     if "layer_types" in cfg:
         return _spec_from_lfm2_moe(cfg)
     held = cfg.get("experts_held")
@@ -326,12 +364,82 @@ def _spec_from_afmoe(cfg: dict) -> HybridSpec:
     )
 
 
+def _spec_from_jamba(cfg: dict) -> HybridSpec:
+    """The ``jamba`` keys: layer ``i`` is attention where ``i mod
+    attn_layer_period == attn_layer_offset`` and a state-space (Mamba) layer
+    elsewhere, as the published modelling code derives it; attention has
+    ``num_key_value_heads`` KV heads of ``hidden_size / num_attention_heads``
+    and no position signal at all; every FFN is dense (``num_experts`` 1, so
+    ``expert_layer_period`` routes nothing); the Mamba mixer has
+    ``mamba_expand * hidden_size`` channels, ``mamba_d_state`` states a
+    channel, ``mamba_d_conv`` taps (with a bias where ``mamba_conv_bias``)
+    and a step of rank ``mamba_dt_rank``, and norms the step, B and C (the
+    published code; no key says so)."""
+    if cfg.get("num_experts", 1) != 1 or cfg.get("mamba_proj_bias"):
+        raise ValueError("jamba: only num_experts 1 and mamba_proj_bias false "
+                         "are written")
+    layers, d = cfg["num_hidden_layers"], cfg["hidden_size"]
+    heads, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    period, offset = cfg["attn_layer_period"], cfg["attn_layer_offset"]
+    return HybridSpec(
+        vocab_size=cfg["vocab_size"],
+        d_model=d,
+        num_q_heads=heads,
+        k_dim=d // heads,
+        v_dim=d // heads,
+        rotary_dim=0,
+        kv_heads_full=kv,
+        kv_heads_window=kv,
+        window=0,
+        theta_full=0.0,
+        theta_window=0.0,
+        sink_full=False,
+        sink_window=False,
+        value_scale=1.0,
+        eps=float(cfg["rms_norm_eps"]),
+        attn_kinds=tuple(FULL if i % period == offset else MAMBA
+                         for i in range(layers)),
+        ffn_kinds=(DENSE,) * layers,
+        d_ff=cfg["intermediate_size"],
+        d_expert=0,
+        num_experts=1,
+        experts_per_token=1,
+        experts_held=(),
+        tied_head=bool(cfg["tie_word_embeddings"]),
+        rotate_full=False,
+        ssm=SsmSpec(d_inner=cfg["mamba_expand"] * d,
+                    d_state=cfg["mamba_d_state"], d_conv=cfg["mamba_d_conv"],
+                    dt_rank=cfg["mamba_dt_rank"],
+                    conv_bias=bool(cfg["mamba_conv_bias"])),
+    )
+
+
+def mamba_shapes(spec: HybridSpec) -> Dict[str, tuple]:
+    """name -> shape of a state-space mixer's weights: ``w_in`` (the ``x``
+    and ``z`` halves side by side), ``conv_w`` (a row a tap, oldest first),
+    ``conv_b``, ``w_x`` (the step's, B's and C's columns side by side), the
+    three inner norms, ``w_dt``, ``b_dt``, ``A_log`` (state-major: ``[d_state,
+    d_inner]``), ``D`` and ``w_out``."""
+    m, d = spec.ssm, spec.d_model
+    di, n, r = m.d_inner, m.d_state, m.dt_rank
+    out = {"w_in": (d, 2 * di), "conv_w": (m.d_conv, di)}
+    if m.conv_bias:
+        out["conv_b"] = (di,)
+    out["w_x"] = (di, r + 2 * n)
+    if m.inner_norms:
+        out.update(dt_norm=(r,), b_norm=(n,), c_norm=(n,))
+    out.update(w_dt=(r, di), b_dt=(di,), A_log=(n, di), D=(di,), w_out=(di, d))
+    return out
+
+
 def layer_shapes(spec: HybridSpec, layer: int) -> Dict[str, tuple]:
     """name -> shape of one layer's weights."""
     kind, d = spec.attn_kinds[layer], spec.d_model
     if kind == CONV:
         out = {"ln1": (d,), "w_in": (d, 3 * d), "conv_w": (spec.conv_taps, d),
                "w_out": (d, d), "ln2": (d,)}
+    elif kind == MAMBA:
+        out = {"ln1": (d,), **mamba_shapes(spec), "ln2": (d,)}
     else:
         hq, hkv = spec.num_q_heads, spec.kv_heads(kind)
         out = {
@@ -361,12 +469,31 @@ def layer_shapes(spec: HybridSpec, layer: int) -> Dict[str, tuple]:
     return out
 
 
+def mamba_init(key, name: str, shape: tuple, dtype):
+    """A state-space mixer's leaves that are not plain normals
+    (:data:`MAMBA_MADE`), made as the published Mamba code makes them: the
+    inner norms' scales are 1, ``A_log`` row ``n`` is ``log(n + 1)`` (so ``A
+    = -exp(A_log)`` runs -1 .. -d_state), ``D`` is 1, and ``b_dt`` is the
+    inverse softplus of steps drawn log-uniform in [0.001, 0.1]."""
+    if name in ("dt_norm", "b_norm", "c_norm", "D"):
+        return jnp.ones(shape, dtype)
+    if name == "A_log":
+        n = jnp.arange(1, shape[0] + 1, dtype=jnp.float32)
+        return jnp.broadcast_to(jnp.log(n)[:, None], shape).astype(dtype)
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                    np.log(1e-3), np.log(1e-1)))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
 def init_params(rng: jax.Array, spec: HybridSpec, *, dtype=jnp.float32,
                 std: float = 0.02) -> PyTree:
     """Seeded weights: normal(0, std), norm scales 1, the sink logits and
     the router's correction bias small normals (so that a test can tell
-    selecting by ``s + b`` from weighing by ``s``)."""
-    keys = iter(jax.random.split(rng, 2 + 16 * spec.num_layers))
+    selecting by ``s + b`` from weighing by ``s``); a state-space mixer's
+    :data:`MAMBA_MADE` leaves as :func:`mamba_init` makes them."""
+    # a split of 16 a layer keeps the other kinds' seeded weights as they were
+    per_layer = 20 if MAMBA in spec.attn_kinds else 16
+    keys = iter(jax.random.split(rng, 2 + per_layer * spec.num_layers))
 
     def nrm(shape):
         return (jax.random.normal(next(keys), shape, jnp.float32) * std
@@ -376,8 +503,11 @@ def init_params(rng: jax.Array, spec: HybridSpec, *, dtype=jnp.float32,
     for layer in range(spec.num_layers):
         p = {}
         for name, shape in layer_shapes(spec, layer).items():
-            p[name] = (jnp.ones(shape, dtype) if name in NORM_SCALES
-                       else nrm(shape))
+            if spec.attn_kinds[layer] == MAMBA and name in MAMBA_MADE:
+                p[name] = mamba_init(next(keys), name, shape, dtype)
+            else:
+                p[name] = (jnp.ones(shape, dtype) if name in NORM_SCALES
+                           else nrm(shape))
         layers.append(p)
     params = {
         "embed": nrm((spec.vocab_size, spec.d_model)),
@@ -565,6 +695,52 @@ def short_conv(p, h, *, spec: HybridSpec, state):
     return _mm(bcx[:, d:2 * d] * c, p["w_out"])
 
 
+class MambaState(NamedTuple):
+    """A state-space layer's callback, the caller's (:func:`mamba_mixer`).
+
+    ``conv(x [T, di]) -> (x_{t-d_conv+1}, ..., x_t)``, each [T, di], as a
+    convolution layer's ``state``: the inputs before the rows given come from
+    wherever the caller keeps them, and the newest are left there.
+    ``scan(dt, x, b, c, a, d) -> y [T, di]`` runs the recurrence over the
+    rows given (``dt``, ``x`` [T, di], ``b``, ``c`` [T, d_state], ``a``
+    [d_state, di], ``d`` [di], float32) from the state the caller keeps,
+    and leaves the new state there."""
+
+    conv: Callable
+    scan: Callable
+
+
+def mamba_mixer(p, h, *, spec: HybridSpec, state: MambaState):
+    """A state-space (Mamba) layer's operator on the normed rows ``h`` [T,
+    d]: ``[x | z] = h W_in``; ``x~ = silu(sum_j w_j x_{t-d_conv+1+j} + b)``
+    (depthwise, causal, inputs before the sequence's start are 0); ``[dt | B
+    | C] = x~ W_x``, each normed where the spec says so; ``dt =
+    softplus(dt W_dt + b_dt)``; ``A = -exp(A_log)``; the selective scan
+    ``s_t = exp(dt_t A) s_{t-1} + (dt_t x~_t) B_t``, ``y_t = s_t C_t + D
+    x~_t``; ``(y * silu(z)) W_out``.  ``x`` is rounded to the weights' dtype
+    before it is summed or stored, as :func:`short_conv` rounds ``u``."""
+    m = spec.ssm
+    di, n, r = m.d_inner, m.d_state, m.dt_rank
+    f32 = jnp.float32
+    xz = _mm(h, p["w_in"])
+    x = xz[:, :di].astype(p["w_in"].dtype)
+    w = p["conv_w"].astype(f32)
+    c = sum(w[j] * tap.astype(f32) for j, tap in enumerate(state.conv(x)))
+    if m.conv_bias:
+        c = c + p["conv_b"].astype(f32)
+    xc = jax.nn.silu(c)
+    dbc = _mm(xc, p["w_x"])
+    dt, b, cc = dbc[:, :r], dbc[:, r:r + n], dbc[:, r + n:]
+    if m.inner_norms:
+        dt = rms_norm(dt, p["dt_norm"], spec.eps)
+        b = rms_norm(b, p["b_norm"], spec.eps)
+        cc = rms_norm(cc, p["c_norm"], spec.eps)
+    dt = jax.nn.softplus(_mm(dt, p["w_dt"]) + p["b_dt"].astype(f32))
+    y = state.scan(dt, xc, b, cc, -jnp.exp(p["A_log"].astype(f32)),
+                   p["D"].astype(f32))
+    return _mm(y * jax.nn.silu(xz[:, di:]), p["w_out"])
+
+
 def block(p, x, positions, *, spec: HybridSpec, layer: int, attention=None,
           state=None, live=None):
     """One layer on ``x`` [T, d] (float32 residual) at ``positions`` [T].
@@ -572,8 +748,9 @@ def block(p, x, positions, *, spec: HybridSpec, layer: int, attention=None,
     An attention layer takes ``attention(q [T, Hq, dk], k [T, Hkv, dk], v
     [T, Hkv, dv], sink)`` -> ``ctx [T, Hq, dv]``, the caller's: it writes
     the layer's cache and reads what the queries may see.  A convolution
-    layer takes ``state`` (:func:`short_conv`).  Returns ``(x, counts)``
-    with ``counts`` the expert layer's (None in a dense layer)."""
+    layer takes ``state`` (:func:`short_conv`), a state-space layer a
+    :class:`MambaState`.  Returns ``(x, counts)`` with ``counts`` the expert
+    layer's (None in a dense layer)."""
     kind = spec.attn_kinds[layer]
 
     def joined(x, y, norm):
@@ -584,6 +761,8 @@ def block(p, x, positions, *, spec: HybridSpec, layer: int, attention=None,
     h = rms_norm(x, p["ln1"], spec.eps)
     if kind == CONV:
         y = short_conv(p, h, spec=spec, state=state)
+    elif kind == MAMBA:
+        y = mamba_mixer(p, h, spec=spec, state=state)
     else:
         y = attention_op(p, h, positions, spec=spec, kind=kind,
                          attention=attention)
@@ -614,11 +793,13 @@ def _logits(params, x, spec):
 
 def _stack(spec, params, x, positions, callback_of, live=None):
     """The layers in their published order; ``callback_of(layer)`` gives
-    each its callback (an attention layer's ``attention``, a convolution
-    layer's ``state``).  Returns (x, the expert layers' counts summed)."""
+    each its callback (an attention layer's ``attention``, a convolution or
+    state-space layer's ``state``).  Returns (x, the expert layers' counts
+    summed)."""
     total = jnp.zeros(len(EXPERT_COUNTS), jnp.int32)
     for layer, p in enumerate(params["layers"]):
-        name = "state" if spec.attn_kinds[layer] == CONV else "attention"
+        name = ("state" if spec.attn_kinds[layer] in (CONV, MAMBA)
+                else "attention")
         x, counts = block(p, x, positions, spec=spec, layer=layer, live=live,
                           **{name: callback_of(layer)})
         if counts is not None:
@@ -631,21 +812,29 @@ def _stack(spec, params, x, positions, callback_of, live=None):
 
 def forward(params, tokens, *, spec: HybridSpec):
     """Next-token logits [s, vocab] of one sequence ``tokens`` [s], with
-    no cache: every attention layer attends over the sequence itself, and a
+    no cache: every attention layer attends over the sequence itself, a
     convolution layer's earlier inputs are the rows above (zeros before
-    the sequence's start)."""
+    the sequence's start), and a state-space layer scans from a zero
+    state."""
     s = tokens.shape[0]
     pos = jnp.arange(s)
     causal = pos[None, :] <= pos[:, None]
     if WINDOW in spec.attn_kinds:
         in_window = pos[None, :] > pos[:, None] - spec.window
 
+    def rows_above(taps):
+        return lambda u: tuple(jnp.pad(u, ((back, 0), (0, 0)))[:s]
+                               for back in range(taps - 1, -1, -1))
+
+    def scan_from_zero(dt, x, b, c, a, d):
+        return ssm_scan_chunk(dt, x, b, c, a, d, jnp.zeros_like(a))[0]
+
     def callback_of(layer):
         kind = spec.attn_kinds[layer]
         if kind == CONV:
-            return lambda u: tuple(
-                jnp.pad(u, ((back, 0), (0, 0)))[:s]
-                for back in range(spec.conv_taps - 1, -1, -1))
+            return rows_above(spec.conv_taps)
+        if kind == MAMBA:
+            return MambaState(rows_above(spec.ssm.d_conv), scan_from_zero)
         visible = causal & in_window if kind == WINDOW else causal
         return lambda q, k, v, sink: attend(q, k, v, visible, sink)
 
@@ -670,8 +859,10 @@ def forward_decode(params, token, cache, pos, block_tables, live, *,
                    spec: HybridSpec, page_size: int, kernel: str = "gather"):
     """One token for every slot: ``token``/``pos`` [B], ``block_tables``
     [B, nb] (full layers' pages), ``live`` [B] bool (a lane that is not
-    live writes to the scratch page and leaves its ring and its
-    convolution state as they were, and its token is routed to no expert).
+    live writes to the scratch page and leaves its ring, its convolution
+    state and its scan's state as they were, and its token is routed to no
+    expert).  A state-space layer takes one step of its recurrence for
+    every lane, in XLA (elementwise over ``[B, d_state, d_inner]``).
     Returns ``(logits [B, vocab] float32,
     cache, counts)`` with ``counts`` the :data:`EXPERT_COUNTS` over the
     step's expert layers (int32 [4])."""
@@ -687,19 +878,32 @@ def forward_decode(params, token, cache, pos, block_tables, live, *,
     def callback_of(layer):
         kind, i = spec.attn_kinds[layer], spec.index_in_kind(layer)
 
-        def conv(u):
+        def conv(u, leaf="conv_state", taps=spec.conv_taps):
             # each lane's earlier inputs are its slot's state, which then
             # moves on by one (a lane that is not live keeps its own)
-            held = cache["conv_state"][i]
+            held = cache[leaf][i]
             d = u.shape[1]
             u = u.astype(held.dtype)
             moved = jnp.concatenate([held[:, d:], u], axis=1)
-            cache["conv_state"][i] = jnp.where(live[:, None], moved, held)
+            cache[leaf][i] = jnp.where(live[:, None], moved, held)
             return tuple(held[:, j * d:(j + 1) * d]
-                         for j in range(spec.conv_taps - 1)) + (u,)
+                         for j in range(taps - 1)) + (u,)
+
+        def scan(dt, x, b, c, a, d):
+            # one step a lane from its slot's state [B, d_state * d_inner]
+            held = cache["ssm_state"][i]
+            s = held.reshape(B, *a.shape)
+            s = (jnp.exp(dt[:, None, :] * a) * s
+                 + (dt * x)[:, None, :] * b[:, :, None])
+            cache["ssm_state"][i] = jnp.where(live[:, None], s.reshape(B, -1),
+                                              held)
+            return (s * c[:, :, None]).sum(1) + d * x
 
         if kind == CONV:
             return conv
+        if kind == MAMBA:
+            return MambaState(
+                lambda x: conv(x, "mamba_conv_state", spec.ssm.d_conv), scan)
 
         def full(q, k, v, sink):
             k_pool = cache["k_full"][i].at[page, off].set(k.reshape(B, -1))
@@ -746,8 +950,13 @@ def forward_prefill_chunk(params, tokens, cache, block_table, offset, slot,
     layer starts from the slot's state (from zeros where ``offset`` is 0:
     a sequence's first chunk, whatever the slot's last occupant left) and
     leaves the inputs of the chunk's last ``conv_taps - 1`` real positions
-    there.  Returns ``(logits [1, 1, vocab] of the last real position,
-    cache)``: the one row a serving engine samples from."""
+    there.  A state-space layer does the same with its convolution's inputs
+    and scans the chunk's rows from the slot's state (zeros at offset 0)
+    with :func:`ops.ssm_scan.ssm_scan_chunk`; the rows that pad the chunk
+    take a step of 0, which leaves the state as it was, so what is stored is
+    the state after the last real row.  Returns ``(logits [1, 1, vocab] of
+    the last real position, cache)``: the one row a serving engine samples
+    from."""
     b, C = tokens.shape
     if b != 1:
         raise ValueError(f"chunked prefill is per-sequence, got batch {b}")
@@ -774,20 +983,31 @@ def forward_prefill_chunk(params, tokens, cache, block_table, offset, slot,
     def callback_of(layer):
         kind, i = spec.attn_kinds[layer], spec.index_in_kind(layer)
 
-        def conv(u):
-            leaf = cache["conv_state"][i]
-            before = spec.conv_taps - 1
+        def conv(u, name="conv_state", taps=spec.conv_taps):
+            leaf = cache[name][i]
+            before = taps - 1
             u = u.astype(leaf.dtype)
             held = jnp.where(offset > 0, leaf[slot], 0).reshape(before, -1)
             rows = jnp.concatenate([held, u], axis=0)  # row r: chunk row r - before
             # the last real rows (with fewer than `before` of them, what
             # was held moves up) stay for the next chunk or decode step
-            cache["conv_state"][i] = leaf.at[slot].set(
+            cache[name][i] = leaf.at[slot].set(
                 jax.lax.dynamic_slice_in_dim(rows, real, before).reshape(-1))
-            return tuple(rows[j:j + C] for j in range(spec.conv_taps))
+            return tuple(rows[j:j + C] for j in range(taps))
+
+        def scan(dt, x, b, c, a, d):
+            leaf = cache["ssm_state"][i]
+            s0 = jnp.where(offset > 0, leaf[slot], 0).reshape(a.shape)
+            dt = jnp.where((jnp.arange(C) < real)[:, None], dt, 0.0)
+            y, s = ssm_scan_chunk(dt, x, b, c, a, d, s0)
+            cache["ssm_state"][i] = leaf.at[slot].set(s.reshape(-1))
+            return y
 
         if kind == CONV:
             return conv
+        if kind == MAMBA:
+            return MambaState(
+                lambda x: conv(x, "mamba_conv_state", spec.ssm.d_conv), scan)
 
         def full(q, k, v, sink):
             k_pool = cache["k_full"][i].at[pages, offs].set(k.reshape(C, -1))

@@ -1370,7 +1370,8 @@ class PagedInferenceEngine:
             if self.model.slot_state else ()
         )
         with get_tracer().span(
-            "serve/engine.chunk_dispatch", chunk=C, offset=task.offset
+            "serve/engine.chunk_dispatch", chunk=C, real=real,
+            offset=task.offset
         ):
             logits, self._cache = self._chunk_jit(
                 self.params,

@@ -249,11 +249,16 @@ def slot_state_bytes(cache: Cache) -> int:
 # buffer is updated in place on its own).  A gated short-convolution layer
 # has no K/V at all: what it carries of a sequence is the last few inputs of
 # its convolution, ``conv_positions`` rows of the model's width a slot,
-# folded into the minor axis like the heads (oldest first).
+# folded into the minor axis like the heads (oldest first).  A state-space
+# (Mamba) layer carries the same of its own convolution (``d_conv - 1``
+# inputs of ``d_inner``) and its scan's state, ``d_state x d_inner`` values
+# in float32 whatever the cache's dtype (a decode step would otherwise round
+# a state carried over tens of thousands of positions), folded state-major.
 # --------------------------------------------------------------------------
 
 #: the per-slot leaves of such a cache (every other leaf is paged)
-RING_LEAVES = ("k_win", "v_win", "conv_state")
+RING_LEAVES = ("k_win", "v_win", "conv_state", "mamba_conv_state",
+               "ssm_state")
 
 
 def init_hybrid_cache(
@@ -271,6 +276,10 @@ def init_hybrid_cache(
     conv_layers: int = 0,
     conv_positions: int = 0,
     d_model: int = 0,
+    ssm_layers: int = 0,
+    ssm_conv_positions: int = 0,
+    d_inner: int = 0,
+    d_state: int = 0,
     dtype: Any = jnp.bfloat16,
 ) -> Cache:
     """``{"k_full", "v_full"}``: per full layer ``[pages + 1, page_size,
@@ -278,7 +287,9 @@ def init_hybrid_cache(
     "v_win"}``: per window layer ``[batch_slots, window, kv_heads_window *
     width]``; ``{"conv_state"}``: per convolution layer ``[batch_slots,
     conv_positions * d_model]``.  A kind with no layers has an empty
-    tuple."""
+    tuple.  Per state-space layer, ``{"mamba_conv_state"}`` ``[batch_slots,
+    ssm_conv_positions * d_inner]`` and ``{"ssm_state"}`` ``[batch_slots,
+    d_state * d_inner]``, the latter float32."""
     if num_pages < 1:
         raise ValueError(f"num_pages must be >= 1, got {num_pages}")
     if _is_int8(dtype):
@@ -300,6 +311,14 @@ def init_hybrid_cache(
         "conv_state": tuple(
             jnp.zeros((batch_slots, conv_positions * d_model), dtype)
             for _ in range(conv_layers)
+        ),
+        "mamba_conv_state": tuple(
+            jnp.zeros((batch_slots, ssm_conv_positions * d_inner), dtype)
+            for _ in range(ssm_layers)
+        ),
+        "ssm_state": tuple(
+            jnp.zeros((batch_slots, d_state * d_inner), jnp.float32)
+            for _ in range(ssm_layers)
         ),
     }
 

@@ -13,8 +13,9 @@ has to refuse for it.  Two bindings exist:
   full attention layers with their own KV head counts and cache lifetimes,
   sparse experts of which a stated subset is held, with or without an
   always-on one beside them; gated short-convolution layers with no K/V at
-  all): its cache has per-slot state (the window
-  layers' rings, the convolution layers' last inputs) beside the pages, so
+  all; state-space layers): its cache has per-slot state (the window
+  layers' rings, the convolution layers' last inputs, the state-space
+  layers' last inputs and scan state) beside the pages, so
   its chunk program is also told the slot and how many of the chunk's
   tokens are real, its decode program which lanes are live, and its decode
   step returns a small vector of expert counts that rides with the step's
@@ -177,6 +178,10 @@ def hybrid_model(spec) -> ServedModel:
     n_full = len(spec.layers_of(hm.FULL))
     n_window = len(spec.layers_of(hm.WINDOW))
     n_conv = len(spec.layers_of(hm.CONV))
+    n_mamba = len(spec.layers_of(hm.MAMBA))
+    ssm = {} if spec.ssm is None else dict(
+        ssm_conv_positions=spec.ssm.d_conv - 1, d_inner=spec.ssm.d_inner,
+        d_state=spec.ssm.d_state)
 
     # bytes of the cache this description laid out (set by init_cache, read
     # by count_step): the per-slot state of ONE lane, the K/V of ONE position
@@ -191,7 +196,7 @@ def hybrid_model(spec) -> ServedModel:
             kv_heads_window=spec.kv_heads_window,
             k_dim=spec.k_dim, v_dim=spec.v_dim,
             conv_layers=n_conv, conv_positions=max(spec.conv_taps - 1, 0),
-            d_model=spec.d_model, dtype=dtype,
+            d_model=spec.d_model, ssm_layers=n_mamba, **ssm, dtype=dtype,
         )
         laid_out["lane_state"] = kv_cache.slot_state_bytes(cache) // batch_slots
         laid_out["position"] = kv_cache.page_bytes(cache) // page_size
@@ -212,8 +217,8 @@ def hybrid_model(spec) -> ServedModel:
     def scrub(cache, page_ids, from_offs, slot, *, page_size):
         # the listed pages from their offsets on, in every full layer, and
         # the slot's whole ring in every window layer and whole state in
-        # every convolution layer: what they hold is the sequence's newest
-        # positions, which a scrub from any position reaches
+        # every convolution and state-space layer: what they hold is the
+        # sequence's newest positions, which a scrub from any position reaches
         zero = (jnp.arange(page_size)[None, :] >= from_offs[:, None])[..., None]
         out = {}
         for name, leaves in cache.items():
@@ -263,7 +268,8 @@ def hybrid_model(spec) -> ServedModel:
             "kv_bytes_held_sum": int(held.sum()) * laid_out["position"],
         }
 
-    state = ("the convolution layers' state" if n_conv
+    state = ("the state-space layers' state" if n_mamba
+             else "the convolution layers' state" if n_conv
              else "the window layers' last positions")
     reasons = {
         "prefix_cache": f"a hit would need {state} at the prefix's end, and "

@@ -160,7 +160,7 @@ def test_the_spec_reads_the_published_keys():
 
 
 NEW_DEFAULTS = {"shared_width": 0, "output_gate": False, "post_norms": False,
-                "rotate_full": True, "embed_scale": 1.0}
+                "rotate_full": True, "embed_scale": 1.0, "ssm": None}
 #: the specs the two accepted files gave on the parent commit, field by field
 AS_BEFORE = {
     "mimo-v2-flash": dict(

@@ -11,7 +11,9 @@ kernel the TPU compiler would refuse fails here, in tier-1, instead of as an
 single head (the one shape at which the block rule happens to hold whatever
 the kernel does, which exposes what else the lowering objects to).  Pool
 pages come as the pool holds them: heads folded into the minor axis,
-``[P, page, h * hd]``, a head read as a lane slice of the page.
+``[P, page, h * hd]``, a head read as a lane slice of the page.  The
+state-space chunk scan (``ops/ssm_scan.py``) lowers at ``jamba2-3b``'s 5,120
+channels and 16 states.
 """
 
 from __future__ import annotations
@@ -148,3 +150,17 @@ def test_dense_layout_refuses_a_length_it_cannot_tile():
     assert fd.dense_block(320) == 80
     with pytest.raises(ValueError, match="no multiple-of-8 divisor"):
         fd.dense_block(331)
+
+
+@pytest.mark.parametrize("rows", [512, 256, 128, 5])
+def test_ssm_scan_chunk_lowers_for_tpu(rows):
+    """The state-space layers' chunk scan at the served width (5,120
+    channels, 16 states): the prefill chunk's widths, and a count of rows
+    that is not a multiple of eight."""
+    from distributeddeeplearning_tpu.ops import ssm_scan
+
+    by_row, state = _sds((rows, 5120)), _sds((16, 5120))
+    text = _lower_for_tpu(ssm_scan.ssm_scan_chunk, by_row, by_row,
+                          _sds((rows, 16)), _sds((rows, 16)), state,
+                          _sds((5120,)), state)
+    assert text.count(MOSAIC) == 1 and "ssm_scan_chunk_float32" in text
