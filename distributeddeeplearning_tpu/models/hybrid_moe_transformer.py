@@ -45,10 +45,10 @@ whatever the slot holds.
 A fourth kind, the **state-space** (Mamba) layer (:func:`mamba_mixer`),
 carries more than a few inputs: beside its convolution's last ``d_conv - 1``
 inputs, a state of ``d_state x d_inner`` values a slot that a selective scan
-updates at every row (:mod:`ops.ssm_scan` over a chunk's rows, one step a lane
-in a decode step).  Its cache is two per-slot leaves: the convolution's last
-inputs, laid out as a convolution layer's, and the state in float32, folded
-``[slots, d_state * d_inner]``; both start from zeros at offset 0.
+updates at every row (:mod:`ops.ssm_scan` over a chunk's rows, and one step
+a live lane in a decode step).  Its cache is two per-slot leaves: the
+convolution's last inputs, laid out as a convolution layer's, and the state
+in float32, ``[slots, d_state, d_inner]``; both start from zeros at offset 0.
 """
 
 from __future__ import annotations
@@ -61,7 +61,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from distributeddeeplearning_tpu.ops import flash_decode as _fd
-from distributeddeeplearning_tpu.ops.ssm_scan import ssm_scan_chunk
+from distributeddeeplearning_tpu.ops.ssm_scan import (
+    live_lanes,
+    ssm_decode_step,
+    ssm_scan_chunk,
+)
 
 PyTree = Any
 FULL, WINDOW, CONV, MAMBA = 0, 1, 2, 3
@@ -862,7 +866,8 @@ def forward_decode(params, token, cache, pos, block_tables, live, *,
     live writes to the scratch page and leaves its ring, its convolution
     state and its scan's state as they were, and its token is routed to no
     expert).  A state-space layer takes one step of its recurrence for
-    every lane, in XLA (elementwise over ``[B, d_state, d_inner]``).
+    each live lane (:func:`ops.ssm_scan.ssm_decode_step`, which reads and
+    writes no other lane's state).
     Returns ``(logits [B, vocab] float32,
     cache, counts)`` with ``counts`` the :data:`EXPERT_COUNTS` over the
     step's expert layers (int32 [4])."""
@@ -873,6 +878,8 @@ def forward_decode(params, token, cache, pos, block_tables, live, *,
     off = pos % page_size
     if WINDOW in spec.attn_kinds:
         slot_pos = ring_positions(pos, W)  # [B, W]
+    if MAMBA in spec.attn_kinds:
+        lanes = live_lanes(live)  # the live lanes first, and how many
     cache = {name: list(leaves) for name, leaves in cache.items()}
 
     def callback_of(layer):
@@ -890,14 +897,11 @@ def forward_decode(params, token, cache, pos, block_tables, live, *,
                          for j in range(taps - 1)) + (u,)
 
         def scan(dt, x, b, c, a, d):
-            # one step a lane from its slot's state [B, d_state * d_inner]
-            held = cache["ssm_state"][i]
-            s = held.reshape(B, *a.shape)
-            s = (jnp.exp(dt[:, None, :] * a) * s
-                 + (dt * x)[:, None, :] * b[:, :, None])
-            cache["ssm_state"][i] = jnp.where(live[:, None], s.reshape(B, -1),
-                                              held)
-            return (s * c[:, :, None]).sum(1) + d * x
+            # one step for each live lane from its slot's state [B, d_state,
+            # d_inner], in place: the other lanes' are neither read nor written
+            y, cache["ssm_state"][i] = ssm_decode_step(
+                dt, x, b, c, a, d, cache["ssm_state"][i], lanes)
+            return y
 
         if kind == CONV:
             return conv
@@ -997,10 +1001,10 @@ def forward_prefill_chunk(params, tokens, cache, block_table, offset, slot,
 
         def scan(dt, x, b, c, a, d):
             leaf = cache["ssm_state"][i]
-            s0 = jnp.where(offset > 0, leaf[slot], 0).reshape(a.shape)
+            s0 = jnp.where(offset > 0, leaf[slot], 0)
             dt = jnp.where((jnp.arange(C) < real)[:, None], dt, 0.0)
             y, s = ssm_scan_chunk(dt, x, b, c, a, d, s0)
-            cache["ssm_state"][i] = leaf.at[slot].set(s.reshape(-1))
+            cache["ssm_state"][i] = leaf.at[slot].set(s)
             return y
 
         if kind == CONV:

@@ -19,10 +19,20 @@ from the first row to the last, so each operand is read from HBM once and the
 ``[T, d_state, d_inner]`` products never exist.  A row whose ``dt`` is 0 leaves
 the state as it was (``exp(0) = 1`` and it adds nothing): the caller marks the
 rows that pad a chunk so, and the wrapper pads the rows to a multiple of eight
-the same way.  Off the TPU it runs in interpret mode, whenever
-``ops.flash_decode`` does (its ``_use_interpret`` decides for both).
-:func:`ssm_scan_reference` is the same recurrence as a plain ``lax.scan``
-over the rows: the kernel's pin.
+the same way.  :func:`ssm_scan_reference` is the same recurrence as a plain
+``lax.scan`` over the rows: the kernel's pin.
+
+:func:`ssm_decode_step` takes one row of every lane of a batch (a decode
+step) from the states ``[B, d_state, d_inner]``, and reads and writes the
+state of the live lanes only: its grid walks the lanes in the order
+:func:`live_lanes` gives (the live ones first, as scalar prefetch), a step
+past the last live lane maps its state block to that lane's, so it fetches
+nothing and writes nothing back, and the state is updated in place, so a
+lane the kernel never visits keeps its bytes.  :func:`ssm_step_reference` is
+the same step in XLA over every lane: its pin.
+
+Off the TPU both kernels run in interpret mode, whenever ``ops.flash_decode``
+does (its ``_use_interpret`` decides for all).
 """
 
 from __future__ import annotations
@@ -125,3 +135,97 @@ def ssm_scan_reference(dt, x, b, c, a, d, s0):
     s, y = jax.lax.scan(step, s0.astype(f32),
                         tuple(v.astype(f32) for v in (dt, x, b, c)))
     return y, s
+
+
+def live_lanes(live):
+    """``(order [B], count [1])`` int32 of a ``live`` [B] bool: the live
+    lanes first, each group in lane order, and how many are live."""
+    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+    return order, live.sum(dtype=jnp.int32).reshape(1)
+
+
+def _step_kernel(order_ref, count_ref, dt_ref, x_ref, b_ref, c_ref, a_ref,
+                 d_ref, s_ref, y_ref, s_out_ref):
+    """Grid step ``i`` takes lane ``order[i]`` while ``i < count``:
+    ``dt_ref``, ``x_ref``, ``y_ref`` [B, d_inner] and ``b_ref``, ``c_ref``
+    [B, N] whole; ``s_ref``, ``s_out_ref`` the lane's [N, d_inner]; ``a_ref``
+    [N, d_inner]; ``d_ref`` [1, d_inner]."""
+    i, count = pl.program_id(0), count_ref[0]
+
+    @pl.when(i == 0)
+    def _():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when((i == 0) & (count == 0))
+    def _():
+        # no live lane: the block goes back as it came
+        s_out_ref[...] = s_ref[...]
+
+    @pl.when(i < count)
+    def _():
+        lane = order_ref[i]
+        n = a_ref.shape[0]
+        eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+               == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+               ).astype(jnp.float32)
+        dt = dt_ref[pl.ds(lane, 1), :]
+        x = x_ref[pl.ds(lane, 1), :]
+        b_col = jnp.sum(eye * b_ref[pl.ds(lane, 1), :], axis=1, keepdims=True)
+        c_col = jnp.sum(eye * c_ref[pl.ds(lane, 1), :], axis=1, keepdims=True)
+        s = jnp.exp(dt * a_ref[...]) * s_ref[...] + (dt * x) * b_col
+        s_out_ref[...] = s
+        y_ref[pl.ds(lane, 1), :] = (jnp.sum(s * c_col, axis=0, keepdims=True)
+                                    + d_ref[...] * x)
+
+
+def ssm_decode_step(dt, x, b, c, a, d, s, lanes):
+    """One step of the recurrence for every live lane: ``dt``, ``x`` [B,
+    d_inner], ``b``, ``c`` [B, N], ``a`` [N, d_inner], ``d`` [d_inner],
+    ``s`` [B, N, d_inner], all float32, and ``lanes`` = :func:`live_lanes`.
+    Returns ``(y [B, d_inner], s')``: a lane that is not live has a ``y`` of
+    0 and its state as it was, to the bit.  ``s`` is updated in place (its
+    buffer is the result's where the caller donates it).  The kernel is
+    named ``ssm_decode_step_<dtype>`` in a trace."""
+    B, di = dt.shape
+    n = a.shape[0]
+    f32 = jnp.float32
+    order, count = lanes
+
+    def lane_block(i, order, count):
+        # a step past the last live lane stays on that lane's block
+        return (order[jnp.minimum(i, jnp.maximum(count[0] - 1, 0))], 0, 0)
+
+    whole = lambda shape: pl.BlockSpec(shape, lambda i, order, count: (0, 0))
+    state = pl.BlockSpec((None, n, di), lane_block)
+    interpret = _fd._use_interpret()
+    y, s = pl.pallas_call(
+        _step_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[whole((B, di)), whole((B, di)), whole((B, n)),
+                      whole((B, n)), whole((n, di)), whole((1, di)), state],
+            out_specs=[whole((B, di)), state],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, di), f32),
+                   jax.ShapeDtypeStruct((B, n, di), f32)],
+        # operand 8 (after the two prefetched scalars) is the state
+        input_output_aliases={8: 1},
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=f"ssm_decode_step_{jnp.dtype(f32).name}",
+    )(order, count, *(v.astype(f32) for v in (dt, x, b, c, a)),
+      d.astype(f32).reshape(1, di), s.astype(f32))
+    return y, s
+
+
+@jax.jit
+def ssm_step_reference(dt, x, b, c, a, d, s, live):
+    """The same step in XLA over every lane (the kernel's pin): each lane's
+    state [B, N, d_inner] moves on by one, and a lane that is not ``live``
+    keeps its own; ``y`` is every lane's."""
+    new = (jnp.exp(dt[:, None, :] * a) * s
+           + (dt * x)[:, None, :] * b[:, :, None])
+    return ((new * c[:, :, None]).sum(1) + d * x,
+            jnp.where(live[:, None, None], new, s))

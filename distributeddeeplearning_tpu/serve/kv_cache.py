@@ -253,7 +253,9 @@ def slot_state_bytes(cache: Cache) -> int:
 # (Mamba) layer carries the same of its own convolution (``d_conv - 1``
 # inputs of ``d_inner``) and its scan's state, ``d_state x d_inner`` values
 # in float32 whatever the cache's dtype (a decode step would otherwise round
-# a state carried over tens of thousands of positions), folded state-major.
+# a state carried over tens of thousands of positions), ``[d_state, d_inner]``
+# a slot as the scan kernels read it (a folded row would be copied to that
+# tiling at every step).
 # --------------------------------------------------------------------------
 
 #: the per-slot leaves of such a cache (every other leaf is paged)
@@ -289,7 +291,7 @@ def init_hybrid_cache(
     conv_positions * d_model]``.  A kind with no layers has an empty
     tuple.  Per state-space layer, ``{"mamba_conv_state"}`` ``[batch_slots,
     ssm_conv_positions * d_inner]`` and ``{"ssm_state"}`` ``[batch_slots,
-    d_state * d_inner]``, the latter float32."""
+    d_state, d_inner]``, the latter float32."""
     if num_pages < 1:
         raise ValueError(f"num_pages must be >= 1, got {num_pages}")
     if _is_int8(dtype):
@@ -317,7 +319,7 @@ def init_hybrid_cache(
             for _ in range(ssm_layers)
         ),
         "ssm_state": tuple(
-            jnp.zeros((batch_slots, d_state * d_inner), jnp.float32)
+            jnp.zeros((batch_slots, d_state, d_inner), jnp.float32)
             for _ in range(ssm_layers)
         ),
     }

@@ -13,7 +13,7 @@ the kernel does, which exposes what else the lowering objects to).  Pool
 pages come as the pool holds them: heads folded into the minor axis,
 ``[P, page, h * hd]``, a head read as a lane slice of the page.  The
 state-space chunk scan (``ops/ssm_scan.py``) lowers at ``jamba2-3b``'s 5,120
-channels and 16 states.
+channels and 16 states, and its decode step at the served cell's 64 lanes.
 """
 
 from __future__ import annotations
@@ -164,3 +164,19 @@ def test_ssm_scan_chunk_lowers_for_tpu(rows):
                           _sds((rows, 16)), _sds((rows, 16)), state,
                           _sds((5120,)), state)
     assert text.count(MOSAIC) == 1 and "ssm_scan_chunk_float32" in text
+
+
+def test_ssm_decode_step_lowers_for_tpu():
+    """The state-space layers' decode step at the served cell's 64 lanes of
+    5,120 channels and 16 states, its state updated in place."""
+    from distributeddeeplearning_tpu.ops import ssm_scan
+
+    def step(dt, x, b, c, a, d, s, live):
+        return ssm_scan.ssm_decode_step(dt, x, b, c, a, d, s,
+                                        ssm_scan.live_lanes(live))
+
+    by_lane = _sds((64, 5120))
+    text = _lower_for_tpu(step, by_lane, by_lane, _sds((64, 16)),
+                          _sds((64, 16)), _sds((16, 5120)), _sds((5120,)),
+                          _sds((64, 16, 5120)), _sds((64,), jnp.bool_))
+    assert text.count(MOSAIC) == 1 and "ssm_decode_step_float32" in text

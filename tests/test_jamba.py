@@ -210,7 +210,7 @@ def test_the_state_is_a_slot_s_and_does_not_grow_with_the_sequence():
         assert [leaf.shape for leaf in eng.cache["mamba_conv_state"]] == [
             (SLOTS, 3 * DI)] * 4
         assert [leaf.shape for leaf in eng.cache["ssm_state"]] == [
-            (SLOTS, N * DI)] * 4
+            (SLOTS, N, DI)] * 4
         assert kv_cache.slot_state_bytes(eng.cache) == SLOTS * lane
     assert short.page_bytes_each == PAGE * 2 * 8 * 4  # one attention layer, K and V
     assert long.required_pages(1000, 24) == 256
@@ -487,6 +487,80 @@ def test_the_chunk_program_calls_the_scan_kernel():
     names = [e.params.get("name") for e in jaxpr.eqns
              if e.primitive.name == "pallas_call"]
     assert names == ["ssm_scan_chunk_float32"] * 4
+
+
+# -- the decode step kernel ------------------------------------------------------------
+
+
+LANES = 64
+LIVE_PATTERNS = {
+    "none": [],
+    "lane_0": [0],
+    "lane_63": [63],
+    "every_lane": list(range(LANES)),
+    "scattered": [1, 5, 6, 17, 30, 31, 32, 47, 58],
+}
+
+
+def _step_case(live, di=5120, seed=0):
+    """One decode step's inputs at the served width, from states that are
+    not zero, and ``live`` [LANES] from a list of live lanes."""
+    rng = np.random.default_rng(seed)
+    f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    dt = jnp.asarray(rng.uniform(0.0, 0.3, (LANES, di)), jnp.float32)
+    a = -jnp.asarray(rng.uniform(1.0, 16.0, (N, di)), jnp.float32)
+    case = (dt, f32(LANES, di), f32(LANES, N), f32(LANES, N), a, f32(di),
+            f32(LANES, N, di))
+    return case, jnp.zeros(LANES, bool).at[jnp.asarray(live, int)].set(True)
+
+
+@pytest.mark.parametrize("pattern", sorted(LIVE_PATTERNS))
+def test_the_decode_step_kernel_moves_live_lanes_only(pattern):
+    """In interpret mode at 64 lanes of 5,120 channels and 16 states: the
+    live lanes' state and ``y`` are the reference's to float32 rounding, the
+    other lanes' state is as it was to the bit and their ``y`` finite, and a
+    live lane whose state is NaN comes out NaN."""
+    live_list = LIVE_PATTERNS[pattern]
+    (dt, x, b, c, a, d, s), live = _step_case(live_list)
+    if live_list:  # poison the last live lane's state at one value
+        s = s.at[live_list[-1], 3, 100].set(jnp.nan)
+    held = np.asarray(s)
+    y, s_new = ssm_scan.ssm_decode_step(dt, x, b, c, a, d, s,
+                                        ssm_scan.live_lanes(live))
+    y_ref, s_ref = ssm_scan.ssm_step_reference(dt, x, b, c, a, d, s, live)
+    y, s_new, y_ref, s_ref = map(np.asarray, (y, s_new, y_ref, s_ref))
+    on = np.asarray(live)
+    assert y.shape == (LANES, 5120) and s_new.shape == (LANES, N, 5120)
+    np.testing.assert_allclose(y[on], y_ref[on], rtol=1e-6, atol=1e-5)
+    np.testing.assert_allclose(s_new[on], s_ref[on], rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(s_new[~on], held[~on])
+    assert np.isfinite(y[~on]).all()
+    if live_list:
+        poisoned, clean = live_list[-1], live_list[:-1]
+        assert np.isnan(s_new[poisoned, 3, 100]) and np.isnan(y[poisoned, 100])
+        assert np.isfinite(s_new[clean]).all() and np.isfinite(y[clean]).all()
+    if len(live_list) > 1:  # the state moved
+        assert np.abs(s_new[clean] - held[clean]).max() > 1e-2
+
+
+def test_the_decode_program_calls_the_step_kernel_and_copies_no_state():
+    params = make_params()
+    cache = hybrid_model(SPEC).init_cache(num_pages=8, page_size=PAGE,
+                                          batch_slots=SLOTS, dtype=jnp.float32)
+    zeros = jnp.zeros(SLOTS, jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda p, c: hm.forward_decode(
+        p, zeros, c, zeros, jnp.zeros((SLOTS, 4), jnp.int32),
+        jnp.ones(SLOTS, bool), spec=SPEC, page_size=PAGE))(params, cache)
+    names = [e.params.get("name") for e in jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert names == ["ssm_decode_step_float32"] * 4
+    # the state goes to the kernel as the cache holds it, and nothing else
+    # reads it
+    states = [v for v in jaxpr.jaxpr.invars if v.aval.shape == (SLOTS, N, DI)]
+    assert len(states) == 4
+    readers = [e for e in jaxpr.eqns
+               if any(v is state for v in e.invars for state in states)]
+    assert [e.primitive.name for e in readers] == ["pallas_call"] * 4
 
 
 # -- what the engine refuses for this model --------------------------------------------
